@@ -16,8 +16,11 @@ Three real-path measurements (one JSON line each on stdout):
    47,019 read req/s on a MacBook i7).
 
 Knobs: BENCH_E2E_VOL_MB (volume size, default 1024), BENCH_E2E_N
-(benchmark file count, default 20000), BENCH_E2E_DEVICE=0 to skip the
-device pass (e.g. when the chip is busy).
+(benchmark file count, default 20000), BENCH_E2E_DEVICE=0 to leave the
+device pass out.  The device pass is a chip metric: with it on and no
+TPU the script exits non-zero before measuring anything, and any pass
+that fails ends the run non-zero — nothing is logged-and-skipped.
+Every emitted line names the platform it ran on.
 
 Diagnostics on stderr; stdout carries exactly one JSON line per metric.
 """
@@ -134,10 +137,7 @@ def bench_ec_encode(base: str, backend: str, chunk_mb: int = 8) -> float:
     mbps = dat_size / dt / 1e6
     log(f"ec.encode[{backend}]: {dat_size / 1e6:.0f} MB in {dt:.2f}s "
         f"= {mbps:.1f} MB/s")
-    try:
-        _stage_breakdown(base, coder, chunk_mb)
-    except Exception as e:  # noqa: BLE001 — diagnostics must not kill
-        log(f"  stage breakdown failed: {type(e).__name__}: {e}")
+    _stage_breakdown(base, coder, chunk_mb)
     return mbps
 
 
@@ -490,6 +490,8 @@ def multichip_row(n_devices: int = 8,
         json.dump(doc, f, indent=2)
         f.write("\n")
     log(f"wrote {out_path}: {tail.strip()}")
+    if not doc["ok"]:
+        raise RuntimeError(f"multichip row failed: {tail.strip()}")
 
 
 def _emit_roofline() -> None:
@@ -499,38 +501,47 @@ def _emit_roofline() -> None:
     fenced kernel rows and pipeline occupancy — publish the headline
     numbers (full table: BENCH_roofline_r01.json via
     `python bench_schemes.py --roofline`)."""
-    try:
-        from seaweedfs_tpu.stats import roofline as rl
-        table = rl.LEDGER.kernel_table()
-        if not table:
-            return
-        cons = rl.LEDGER.conservation()
-        for row in table:
-            ach = row["achieved_p50"]
-            emit(f"roofline {row['kernel']} {row['codec']}/"
-                 f"{row['dtype']} {row['geometry']}",
-                 ach if ach is not None else 0.0,
-                 "fraction of probed roofline", None,
-                 f"{row['count']} fenced calls, {row['seconds']}s, "
-                 f"conservation "
-                 f"{'OK' if cons['ok'] else 'VIOLATED'}")
-        occ = rl.LEDGER.occupancy_summary()
-        for kind, ent in sorted(occ["latest"].items()):
-            if ent["fraction"] is None:
-                continue
-            emit(f"roofline {kind} pipeline device occupancy",
-                 ent["fraction"], "fraction", None,
-                 f"starved by {ent['starving_stage'] or '-'}"
-                 + (" [COLLAPSED]" if occ["collapsed"].get(kind)
-                    else ""))
-    except Exception as e:  # noqa: BLE001
-        log(f"roofline rollup skipped: {type(e).__name__}: {e}")
+    from seaweedfs_tpu.stats import roofline as rl
+    table = rl.LEDGER.kernel_table()
+    if not table:
+        return
+    peaks = rl.local_peaks() or {}
+    where = f"{peaks.get('backend', '?')}/{peaks.get('device_kind', '?')}"
+    cons = rl.LEDGER.conservation()
+    for row in table:
+        ach = row["achieved_p50"]
+        emit(f"roofline {row['kernel']} {row['codec']}/"
+             f"{row['dtype']} {row['geometry']}",
+             ach if ach is not None else 0.0,
+             f"fraction of probed {where} roofline", None,
+             f"{row['count']} fenced calls, {row['seconds']}s, "
+             f"conservation "
+             f"{'OK' if cons['ok'] else 'VIOLATED'}")
+    occ = rl.LEDGER.occupancy_summary()
+    for kind, ent in sorted(occ["latest"].items()):
+        if ent["fraction"] is None:
+            continue
+        emit(f"roofline {kind} pipeline device occupancy ({where})",
+             ent["fraction"], "fraction", None,
+             f"starved by {ent['starving_stage'] or '-'}"
+             + (" [COLLAPSED]" if occ["collapsed"].get(kind)
+                else ""))
 
 
-def main() -> None:
+def main() -> int:
     vol_mb = int(os.environ.get("BENCH_E2E_VOL_MB", "1024"))
     n = int(os.environ.get("BENCH_E2E_N", "20000"))
     do_device = os.environ.get("BENCH_E2E_DEVICE", "1") == "1"
+
+    from seaweedfs_tpu.utils import jaxenv
+    dev = jaxenv.device_summary()
+    where = (f"platform={dev['platform']} device_kind="
+             f"{dev['device_kind']!r} devices={dev['count']}")
+    log(where)
+    if do_device and dev["platform"] != "tpu":
+        log("the device pass measures the chip and JAX resolved no TPU; "
+            "set BENCH_E2E_DEVICE=0 for the host-only rows")
+        return 1
 
     tmp = tempfile.mkdtemp(prefix="bench_e2e_")
     try:
@@ -544,36 +555,26 @@ def main() -> None:
         cleanup_shards(base)
 
         if do_device:
-            try:
-                import jax
-                platform = jax.devices()[0].platform
-                dev_mbps = bench_ec_encode(base, "pallas", chunk_mb=32)
-                emit(f"ec.encode {vol_mb}MB volume, device end-to-end",
-                     dev_mbps, "MB/s",
-                     dev_mbps / cpu_mbps if cpu_mbps else None,
-                     f"write_ec_files on {platform}: disk -> host -> "
-                     "device -> kernel -> host -> shard files")
-                cleanup_shards(base)
-            except Exception as e:  # noqa: BLE001
-                log(f"device pass skipped: {type(e).__name__}: {e}")
+            dev_mbps = bench_ec_encode(base, "pallas", chunk_mb=32)
+            emit(f"ec.encode {vol_mb}MB volume, device end-to-end",
+                 dev_mbps, "MB/s",
+                 dev_mbps / cpu_mbps if cpu_mbps else None,
+                 f"write_ec_files on {where}: disk -> host -> "
+                 "device -> kernel -> host -> shard files")
+            cleanup_shards(base)
     finally:
         shutil.rmtree(tmp, ignore_errors=True)
 
     if os.environ.get("BENCH_E2E_WIRE", "1") == "1":
-        try:
-            doc = bench_cluster_encode()
-            emit("cluster ec.encode wire-to-wire MB/s (streamed)",
-                 doc["streamed"]["mbps"], "MB/s",
-                 doc["measured_ratio"],
-                 f"vs serialized {doc['serialized']['mbps']} MB/s in "
-                 f"the same run; projected overlap x"
-                 f"{doc['projected_ratio']}; BENCH_e2e_r01.json")
-        except Exception as e:  # noqa: BLE001
-            log(f"wire-to-wire pass failed: {type(e).__name__}: {e}")
-        try:
-            multichip_row()
-        except Exception as e:  # noqa: BLE001
-            log(f"multichip row failed: {type(e).__name__}: {e}")
+        doc = bench_cluster_encode()
+        emit(f"cluster ec.encode wire-to-wire MB/s (streamed, "
+             f"{doc['platform']})",
+             doc["streamed"]["mbps"], "MB/s",
+             doc["measured_ratio"],
+             f"vs serialized {doc['serialized']['mbps']} MB/s in "
+             f"the same run; projected overlap x"
+             f"{doc['projected_ratio']}; BENCH_e2e_r01.json")
+        multichip_row()
 
     _emit_roofline()
 
@@ -586,14 +587,17 @@ def main() -> None:
          rd["req_per_sec"] / REF_READ_RPS,
          f"n={n} 1KB c=16 vs reference MacBook 47019 req/s; "
          f"p99 {rd['latency_ms']['p99']}ms")
+    return 0
 
 
 if __name__ == "__main__":
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    from seaweedfs_tpu.utils.jaxenv import place_compile_cache
+    place_compile_cache()
     if len(sys.argv) > 2 and sys.argv[1] == "--multichip-child":
         _multichip_child(int(sys.argv[2]))
     elif len(sys.argv) > 1 and sys.argv[1] == "--wire-only":
         bench_cluster_encode()
         multichip_row()
     else:
-        main()
+        sys.exit(main())

@@ -117,7 +117,8 @@ def bench_roofline(out: str = "BENCH_roofline_r01.json") -> None:
     kernel table, conservation verdict, and peaks are what this
     publishes — plus the armed-vs-disarmed overhead of the plane
     itself."""
-    n = int(os.environ.get("BENCH_ROOFLINE_N", str(256 * 1024)))
+    # One `.ecc` block: the narrowest width the fused-CRC kernel takes.
+    n = int(os.environ.get("BENCH_ROOFLINE_N", str(1024 * 1024)))
     reps = int(os.environ.get("BENCH_ROOFLINE_REPS", "3"))
     dev = jax.devices()[0]
     log(f"device: {dev}  n={n} bytes/shard  reps={reps}")

@@ -1,6 +1,6 @@
-"""VERDICT r4 #9 probe: separate K-effect from P-effect in the RS
-kernel column-rate spread.  Measures the fused kernel at the two real
-schemes plus the two synthetic cross schemes RS(10,3)/RS(8,4):
+"""Probe: separate K-effect from P-effect in the RS kernel column-rate
+spread (BASELINE.md round 5).  Measures the fused kernel at the two
+real schemes plus the two synthetic cross schemes RS(10,3)/RS(8,4):
 if column rate tracks K (80 vs 64 contraction rows), the spread is
 shape-structural; if it tracks P, it's output-rows-bound."""
 

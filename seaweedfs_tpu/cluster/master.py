@@ -1806,8 +1806,10 @@ class MasterServer:
         """GET /cluster/device — the device roofline rollup: every
         node's heartbeat-carried kernel rows merged into one cluster
         table keyed by (kernel, codec, dtype, geometry), per-node
-        pipeline occupancy with collapse verdicts, and this master's
-        own probed peaks.  ?codec= / ?kernel= filter the table."""
+        pipeline occupancy with collapse verdicts, and — only when this
+        process has itself run a kernel — its own probed peaks (a
+        separate master owns no chip and must not claim one to answer).
+        ?codec= / ?kernel= filter the table."""
         from ..stats import roofline as _roofline
         if not self.is_leader():
             return self._proxy_to_leader("/cluster/device", query,
@@ -1870,7 +1872,7 @@ class MasterServer:
         table = sorted(merged.values(),
                        key=lambda m: (-m["seconds"], m["kernel"]))
         return {"ts": time.time(), "leader": self.url(),
-                "peaks": _roofline.probe_peaks(),
+                "peaks": _roofline.local_peaks(),
                 "nodes": nodes, "kernels": table,
                 "warnings": warnings}
 

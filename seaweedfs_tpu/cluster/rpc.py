@@ -1730,8 +1730,15 @@ class _ConnPool:
         self.gen = 0
 
     def acquire(self, scheme: str, host: str, port: int,
-                timeout: float):
+                timeout: float, fresh: bool = False):
         """Returns (conn, was_reused).
+
+        `fresh` is the retry after a stale keep-alive: every other idle
+        conn to that host is at least as old as the one the server just
+        turned out to have closed (a client that sat out the server's
+        idle timeout — minutes inside one XLA compile — finds its WHOLE
+        pool reaped), so they are all dropped and a new one dialled
+        rather than handing the one-shot retry a second corpse.
 
         Client sockets keep Python-level settimeout (NOT the server's
         SO_RCVTIMEO trick): with a kernel timeout, a slow server is
@@ -1762,9 +1769,12 @@ class _ConnPool:
             except Exception:
                 breaker.record_failure()
                 raise
+        stale: list[_Conn] = []
         with self._lock:
             pool = self._idle.get(key)
-            if pool:
+            if fresh:
+                stale = self._idle.pop(key, [])
+            elif pool:
                 conn = pool.pop()
                 if conn.timeout != timeout:
                     conn.sock.settimeout(timeout)
@@ -1774,6 +1784,8 @@ class _ConnPool:
             # if a rotation lands during our handshake below, this
             # conn keeps the OLD gen and release() will drop it.
             ctx, gen = _client_ssl_context, self.gen
+        for conn in stale:
+            conn.close()
         try:
             sock = socket.create_connection((host, port),
                                             timeout=timeout)
@@ -1894,7 +1906,8 @@ def _request(url: str, method: str, body, timeout: float,
     if body:
         req += body
     for attempt in (0, 1):
-        conn, reused = _pool.acquire(scheme, host, port, timeout)
+        conn, reused = _pool.acquire(scheme, host, port, timeout,
+                                     fresh=attempt > 0)
         try:
             # Fault points fire INSIDE the retry loop's try: an armed
             # `fail` surfaces as a peer reset and takes the exact

@@ -949,7 +949,7 @@ class VolumeServer:
                 # Zero-copy fast path for large plain needles: CRC is
                 # verified by streaming preads, then the responder
                 # os.sendfile's the payload straight from the .dat
-                # (VERDICT r4 #1; the reference serves the same bytes
+                # (the reference serves the same bytes
                 # after its own CRC check,
                 # volume_server_handlers_read.go:28).
                 try:

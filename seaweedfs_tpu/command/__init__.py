@@ -136,6 +136,10 @@ def main(argv: list[str] | None = None) -> int:
         print(f"unknown command {name!r}\n\n{usage()}", file=sys.stderr)
         return 2
     flags, rest = parse_flags(args)
+    # Before any command can reach JAX: compiled kernels persist under
+    # JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache.
+    from ..utils import jaxenv
+    jaxenv.place_compile_cache()
     # Global -v <level> wires glog verbosity on every command (server
     # roles included) so `glog.v(n)` gates actually fire; without the
     # flag the WEED_V env still applies (setup's None path) instead of

@@ -70,6 +70,20 @@ def _slo_flags(flags: Flags) -> dict:
                 flags.get_float("slo.availability", 0.0) or None}
 
 
+def _log_device(role: str, codes: bool, note: str = "") -> None:
+    """Every role says once, at start, what it computes on
+    (utils/jaxenv.py has the cluster -> chip map).  A role that runs a
+    coder resolves it HERE, before it serves: on a TPU host that claims
+    the chip, and a chip it cannot get stops the process now instead of
+    at the first ec.encode."""
+    if codes:
+        from ..ops.erasure import describe_backend
+        glog.infof("%s device: %s", role, describe_backend())
+    else:
+        glog.infof("%s device: none — this role runs no coder and "
+                   "initialises no JAX backend%s", role, note)
+
+
 def _wait_forever(servers: list, grace: float | None = None) -> int:
     stop = threading.Event()
 
@@ -156,6 +170,11 @@ def _start_volume_grpc(vs, flags: Flags, ip: str,
         allow_port_flag)
 
 
+_REPAIR_NOTE = (" until the repair daemon rebuilds an EC volume: that "
+                "leg runs the JAX device mesh in this process, which "
+                "must then be the chip owner (or run JAX_PLATFORMS=cpu)")
+
+
 def run_master(flags: Flags, args: list[str]) -> int:
     from ..cluster.master import MasterServer as Master
     from ..utils.config import load_configuration
@@ -165,6 +184,8 @@ def run_master(flags: Flags, args: list[str]) -> int:
     # master.toml [master.maintenance]: unattended EC/balance lifecycle
     # (master_server.go startAdminScripts).
     mcfg = load_configuration("master")
+    _log_device("master", False, _REPAIR_NOTE
+                if flags.get_bool("repair", False) else "")
     m = Master(
         host=flags.get("ip", "127.0.0.1"),
         port=flags.get_int("port", 9333),
@@ -242,6 +263,7 @@ def run_volume(flags: Flags, args: list[str]) -> int:
     maxes = [int(x) for x in flags.get("max", "8").split(",")]
     if len(maxes) == 1:
         maxes = maxes * len(dirs)
+    _log_device("volume", True)
     vs = VolumeServer(
         master_url=[_norm_master(u) for u in
                     flags.get("mserver", "127.0.0.1:9333").split(",")],
@@ -326,6 +348,7 @@ def run_volume(flags: Flags, args: list[str]) -> int:
 def run_msg_broker(flags: Flags, args: list[str]) -> int:
     from ..messaging.broker import MessageBroker
     filer = flags.get("filer", "127.0.0.1:8888")
+    _log_device("msg.broker", False)
     mb = MessageBroker(
         filer if filer.startswith("http") else f"http://{filer}",
         host=flags.get("ip", "127.0.0.1"),
@@ -341,6 +364,7 @@ def run_msg_broker(flags: Flags, args: list[str]) -> int:
 
 def run_filer(flags: Flags, args: list[str]) -> int:
     from ..filer.server import FilerServer
+    _log_device("filer", False)
     fs = FilerServer(
         master_url=[_norm_master(u) for u in
                     flags.get("master", "127.0.0.1:9333").split(",")],
@@ -399,6 +423,7 @@ def _s3_identities(config_path: str):
 
 def run_s3(flags: Flags, args: list[str]) -> int:
     from ..s3api.server import S3ApiServer
+    _log_device("s3", False)
     s3 = S3ApiServer(
         filer_url=_norm_master(flags.get("filer", "127.0.0.1:8888")),
         host=flags.get("ip", "127.0.0.1"),
@@ -413,6 +438,7 @@ def run_s3(flags: Flags, args: list[str]) -> int:
 
 def run_webdav(flags: Flags, args: list[str]) -> int:
     from ..webdav.server import WebDavServer
+    _log_device("webdav", False)
     dav = WebDavServer(
         filer_url=_norm_master(flags.get("filer", "127.0.0.1:8888")),
         host=flags.get("ip", "127.0.0.1"),
@@ -430,6 +456,8 @@ def run_server(flags: Flags, args: list[str]) -> int:
     from ..cluster.volume_server import VolumeServer
     servers: list = []
     ip = flags.get("ip", "127.0.0.1")
+    # One process, every role: this is the chip owner.
+    _log_device("server", True)
     m = Master(host=ip, port=flags.get_int("master.port", 9333),
                meta_dir=flags.get("mdir") or None,
                volume_size_limit_mb=flags.get_int(

@@ -66,12 +66,11 @@ class EcVolume:
         # The codec rides the .vif sidecar (like the needle version):
         # an explicit coder wins, then an explicit codec name, then
         # whatever the shards were generated with.
+        self._coder = coder
         if coder is not None:
-            self.coder = coder
             self.codec = getattr(coder, "codec", None) or get_codec("rs")
         else:
             self.codec = get_codec(codec or ec_codec_name(base_file_name))
-            self.coder = new_coder(codec=self.codec)
         self.shards: dict[int, EcVolumeShard] = {}
         self._ecx = open(base_file_name + ".ecx", "r+b")
         self.ecx_size = os.path.getsize(base_file_name + ".ecx")
@@ -80,6 +79,15 @@ class EcVolume:
         # Version detection is lazy: a server holding only parity shards
         # can still mount and serve raw shard bytes without knowing it.
         self._version = version
+
+    @property
+    def coder(self) -> ErasureCoder:
+        """Built on first reconstruct, not at mount: resolving the
+        default backend claims the device (utils/jaxenv.py), and a
+        server that only stores and serves shards never needs one."""
+        if self._coder is None:
+            self._coder = new_coder(codec=self.codec)
+        return self._coder
 
     @property
     def version(self) -> int:

@@ -2,8 +2,9 @@
 
 This is the host-side analog of klauspost/reedsolomon (the reference's CPU
 path) — it exists (a) as the honest CPU baseline for the TPU benchmark and
-(b) as the fast fallback when no accelerator is attached.  Requires
-`make -C native`; raises at construction if the library is missing.
+(b) as the coder of every process that owns no chip.  The library is
+built from native/ on first use (utils/native.py); construction raises
+if that build failed.
 """
 
 from __future__ import annotations
@@ -22,7 +23,8 @@ class NativeCoder:
         lib = native_mod.load()
         if lib is None:
             raise RuntimeError(
-                "native library not built — run `make -C native`")
+                "native library unavailable — its build failed (see "
+                "the log line from utils/native.py)")
         self._mix = native_mod.gf_encode_fn(lib)
         self.codec = rs_codec(data_shards, parity_shards, matrix_kind) \
             if codec is None else get_codec(codec)

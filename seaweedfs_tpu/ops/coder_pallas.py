@@ -33,6 +33,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..stats import roofline as _roofline
 from ..stats.metrics import observe_ec_stage
+from ..utils import jaxenv
 from . import crc_fold
 
 
@@ -122,19 +123,30 @@ def apply_bitmatrix_pallas(bmat_pm: jax.Array, shards: jax.Array,
     )(bmat_pm.astype(mm_dtype), shards)
 
 
+# Rows per bit plane in the CRC fold: shard rows (data then parity) are
+# zero-padded to a multiple of this so every per-plane slice of the
+# fold sits on a whole (16, 128) bf16 tile.
+_CRC_ROW_ALIGN = 16
+# CRC constants are zero-padded from 32 to one full lane tile: the MXU
+# spends a whole 128-wide pass on a 32-wide operand either way, and
+# full tiles keep every matmul and store in the kernel aligned.
+_CRC_LANES = 128
+
+
 def _rs_crc_kernel(b_ref, d_ref, w0_ref, pl_ref, pm_ref, o_ref, c_ref, *,
-                   out_rows: int, in_rows: int, mm_dtype):
+                   out_rows: int, in_rows: int, mm_dtype, tpb: int):
     """One tile of the CRC-fused pipeline: bytes (in_rows, BN) ->
-    parity bytes (out_rows, BN) PLUS a position-shifted CRC32-C tile
-    partial per row (in_rows data rows first, then out_rows parity
-    rows) — the `.ecc` sidecar computed from the bits already unpacked
-    in VMEM (ops/crc_fold.py has the algebra).  pm_ref is the
-    tile-position-in-block shift matrix, selected by the grid index
-    mod tiles-per-block, so host-side folding is a plain XOR."""
+    parity bytes (out_rows, BN), PLUS this tile's contribution to the
+    CRC32-C of the `.ecc` block it lies in, for every shard row
+    (in_rows data rows, then out_rows parity rows), accumulated into
+    c_ref across the block's `tpb` tiles (ops/crc_fold.py has the
+    algebra).  pm_ref is the tile-position-in-block shift matrix,
+    selected by the grid index mod tpb, which makes the per-tile
+    partials plain sums.  c_ref holds 0/1 bit COUNTS (rows x 32 live
+    lanes); the caller reduces them mod 2 and packs the words."""
     x = d_ref[:].astype(jnp.int32)
-    bits_i = jnp.concatenate(
-        [(x >> s) & 1 for s in range(8)], axis=0)
-    bits = bits_i.astype(mm_dtype)
+    bits = jnp.concatenate(
+        [(x >> s) & 1 for s in range(8)], axis=0).astype(mm_dtype)
     acc_t = jnp.float32 if mm_dtype == jnp.bfloat16 else jnp.int32
     acc = jnp.dot(b_ref[:], bits, preferred_element_type=acc_t)
     pbits = acc.astype(jnp.int32) & 1
@@ -143,31 +155,57 @@ def _rs_crc_kernel(b_ref, d_ref, w0_ref, pl_ref, pm_ref, o_ref, c_ref, *,
         out = out | (pbits[s * out_rows:(s + 1) * out_rows] << s)
     o_ref[:] = out.astype(jnp.uint8)
 
-    w0 = w0_ref[:]          # (BN, 32)
-    pm = pm_ref[:]          # (32, 32) — position shift, transposed
+    # CRC fold, always bf16 x bf16 -> f32 (exact: operands are 0/1 and
+    # every sum is <= BN < 2^24).  Planes are re-unpacked from the
+    # stacked (data, parity, zero pad) rows so each plane is `rpad`
+    # rows — the parity matmul's planes are in_rows apart, which no
+    # tile boundary divides.
+    rows = in_rows + out_rows
+    rpad = c_ref.shape[0]
+    stacked = [x, out]
+    if rpad > rows:
+        stacked.append(jnp.zeros((rpad - rows, x.shape[1]), jnp.int32))
+    y = jnp.concatenate(stacked, axis=0)
+    planes = jnp.concatenate(
+        [(y >> s) & 1 for s in range(8)], axis=0).astype(jnp.bfloat16)
+    u = jnp.dot(planes, w0_ref[:], preferred_element_type=jnp.float32)
+    ub = u.astype(jnp.int32) & 1            # (8 * rpad, 128)
+    fold = jnp.zeros((rpad, _CRC_LANES), jnp.float32)
+    for s in range(8):
+        fold = fold + jnp.dot(
+            ub[s * rpad:(s + 1) * rpad].astype(jnp.bfloat16),
+            pl_ref[s * _CRC_LANES:(s + 1) * _CRC_LANES],
+            preferred_element_type=jnp.float32)
+    vb = (fold.astype(jnp.int32) & 1).astype(jnp.bfloat16)
+    sh = jnp.dot(vb, pm_ref[:], preferred_element_type=jnp.float32) \
+        .astype(jnp.int32) & 1
 
-    def row_crcs(plane_bits, rows):
-        # (8*rows, BN) plane-major 0/1 -> (rows, 1) uint32 partial
-        u = jnp.dot(plane_bits, w0, preferred_element_type=acc_t)
-        ub = (u.astype(jnp.int32) & 1).astype(mm_dtype)
-        fold = jnp.zeros((rows, 32), acc_t)
-        for s in range(8):
-            fold = fold + jnp.dot(
-                ub[s * rows:(s + 1) * rows],
-                pl_ref[s * 32:(s + 1) * 32],
-                preferred_element_type=acc_t)
-        vb = (fold.astype(jnp.int32) & 1).astype(mm_dtype)
-        sh = jnp.dot(vb, pm, preferred_element_type=acc_t) \
-            .astype(jnp.int32) & 1
-        w = jnp.left_shift(
-            jnp.uint32(1),
-            jax.lax.broadcasted_iota(jnp.uint32, (1, 32), 1))
-        return jnp.sum(sh.astype(jnp.uint32) * w, axis=1,
-                       keepdims=True, dtype=jnp.uint32)
+    @pl.when(pl.program_id(0) % tpb == 0)
+    def _():
+        c_ref[:] = jnp.zeros_like(c_ref)
 
-    c_ref[:] = jnp.concatenate(
-        [row_crcs(bits, in_rows),
-         row_crcs(pbits.astype(mm_dtype), out_rows)], axis=0)
+    c_ref[:] = c_ref[:] + sh
+
+
+def _pad_crc_const(mat: np.ndarray) -> np.ndarray:
+    """Zero-pad each of the k stacked (32, 32) CRC matrices of a
+    (k*32, 32) table to a full (128, 128) tile."""
+    k = mat.shape[0] // 32
+    out = np.zeros((k, _CRC_LANES, _CRC_LANES), mat.dtype)
+    out[:, :32, :32] = mat.reshape(k, 32, 32)
+    return out.reshape(-1, _CRC_LANES)
+
+
+def crc_kernel_consts(block_n: int, crc_block: int = crc_fold.BLOCK):
+    """Device constants for `apply_bitmatrix_crc_pallas` at one
+    (block_n, crc_block) geometry: (w0 (block_n, 128), planes_t
+    (8*128, 128), posmats_t (tpb*128, 128)), bf16."""
+    t = crc_fold.tables(block_n, crc_block)
+    w0 = np.zeros((block_n, _CRC_LANES), np.uint8)
+    w0[:, :32] = t.w0
+    return (jnp.asarray(w0, jnp.bfloat16),
+            jnp.asarray(_pad_crc_const(t.planes_t), jnp.bfloat16),
+            jnp.asarray(_pad_crc_const(t.posmats_t), jnp.bfloat16))
 
 
 @functools.partial(jax.jit,
@@ -182,22 +220,29 @@ def apply_bitmatrix_crc_pallas(bmat_pm: jax.Array, shards: jax.Array,
                                mm: str = "bf16",
                                crc_block: int = crc_fold.BLOCK):
     """apply_bitmatrix_pallas plus fused `.ecc` CRC32-C: returns
-    (parity (out_rows, n) uint8, crc tile partials
-    (in_rows + out_rows, n // block_n) uint32).
+    (parity (out_rows, n) uint8, crcs (in_rows + out_rows,
+    n // crc_block) uint32) — the crc32c of every `.ecc` block of every
+    shard row, data rows first, then parity rows.
 
-    The partials are position-shifted: XOR-ing the `crc_block //
-    block_n` partials of one `.ecc` block and XOR-ing the zero-block
-    constant yields the actual crc32c of that block
-    (crc_fold.block_crcs_from_partials / FusedCrcAccumulator).
-    The input must start on a `.ecc` block boundary.
+    n must be a multiple of crc_block and the input must start on a
+    `.ecc` block boundary; w0/planes_t/posmats_t come from
+    `crc_kernel_consts(block_n, crc_block)`.
     """
     n = shards.shape[1]
+    if n % crc_block or crc_block % block_n:
+        raise ValueError(
+            f"width {n} must be a multiple of the .ecc block "
+            f"{crc_block}, itself a multiple of block_n {block_n}")
     grid = (n // block_n,)
     tpb = crc_block // block_n
+    nb = n // crc_block
+    rows = in_rows + out_rows
+    rpad = -(-rows // _CRC_ROW_ALIGN) * _CRC_ROW_ALIGN
     mm_dtype = jnp.bfloat16 if mm == "bf16" else jnp.int8
     kernel = functools.partial(_rs_crc_kernel, out_rows=out_rows,
-                               in_rows=in_rows, mm_dtype=mm_dtype)
-    return pl.pallas_call(
+                               in_rows=in_rows, mm_dtype=mm_dtype,
+                               tpb=tpb)
+    parity, counts = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -205,23 +250,25 @@ def apply_bitmatrix_crc_pallas(bmat_pm: jax.Array, shards: jax.Array,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((in_rows, block_n), lambda i: (0, i),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((block_n, 32), lambda i: (0, 0),
+            pl.BlockSpec((block_n, _CRC_LANES), lambda i: (0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((8 * 32, 32), lambda i: (0, 0),
+            pl.BlockSpec((8 * _CRC_LANES, _CRC_LANES), lambda i: (0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((32, 32), lambda i: (i % tpb, 0),
+            pl.BlockSpec((_CRC_LANES, _CRC_LANES),
+                         lambda i: (i % tpb, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=[
             pl.BlockSpec((out_rows, block_n), lambda i: (0, i),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((in_rows + out_rows, 1), lambda i: (0, i),
+            # One accumulator block per `.ecc` block: its index holds
+            # still for tpb consecutive grid steps.
+            pl.BlockSpec((rpad, _CRC_LANES), lambda i: (i // tpb, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((out_rows, n), jnp.uint8),
-            jax.ShapeDtypeStruct((in_rows + out_rows, n // block_n),
-                                 jnp.uint32),
+            jax.ShapeDtypeStruct((nb * rpad, _CRC_LANES), jnp.int32),
         ],
         cost_estimate=pl.CostEstimate(
             flops=2 * 8 * out_rows * 8 * in_rows * n
@@ -230,8 +277,13 @@ def apply_bitmatrix_crc_pallas(bmat_pm: jax.Array, shards: jax.Array,
             transcendentals=0,
         ),
         interpret=interpret,
-    )(bmat_pm.astype(mm_dtype), shards, w0.astype(mm_dtype),
-      planes_t.astype(mm_dtype), posmats_t.astype(mm_dtype))
+    )(bmat_pm.astype(mm_dtype), shards, w0, planes_t, posmats_t)
+    bits = counts.reshape(nb, rpad, _CRC_LANES)[:, :rows, :32] & 1
+    words = jnp.sum(
+        bits.astype(jnp.uint32) << jnp.arange(32, dtype=jnp.uint32),
+        axis=2, dtype=jnp.uint32)
+    const = crc_fold.tables(block_n, crc_block).block_const
+    return parity, words.T ^ jnp.uint32(const)
 
 
 def pad_to_block(n: int, block_n: int = BLOCK_N) -> int:
@@ -239,17 +291,16 @@ def pad_to_block(n: int, block_n: int = BLOCK_N) -> int:
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:
-        return False
+    """Whether JAX resolved to a TPU.  Discovery errors propagate: a
+    TPU that fails to initialise must not turn into interpret mode."""
+    return jaxenv.platform() == "tpu"
 
 
 class PallasCoder:
     """RS coder whose byte mixing runs in the fused Pallas kernel.
 
-    Off-TPU (tests on the virtual CPU mesh) the kernel runs in interpreter
-    mode unless `interpret=False` is forced.
+    On the CPU platform (tests on the virtual CPU mesh) the kernel runs
+    in interpreter mode unless `interpret=False` is forced.
     """
 
     def __init__(self, data_shards: int = 10, parity_shards: int = 4,
@@ -257,17 +308,14 @@ class PallasCoder:
                  interpret: bool | None = None,
                  block_n: int | None = None, mm: str | None = None,
                  codec=None):
-        import os
-
         from ..codecs import get_codec, rs_codec
         from .coder_jax import plane_major
 
         self.block_n = block_n or int(
             os.environ.get("SEAWEEDFS_TPU_BLOCK_N", BLOCK_N))
-        # int8 is the measured on-TPU winner (BENCH_r05: 22.5 GB/s
-        # round-trip vs 21.0 for bf16) and exact for 0/1 bit planes
-        # (int32 accumulation; correctness-gated vs NumpyCoder in
-        # tests/test_ecpipe.py).  bf16 stays the off-TPU default.
+        # int8 is exact for 0/1 bit planes (int32 accumulation;
+        # correctness-gated vs NumpyCoder in tests/test_ecpipe.py) and
+        # the MXU's faster dtype; bf16 stays the off-TPU default.
         self.mm = mm or os.environ.get("SEAWEEDFS_TPU_MM") \
             or ("int8" if _on_tpu() else "bf16")
         self.codec = rs_codec(data_shards, parity_shards, matrix_kind) \
@@ -276,12 +324,14 @@ class PallasCoder:
         self.parity_shards = self.codec.parity_shards
         self.total_shards = self.codec.total_shards
         self.matrix_kind = self.codec.matrix_kind
-        self.interpret = (not _on_tpu()) if interpret is None else interpret
+        self.interpret = (jaxenv.platform() == "cpu") \
+            if interpret is None else interpret
         self._plane_major = plane_major
         pb = self.codec.parity_bitmatrix()
         self._parity_pm = jnp.asarray(
             plane_major(pb, self.parity_shards, self.data_shards),
             jnp.bfloat16)
+        self._crc_consts = None
 
     def _apply(self, mat_pm: jax.Array, shards: jax.Array,
                out_rows: int) -> jax.Array:
@@ -299,19 +349,20 @@ class PallasCoder:
 
     @property
     def fused_crc_ok(self) -> bool:
-        """True when this coder can emit `.ecc` CRC32-C tile partials
-        fused into the encode kernel (ops/crc_fold.py): the kernel tile
-        must evenly divide the sidecar block."""
+        """True when this coder can emit `.ecc` CRC32-C values fused
+        into the encode kernel (ops/crc_fold.py): the kernel tile must
+        evenly divide the sidecar block."""
         return crc_fold.BLOCK % self.block_n == 0
 
     def encode_with_crc(self, data) -> tuple[jax.Array, jax.Array]:
-        """Encode AND emit `.ecc` CRC tile partials in one fused kernel.
+        """Encode AND checksum in one fused kernel.
 
-        Returns (parity (p, n) uint8, partials (k + p, padded_n //
-        block_n) uint32) — rows ordered data shards then parity shards,
-        exactly the shard-file order.  Feed the partials to
-        crc_fold.FusedCrcAccumulator; `data` must start block-aligned
-        in its shard files (the encoder's chunks do).
+        Returns (parity (p, n) uint8, crcs (k + p, n // BLOCK) uint32)
+        — the crc32c of every `.ecc` block of every shard row, rows
+        ordered data shards then parity shards, exactly the shard-file
+        order.  n must be a multiple of the `.ecc` block and `data`
+        must start block-aligned in its shard files (the encoder's
+        chunks are and do).
         """
         if not self.fused_crc_ok:
             raise ValueError(
@@ -322,42 +373,29 @@ class PallasCoder:
             raise ValueError(
                 f"expected {self.data_shards} data shards, "
                 f"got {data.shape[0]}")
-        t = crc_fold.tables(self.block_n)
-        consts = getattr(self, "_crc_consts", None)
-        if consts is None:
-            consts = self._crc_consts = (
-                jnp.asarray(t.w0), jnp.asarray(t.planes_t),
-                jnp.asarray(t.posmats_t))
-        n = data.shape[1]
-        padded = pad_to_block(n, self.block_n)
-        if padded != n:
-            data = jnp.pad(data, ((0, 0), (0, padded - n)))
-        if not _prof_on():
-            parity, partials = apply_bitmatrix_crc_pallas(
-                self._parity_pm, data, *consts, self.parity_shards,
-                self.data_shards, interpret=self.interpret,
-                block_n=self.block_n, mm=self.mm)
-            return parity[:, :n], partials
-        # Execution-fenced wall (fencing audit: this leg used to
-        # return unfenced async handles with no timing at all — a
-        # dispatch-only wall would flatter the fused kernel).
+        if self._crc_consts is None:
+            self._crc_consts = crc_kernel_consts(self.block_n)
+        n = int(data.shape[1])
         t0 = time.perf_counter()
-        parity, partials = apply_bitmatrix_crc_pallas(
-            self._parity_pm, data, *consts, self.parity_shards,
-            self.data_shards, interpret=self.interpret,
-            block_n=self.block_n, mm=self.mm)
-        parity = jax.block_until_ready(parity)
-        partials = jax.block_until_ready(partials)
+        parity, crcs = apply_bitmatrix_crc_pallas(
+            self._parity_pm, data, *self._crc_consts,
+            self.parity_shards, self.data_shards,
+            interpret=self.interpret, block_n=self.block_n, mm=self.mm)
+        if not _prof_on():
+            return parity, crcs
+        # Execution-fenced wall: a dispatch-only wall would flatter
+        # the fused kernel.
+        parity, crcs = jax.block_until_ready((parity, crcs))
         dt = time.perf_counter() - t0
         observe_ec_stage("encode_crc_kernel", dt, self.data_shards * n)
         if _roofline.ARMED:
             _record_roofline(
                 "encode_crc_kernel", self,
                 out_rows=self.parity_shards, in_rows=self.data_shards,
-                n=int(n), crc=True, seconds=dt,
+                n=n, crc=True, seconds=dt,
                 measured_bytes=(self.data_shards
-                                + self.parity_shards) * int(n))
-        return parity[:, :n], partials
+                                + self.parity_shards) * n)
+        return parity, crcs
 
     def encode(self, data) -> jax.Array:
         data = jnp.asarray(data, jnp.uint8)

@@ -34,7 +34,9 @@ fixups instead of a 32 x 8T monster:
 
 The actual crc32c of a block is then CONST(block) ^ packed_bits, where
 CONST(block) = crc32c of `block` zero bytes (the affine part the
-inversions introduce).
+inversions introduce).  Both device paths return that actual value per
+`.ecc` block: the Pallas kernel sums the position-shifted partials of a
+block's tiles in VMEM, the jnp path contracts them in one einsum.
 
 Everything here is probed numerically from ``core.crc.crc32c`` — the
 tables are correct by construction against the reference
@@ -59,16 +61,16 @@ def fused_crc_enabled() -> bool:
     """Whether the fused-CRC paths (local `write_ec_files`, batch
     encode, batch rebuild) are active.  `SEAWEEDFS_TPU_EC_FUSED_CRC`
     overrides in either direction (`0`/`false` reverts to the CPU byte
-    accumulators end to end, `1` forces fused).  Unset, the default is
-    platform-gated like the int8 mm choice (coder_pallas._on_tpu): ON
-    where the matmul is free MXU work, OFF on the CPU backend where the
-    bench measured the same einsum as costing more than the native
-    crc32c pass it replaces (bench_e2e.py)."""
+    accumulators end to end, `1` forces fused).  Unset, the default
+    follows the platform JAX resolved, like the int8 mm choice: ON on a
+    TPU, where the fold is MXU work beside the parity matmul, OFF on
+    the CPU backend where the same einsum costs more than the native
+    crc32c pass it replaces."""
     env = os.environ.get("SEAWEEDFS_TPU_EC_FUSED_CRC")
     if env is not None:
         return env not in ("0", "false")
-    from .coder_pallas import _on_tpu
-    return _on_tpu()
+    from ..utils import jaxenv
+    return jaxenv.platform() == "tpu"
 
 # `.ecc` checksum granularity (ec/integrity.BLOCK re-derived here to
 # avoid an import cycle; asserted equal in tests).
@@ -201,8 +203,8 @@ def tables(tile_n: int, block: int = BLOCK) -> CrcFoldTables:
 
 
 # ---------------------------------------------------------------------------
-# Reference (numpy) tile partials — the oracle the kernel is tested
-# against, and the host-side fallback combiner's building block.
+# Reference (numpy) tile partials — the oracle the kernel algebra is
+# tested against.
 # ---------------------------------------------------------------------------
 
 def tile_partials_np(rows: np.ndarray, tile_n: int,
@@ -313,55 +315,3 @@ def block_crcs_jnp(rows, tile_n: int = JNP_TILE, block: int = BLOCK):
     packed = jnp.sum(blockbits.astype(jnp.uint32) * weights, axis=2,
                      dtype=jnp.uint32)
     return packed ^ jnp.uint32(const)
-
-
-# ---------------------------------------------------------------------------
-# Host-side streaming combiner — consumes kernel tile partials chunk by
-# chunk (plus optional ragged byte tails) and emits the same list of
-# per-block CRCs BlockCrcAccumulator would have produced.
-# ---------------------------------------------------------------------------
-
-class FusedCrcAccumulator:
-    """Per-shard-row `.ecc` accumulator fed from kernel outputs.
-
-    ``feed_tiles(partials, width)`` consumes position-shifted tile
-    partials covering `width` bytes (width % block == 0, and the stream
-    must be block-aligned — i.e. no byte tail pending).
-    ``feed_bytes(buf)`` is the CPU fallback for ragged chunks/tails;
-    both may be mixed as long as tile feeds land on block boundaries.
-    ``finalize()`` matches BlockCrcAccumulator.finalize() bit for bit.
-    """
-
-    def __init__(self, tile_n: int, block: int = BLOCK):
-        self.tile_n = tile_n
-        self.block = block
-        self._crcs: list[int] = []
-        self._cur = 0
-        self._fill = 0
-
-    def feed_tiles(self, partials, width: int) -> None:
-        if self._fill:
-            raise ValueError(
-                "feed_tiles on a non-block-aligned stream "
-                f"(pending tail of {self._fill} bytes)")
-        self._crcs.extend(block_crcs_from_partials(
-            partials, width, self.tile_n, self.block))
-
-    def feed_bytes(self, buf) -> None:
-        mv = memoryview(buf)
-        while len(mv):
-            take = min(self.block - self._fill, len(mv))
-            self._cur = crc32c(bytes(mv[:take]), self._cur)
-            self._fill += take
-            mv = mv[take:]
-            if self._fill == self.block:
-                self._crcs.append(self._cur)
-                self._cur = 0
-                self._fill = 0
-
-    def finalize(self) -> list[int]:
-        if self._fill:
-            self._crcs.append(self._cur)
-            self._cur = 0
-            self._fill = 0
-        return list(self._crcs)

@@ -5,13 +5,14 @@ The reference hides klauspost/reedsolomon behind direct calls in
 interface seam that picks a backend at startup.  Backends:
 
 - "numpy":  table-lookup oracle (always available, slow)
-- "native": C++ AVX2 PSHUFB kernels (klauspost-class CPU path; needs
-            `make -C native`)
+- "native": C++ AVX2 PSHUFB kernels (klauspost-class CPU path; built
+            from native/ on first use, utils/native.py)
 - "jax":    XLA bit-sliced matmul (any jax backend)
 - "pallas": fused MXU kernel (TPU; interpreter mode elsewhere)
 
 Selection: SEAWEEDFS_TPU_CODER env var, else pallas on TPU, else native
-if built, else jax.
+if built, else jax (`default_backend`; no fallback when the device
+cannot be reached).
 All backends share the same API: encode / encode_all / reconstruct / verify,
 operating on (shards, n) uint8 arrays; results are byte-identical.
 """
@@ -45,21 +46,36 @@ def _native_available() -> bool:
 
 
 def default_backend() -> str:
+    """The coder this process uses: the SEAWEEDFS_TPU_CODER override,
+    else pallas when JAX resolves to a TPU, else native if built, else
+    jax.  Asking initialises the JAX backend (on a TPU host that claims
+    the chip — utils/jaxenv.py has the cluster -> chip map) and a
+    backend that cannot initialise raises: a process meant to own a
+    chip must not quietly code on the CPU instead."""
     env = os.environ.get("SEAWEEDFS_TPU_CODER")
     if env:
         if env not in _BACKENDS:
             raise ValueError(
                 f"SEAWEEDFS_TPU_CODER={env!r}; expected one of {_BACKENDS}")
         return env
-    try:
-        import jax
-        if jax.devices()[0].platform == "tpu":
-            return "pallas"
-        if _native_available():
-            return "native"
-        return "jax"
-    except Exception:
-        return "native" if _native_available() else "numpy"
+    from ..utils import jaxenv
+    if jaxenv.platform() == "tpu":
+        return "pallas"
+    return "native" if _native_available() else "jax"
+
+
+def describe_backend() -> str:
+    """One start-up log line's worth: the coder backend and the devices
+    JAX resolved behind it.  Resolves (and so claims) the device like
+    the first EC call would."""
+    backend = default_backend()
+    if backend in ("numpy", "native") \
+            and os.environ.get("SEAWEEDFS_TPU_CODER"):
+        return f"coder={backend} (SEAWEEDFS_TPU_CODER; no JAX device)"
+    from ..utils import jaxenv
+    d = jaxenv.device_summary()
+    return (f"coder={backend} platform={d['platform']} "
+            f"device_kind={d['device_kind']!r} devices={d['count']}")
 
 
 def new_coder(data_shards: int = 10, parity_shards: int = 4,

@@ -30,25 +30,16 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops import rs_bitmatrix
 from ..ops.coder_jax import apply_bitmatrix, plane_major
-
-# jax.shard_map landed as a top-level API after 0.4.x; on the 0.4
-# toolchain the same function lives under jax.experimental.shard_map.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover — exercised on the 0.4.x image
-    from jax.experimental.shard_map import shard_map as _shard_map
+from ..utils import jaxenv
 
 
 def _mm_dtype():
     """Bit-matrix matmul dtype for the batch paths: bf16 feeds the MXU
     on TPU; off-TPU, XLA emulates bf16 slowly in software while f32 is
     exactly as correct for 0/1 bit planes (counts < 2^24 accumulate
-    exactly either way) and measured ~1.7x faster on the CPU backend."""
-    try:
-        platform = jax.devices()[0].platform
-    except Exception:  # pragma: no cover — backend init failure
-        platform = "cpu"
-    return jnp.bfloat16 if platform == "tpu" else jnp.float32
+    exactly either way) and measured ~1.7x faster on the CPU backend.
+    A backend that cannot initialise raises here."""
+    return jnp.bfloat16 if jaxenv.platform() == "tpu" else jnp.float32
 
 
 def mm_name() -> str:
@@ -117,26 +108,18 @@ def _local_map(fn, mesh: Mesh):
     matrix rides along replicated, data shards over volumes/columns.
     Every chip computes ONLY its own volume/column block — by
     construction there are ZERO collectives in the lowered program
-    (asserted by tests/test_ecpipe.py on the compiled HLO).  check_rep
-    is off: no output claims replication, and the 0.4.x rep-rewriter
-    chokes on jitted decode matrices.
+    (asserted by tests/test_ecpipe.py on the compiled HLO).  check_vma
+    stays on: every output is sharded over both axes, which the check
+    confirms at trace time for free.
 
     Callers MUST route through the `_mapped_*` lru_cached factories
     below (never wrap a fresh closure per call): jax.jit caches by
     callable identity, so an uncached wrapper would retrace + XLA
     compile on EVERY dispatched chunk batch of the stream pipeline."""
-    try:
-        mapped = _shard_map(fn, mesh=mesh,
-                            in_specs=(P(None, None),
-                                      P("vol", None, "col")),
-                            out_specs=P("vol", None, "col"),
-                            check_rep=False)
-    except TypeError:  # pragma: no cover — newer API dropped check_rep
-        mapped = _shard_map(fn, mesh=mesh,
-                            in_specs=(P(None, None),
-                                      P("vol", None, "col")),
-                            out_specs=P("vol", None, "col"))
-    return jax.jit(mapped)
+    return jax.jit(jax.shard_map(
+        fn, mesh=mesh,
+        in_specs=(P(None, None), P("vol", None, "col")),
+        out_specs=P("vol", None, "col"), check_vma=True))
 
 
 @functools.lru_cache(maxsize=64)
@@ -388,7 +371,7 @@ def all_to_all_reconstruct(stacked, present: tuple[int, ...],
             lambda x: apply_bitmatrix(pm, x, wanted_count))(gathered)
         return out  # (v_loc, wanted, chunk) — column-sharded result
 
-    fn = jax.jit(_shard_map(
+    fn = jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=P("vol", "col", None),
         out_specs=P("vol", None, "col")))
@@ -458,7 +441,7 @@ def ring_reconstruct(stacked, present: tuple[int, ...],
         acc = jax.lax.fori_loop(1, n_ring, step, acc)
         return acc  # chip d holds the reduced chunk d
 
-    fn = jax.jit(_shard_map(
+    fn = jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=P("vol", "col", None),
         out_specs=P("vol", None, "col")))

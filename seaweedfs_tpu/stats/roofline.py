@@ -221,8 +221,8 @@ def _cache_dir() -> str:
     d = os.environ.get("SEAWEEDFS_TPU_ROOFLINE_CACHE", "")
     if d:
         return d
-    return os.path.join(os.path.expanduser("~"), ".cache",
-                        "seaweedfs_tpu")
+    from ..utils import jaxenv
+    return os.path.join(jaxenv.cache_root(), "roofline")
 
 
 def _cache_path(backend: str, kind: str) -> str:
@@ -574,6 +574,12 @@ class RooflineLedger:
         return {"kernels": self.kernel_table(),
                 "occupancy": self.occupancy_summary()}
 
+    def has_rows(self) -> bool:
+        """Whether this process has recorded any kernel — i.e. has a
+        live JAX backend of its own."""
+        with self._lock:
+            return bool(self._series)
+
     def reset(self) -> None:
         with self._lock:
             self._ring.clear()
@@ -592,25 +598,29 @@ def _rq(sketch, q: float):
 LEDGER = RooflineLedger()
 
 
+def local_peaks() -> dict | None:
+    """Peaks of THIS process's device, or None when it has run no
+    kernel: probing initialises the JAX backend, and a role that owns
+    no chip (utils/jaxenv.py) must not claim one to answer a GET."""
+    return probe_peaks() if LEDGER.has_rows() else None
+
+
 def _device_memory_stats() -> list[dict]:
-    """jax.local_devices() memory stats, best-effort (CPU backends
-    usually expose nothing)."""
+    """jax.local_devices() with memory stats where the backend reports
+    them; empty for a process that has run no kernel (see
+    `local_peaks`)."""
+    if not LEDGER.has_rows():
+        return []
+    import jax
     out = []
-    try:
-        import jax
-        for d in jax.local_devices():
-            row = {"id": d.id, "kind": d.device_kind,
-                   "platform": d.platform}
-            try:
-                ms = d.memory_stats()
-                if ms:
-                    row["bytes_in_use"] = ms.get("bytes_in_use")
-                    row["bytes_limit"] = ms.get("bytes_limit")
-            except Exception:  # noqa: BLE001 — not all backends
-                pass
-            out.append(row)
-    except Exception:  # noqa: BLE001 — no jax, no rows
-        pass
+    for d in jax.local_devices():
+        row = {"id": d.id, "kind": d.device_kind,
+               "platform": d.platform}
+        ms = d.memory_stats()   # None on backends without stats
+        if ms:
+            row["bytes_in_use"] = ms.get("bytes_in_use")
+            row["bytes_limit"] = ms.get("bytes_limit")
+        out.append(row)
     return out
 
 
@@ -620,7 +630,7 @@ def debug_doc(node: str, role: str) -> dict:
     bubble attribution, the conservation verdict, and device memory
     stats."""
     return {"node": node, "role": role, "armed": ARMED,
-            "peaks": probe_peaks(),
+            "peaks": local_peaks(),
             "kernels": LEDGER.kernel_table(),
             "recent": LEDGER.recent(16),
             "pipelines": LEDGER.pipelines(4),

@@ -1,12 +1,14 @@
-"""ctypes loader for the optional C++ host-side library (native/).
+"""ctypes loader for the C++ host-side library (native/).
 
 The native library accelerates host-path hot spots the way the reference
 leans on Go-assembly SIMD (klauspost/crc32, klauspost/reedsolomon):
-CRC32-C, GF(2^8) encode for the CPU fallback path, and needle scanning.
-Pure-Python fallbacks exist for every entry point; everything degrades
-gracefully when the library hasn't been built.
+CRC32-C, GF(2^8) encode for the CPU coder, and needle scanning.
+Pure-Python fallbacks exist for every entry point.
 
-Build: `make -C native` (produces native/libseaweed_native.so).
+The shared object is a build product, not a tracked file: `load()`
+runs `make -C native` (the Makefile owns the flags) when it is absent
+or older than its source.  Without a compiler the build fails, that is
+logged once with the compiler's error, and callers get None.
 """
 
 from __future__ import annotations
@@ -14,25 +16,58 @@ from __future__ import annotations
 import ctypes
 import functools
 import os
+import subprocess
 
-_LIB_NAMES = ("libseaweed_native.so",)
+from . import glog
+
+_NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))), "native")
+_LIB = "libseaweed_native.so"
+_SRC = "seaweed_native.cpp"
+
+
+def _build(lib: str) -> None:
+    """`make` into a private name, then rename into place: several
+    processes may start at once, and none may dlopen a half-written
+    file."""
+    tmp = f"{_LIB}.{os.getpid()}.tmp"
+    try:
+        subprocess.run(["make", "-C", _NATIVE_DIR, "-s", f"TARGET={tmp}"],
+                       check=True, capture_output=True, text=True,
+                       timeout=300)
+        os.replace(os.path.join(_NATIVE_DIR, tmp), lib)
+    finally:
+        try:
+            os.unlink(os.path.join(_NATIVE_DIR, tmp))
+        except FileNotFoundError:
+            pass
 
 
 @functools.lru_cache(maxsize=1)
 def load() -> ctypes.CDLL | None:
+    """The native library (its path is `load()._name`), built first if
+    need be; None when it cannot be built or loaded."""
     override = os.environ.get("SEAWEEDFS_TPU_NATIVE_LIB")
-    candidates = [override] if override else []
-    here = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    for name in _LIB_NAMES:
-        candidates.append(os.path.join(here, "native", name))
-    for path in candidates:
-        if path and os.path.exists(path):
-            try:
-                return ctypes.CDLL(path)
-            except OSError:
-                continue
-    return None
+    lib = override or os.path.join(_NATIVE_DIR, _LIB)
+    src = os.path.join(_NATIVE_DIR, _SRC)
+    if not override and os.path.exists(src) and (
+            not os.path.exists(lib)
+            or os.path.getmtime(lib) < os.path.getmtime(src)):
+        try:
+            _build(lib)
+        except subprocess.CalledProcessError as e:
+            glog.warningf("native library build failed (pure-Python "
+                          "fallbacks in use):\n%s", e.stderr.strip())
+        except (OSError, subprocess.TimeoutExpired) as e:
+            glog.warningf("native library build could not run (pure-"
+                          "Python fallbacks in use): %s", e)
+    if not os.path.exists(lib):
+        return None
+    try:
+        return ctypes.CDLL(lib)
+    except OSError as e:
+        glog.warningf("native library %s does not load: %s", lib, e)
+        return None
 
 
 def crc32c_fn(lib: ctypes.CDLL):

@@ -63,51 +63,27 @@ def test_crc_fold_matches_reference_blocks():
           for b in range(3)] for r in range(3)]
 
 
-def test_fused_accumulator_final_partial_block():
-    """feed_tiles for the aligned body + feed_bytes for a ragged tail
-    must reproduce BlockCrcAccumulator.finalize() bit for bit,
-    including the final partial block."""
-    rng = np.random.default_rng(1)
-    tile, block = 512, 4096
-    body = rng.integers(0, 256, (1, 2 * block), dtype=np.uint8)
-    tail = rng.integers(0, 256, block // 3, dtype=np.uint8).tobytes()
-    parts = crc_fold.tile_partials_np(body, tile, block)
-    acc = crc_fold.FusedCrcAccumulator(tile, block)
-    acc.feed_tiles(parts[0], 2 * block)
-    acc.feed_bytes(tail)
-    want = [crc32c(body[0, :block].tobytes()),
-            crc32c(body[0, block:].tobytes()), crc32c(tail)]
-    assert acc.finalize() == want
-    # tiles after a pending tail must refuse (never silently misalign)
-    acc2 = crc_fold.FusedCrcAccumulator(tile, block)
-    acc2.feed_bytes(b"x")
-    with pytest.raises(ValueError):
-        acc2.feed_tiles(parts[0], block)
-
-
 @pytest.mark.parametrize("codec", ["rs", "lrc"])
 @pytest.mark.parametrize("mm", ["bf16", "int8"])
 def test_fused_kernel_crcs_bit_exact(codec, mm):
-    """The Pallas kernel's second output folds to the exact crc32c of
-    every `.ecc` block of every shard row — data and parity — with a
-    ragged tail handled by the CPU fallback."""
+    """The Pallas kernel's second output is the exact crc32c of every
+    `.ecc` block of every shard row — data and parity — and a width
+    that is not a whole number of blocks is refused."""
     rng = np.random.default_rng(2)
-    n = 2 * BLOCK + 4096  # two full blocks + a partial tail
+    n = 2 * BLOCK
     data = rng.integers(0, 256, (10, n), dtype=np.uint8)
     coder = PallasCoder(block_n=4096, mm=mm, codec=codec)
     assert coder.fused_crc_ok
-    parity, parts = coder.encode_with_crc(data)
-    parity, parts = np.asarray(parity), np.asarray(parts)
+    parity, crcs = coder.encode_with_crc(data)
+    parity, crcs = np.asarray(parity), np.asarray(crcs)
     assert np.array_equal(parity, NumpyCoder(codec=codec).encode(data))
+    assert crcs.dtype == np.uint32 and crcs.shape == (14, 2)
     rows = np.concatenate([data, parity], axis=0)
-    for r in range(rows.shape[0]):
-        acc = crc_fold.FusedCrcAccumulator(coder.block_n)
-        acc.feed_tiles(parts[r], 2 * BLOCK)
-        acc.feed_bytes(rows[r, 2 * BLOCK:].tobytes())
-        want = [crc32c(rows[r, b * BLOCK:(b + 1) * BLOCK].tobytes())
-                for b in range(2)] + [crc32c(rows[r, 2 * BLOCK:]
-                                             .tobytes())]
-        assert acc.finalize() == want, f"row {r}"
+    want = [[crc32c(rows[r, b * BLOCK:(b + 1) * BLOCK].tobytes())
+             for b in range(2)] for r in range(rows.shape[0])]
+    assert crcs.tolist() == want
+    with pytest.raises(ValueError):
+        coder.encode_with_crc(data[:, :BLOCK + 4096])
 
 
 def test_int8_mm_correctness_gate():

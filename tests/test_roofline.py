@@ -30,7 +30,7 @@ from seaweedfs_tpu.cluster import rpc
 from seaweedfs_tpu.cluster.master import MasterServer
 from seaweedfs_tpu.cluster.volume_server import VolumeServer
 from seaweedfs_tpu.events.journal import JOURNAL
-from seaweedfs_tpu.ops import coder_pallas
+from seaweedfs_tpu.ops import coder_pallas, crc_fold
 from seaweedfs_tpu.ops.coder_pallas import PallasCoder
 from seaweedfs_tpu.parallel.stream_pipeline import PipelineRecorder
 from seaweedfs_tpu.shell import CommandEnv, run_command
@@ -221,7 +221,8 @@ def test_real_encode_records_and_conserves(fake_peaks):
         data = np.arange(4 * 2048, dtype=np.uint8).reshape(4, 2048)
         parity = np.asarray(pc.encode(data))
         assert parity.shape == (2, 2048)
-        pc.encode_with_crc(data)
+        # the fused kernel takes whole `.ecc` blocks only
+        pc.encode_with_crc(np.resize(data, (4, crc_fold.BLOCK)))
         shards = {i: data[i] for i in range(4)}
         shards[4] = parity[0]
         pc.reconstruct({k: v for k, v in shards.items() if k != 0},
@@ -251,7 +252,7 @@ def test_disarmed_path_is_one_flag_check(monkeypatch):
         data = np.ones((4, 1024), np.uint8)
         out = np.asarray(pc.encode(data))
         assert out.shape == (2, 1024)
-        pc.encode_with_crc(data)
+        pc.encode_with_crc(np.ones((4, crc_fold.BLOCK), np.uint8))
     finally:
         roofline.set_armed(True)
 

@@ -172,3 +172,35 @@ def test_server_ignores_truncated_request():
         assert hits == []
     finally:
         server.stop()
+
+
+def test_whole_pool_reaped_by_server_idle_timeout_still_delivers_a_post():
+    """A client that sat out the server's idle timeout holds a pool of
+    dead keep-alives (found on the chip: minutes inside one XLA compile
+    between the seal's scatter and the rebuild's).  The one-shot stale
+    retry must dial fresh — handing it the next pooled corpse failed
+    the POST with BrokenPipe."""
+    import time
+    from concurrent.futures import ThreadPoolExecutor
+
+    server = rpc.JsonHttpServer(idle_timeout=0.3)
+    server.route("POST", "/echo", lambda q, b: {"n": len(b)})
+    server.start()
+    url = f"http://127.0.0.1:{server.port}/echo"
+    gate = threading.Barrier(4)
+
+    def one(_):
+        gate.wait(timeout=10)       # four conns open at once
+        return rpc.call(url, "POST", b"x", timeout=5.0)
+    try:
+        with ThreadPoolExecutor(4) as ex:
+            assert list(ex.map(one, range(4))) == [{"n": 1}] * 4
+        key = ("http", "127.0.0.1", server.port)
+        assert len(rpc._pool._idle.get(key, [])) >= 2
+        time.sleep(1.0)             # the server reaps them all
+        body = b"y" * (4 << 20)
+        assert rpc.call(url, "POST", body, timeout=10.0) == \
+            {"n": len(body)}
+        assert rpc.call(url, "POST", b"z", timeout=5.0) == {"n": 1}
+    finally:
+        server.stop()
