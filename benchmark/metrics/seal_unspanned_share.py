@@ -1,0 +1,11 @@
+"""EC file pipeline: the window less every main-thread `seal.*` stage
+row: what no stage of the program covers (the shell in the harness,
+HTTP, the master's lookups, heartbeats)."""
+
+from benchmark import stages
+
+OP, PREFIX = "ec.encode", "seal."
+
+
+def read(facts):
+    return stages.unspanned_share(facts, OP, PREFIX)

@@ -43,7 +43,7 @@ from ..storage.store import Store
 from ..storage.vacuum import vacuum as vacuum_volume
 from ..storage.volume import (CorruptNeedleError, DiskFullError,
                               NotFoundError, TierReadError, VolumeError)
-from ..trace import span as trace_span
+from ..trace import current_span, span as trace_span
 from . import rpc
 
 # How long a receive_ecc fragment may wait for its receive_shard before
@@ -2257,7 +2257,15 @@ class VolumeServer:
 
     def _admin_delete_volume(self, query: dict, body: bytes) -> dict:
         req = json.loads(body)
-        self.store.delete_volume(req["volume"])
+        ev = self.ec_volumes.get(req["volume"])
+        if ev is None:
+            self.store.delete_volume(req["volume"])
+        else:
+            # The last server-side step of a seal: the original goes
+            # once its shards are mounted.
+            with _roofline.StageClock(ev.codec.name)(
+                    "seal.delete_original"):
+                self.store.delete_volume(req["volume"])
         # Whole-volume teardown: subtract everything the volume still
         # held from the tenant ledger (the per-needle decrement path
         # never saw these).
@@ -2389,19 +2397,23 @@ class VolumeServer:
                         return hit[:m.start()]
         return os.path.join(self.store.locations[0].directory, str(vid))
 
-    def _ec_total_shards(self, vid: int, base: str | None = None) -> int:
-        """Shard-file count of an EC volume, codec-derived (mounted
-        EcVolume first, then the on-disk .vif) — a mixed-codec cluster
-        must not assume RS(10,4)'s 14 everywhere."""
+    def _ec_codec(self, vid: int, base: str | None = None):
+        """Codec of an EC volume (mounted EcVolume first, then the
+        on-disk .vif) — a mixed-codec cluster must not assume RS(10,4)
+        everywhere."""
         ev = self.ec_volumes.get(vid)
         if ev is not None:
-            return ev.codec.total_shards
+            return ev.codec
         from ..ec.volume_info import ec_codec_name
         try:
             return get_codec(
-                ec_codec_name(base or self._volume_base(vid))).total_shards
+                ec_codec_name(base or self._volume_base(vid)))
         except ValueError:
-            return TOTAL_SHARDS
+            return get_codec("rs")
+
+    def _ec_total_shards(self, vid: int, base: str | None = None) -> int:
+        """Shard-file count of an EC volume, codec-derived."""
+        return self._ec_codec(vid, base).total_shards
 
     def _ec_generate(self, query: dict, body: bytes) -> dict:
         """VolumeEcShardsGenerate: .dat -> shard files + .ecx + .vif.
@@ -2422,10 +2434,12 @@ class VolumeServer:
         dat_bytes = v.dat_size()
         emit_event("ec.encode.start", node=self.url(), vid=vid,
                    dat_bytes=dat_bytes, codec=codec.name)
+        clock = _roofline.StageClock(codec.name)
         t0 = time.perf_counter()
         try:
-            write_sorted_file_from_idx(base)
-            write_ec_files(base, codec=codec.name)
+            with clock("seal.finish"):
+                write_sorted_file_from_idx(base)
+            write_ec_files(base, codec=codec.name, clock=clock)
         except Exception as e:
             emit_event("ec.encode.finish", node=self.url(),
                        severity="error", vid=vid,
@@ -2433,25 +2447,43 @@ class VolumeServer:
                        error=f"{type(e).__name__}: {e}")
             raise
         from ..ec.volume_info import save_volume_info
-        save_volume_info(base, v.version, codec=codec.name)
+        with clock("seal.finish"):
+            save_volume_info(base, v.version, codec=codec.name)
+        stages = self._note_stages(clock)
         emit_event("ec.encode.finish", node=self.url(), vid=vid,
                    seconds=round(time.perf_counter() - t0, 6),
                    dat_bytes=dat_bytes, shards=codec.total_shards,
-                   codec=codec.name)
+                   codec=codec.name, stages=stages)
         return {"shards": list(range(codec.total_shards)),
                 "codec": codec.name}
+
+    @staticmethod
+    def _note_stages(clock) -> dict:
+        """A finished job's stage totals (stats/roofline.py), set once
+        on the admin request's server span when one is recorded, and
+        handed back for the finish event: no per-chunk span enters the
+        trace ring."""
+        stages = clock.totals()
+        sp = current_span()
+        if sp is not None:
+            sp.set(stages=stages)
+        return stages
 
     def _ec_mount(self, query: dict, body: bytes) -> dict:
         req = json.loads(body)
         vid = req["volume"]
         base = self._volume_base(vid)
         ev = self.ec_volumes.get(vid)
-        if ev is None:
-            ev = EcVolume(base, vid=vid)
-            self.ec_volumes[vid] = ev
-        else:
-            ev.load_local_shards()
-        self._send_heartbeat()
+        # A volume's first mount here ends a seal (or a shard copy);
+        # a mounted volume re-loads its local shards after a rebuild.
+        clock = _roofline.StageClock(self._ec_codec(vid, base).name)
+        with clock("seal.mount" if ev is None else "rebuild.mount"):
+            if ev is None:
+                ev = EcVolume(base, vid=vid)
+                self.ec_volumes[vid] = ev
+            else:
+                ev.load_local_shards()
+            self._send_heartbeat()
         return {"shards": sorted(ev.shards)}
 
     def _ec_unmount(self, query: dict, body: bytes) -> dict:
@@ -2467,9 +2499,10 @@ class VolumeServer:
         vid = req["volume"]
         base = self._volume_base(vid)
         emit_event("ec.rebuild.start", node=self.url(), vid=vid)
+        clock = _roofline.StageClock(self._ec_codec(vid, base).name)
         t0 = time.perf_counter()
         try:
-            generated = rebuild_ec_files(base)
+            generated = rebuild_ec_files(base, clock=clock)
         except Exception as e:
             emit_event("ec.rebuild.finish", node=self.url(),
                        severity="error", vid=vid,
@@ -2478,7 +2511,7 @@ class VolumeServer:
             raise
         emit_event("ec.rebuild.finish", node=self.url(), vid=vid,
                    seconds=round(time.perf_counter() - t0, 6),
-                   rebuilt=generated)
+                   rebuilt=generated, stages=self._note_stages(clock))
         return {"rebuilt_shards": generated}
 
     def _ec_delete_shards(self, query: dict, body: bytes) -> dict:

@@ -27,6 +27,7 @@ from ..codecs import get_codec
 from ..fault import registry as _fault
 from ..ops.erasure import ErasureCoder, new_coder
 from ..stats.metrics import ec_repair_read_bytes_total
+from ..stats.roofline import StageClock
 from ..storage.needle_map import MemDb
 
 # Per-shard contiguous bytes handed to one coder call. Must divide
@@ -64,12 +65,14 @@ def write_ec_files(base_file_name: str, coder: ErasureCoder | None = None,
                    large_block_size: int = LARGE_BLOCK_SIZE,
                    small_block_size: int = SMALL_BLOCK_SIZE,
                    chunk_size: int = DEFAULT_CHUNK,
-                   codec=None) -> None:
+                   codec=None, clock: StageClock | None = None) -> None:
     """Generate the shard files from the .dat (WriteEcFiles), plus the
     `.ecc` per-block checksum sidecar the background scrub verifies
     shards against (ec/integrity.py).  `codec` selects the erasure
     codec ("rs" default, "lrc", ...); shard-file count, parity rows
-    and the recorded `.vif` codec id all derive from it."""
+    and the recorded `.vif` codec id all derive from it.  `clock` is
+    the job's stage clock (stats/roofline.py `seal.*`); a caller that
+    wants the job's stage totals passes its own."""
     if coder is None:
         coder = new_coder(codec=codec)
     cd = getattr(coder, "codec", None) or get_codec("rs")
@@ -84,6 +87,8 @@ def write_ec_files(base_file_name: str, coder: ErasureCoder | None = None,
         raise ValueError(
             f"codec {cd.name!r}: data shards must be {DATA_SHARDS} for "
             "the weed shard layout")
+    if clock is None:
+        clock = StageClock(cd.name)
     dat_size = os.path.getsize(base_file_name + ".dat")
     outputs = [open(base_file_name + to_ext(i), "wb")
                for i in range(cd.total_shards)]
@@ -107,27 +112,30 @@ def write_ec_files(base_file_name: str, coder: ErasureCoder | None = None,
             crc_map = _encode_dat_file(
                 dat, dat_size, coder, outputs,
                 large_block_size, small_block_size, chunk_size,
-                accs=accs)
+                accs=accs, clock=clock)
     finally:
-        for f in outputs:
-            f.close()
-    # The codec id travels in the .vif like the needle version: any
-    # server that later mounts these shards must pick the matching
-    # decode matrices.
-    update_volume_info(base_file_name, codec=cd.name)
-    with ecc_lock(base_file_name):
-        ecc = ShardChecksums(base_file_name)
-        for sid in range(cd.total_shards):
-            ecc.set_shard(sid, crc_map[sid] if crc_map is not None
-                          else accs[sid].finalize())
-        ecc.save()
+        with clock("seal.finish"):
+            for f in outputs:
+                f.close()
+    with clock("seal.finish"):
+        # The codec id travels in the .vif like the needle version: any
+        # server that later mounts these shards must pick the matching
+        # decode matrices.
+        update_volume_info(base_file_name, codec=cd.name)
+        with ecc_lock(base_file_name):
+            ecc = ShardChecksums(base_file_name)
+            for sid in range(cd.total_shards):
+                ecc.set_shard(sid, crc_map[sid] if crc_map is not None
+                              else accs[sid].finalize())
+            ecc.save()
 
 
 def _encode_dat_file(dat, dat_size: int, coder: ErasureCoder, outputs,
                      large: int, small: int, chunk_size: int,
-                     accs=None):
+                     accs=None, clock: StageClock | None = None):
     chunks = _chunk_reader(dat, dat_size, large, small, chunk_size)
-    return _pipelined_encode(chunks, coder, outputs, accs=accs)
+    return _pipelined_encode(chunks, coder, outputs, accs=accs,
+                             clock=clock)
 
 
 def _chunk_reader(dat, dat_size: int, large: int, small: int,
@@ -179,14 +187,18 @@ def _chunk_reader(dat, dat_size: int, large: int, small: int,
 
 
 def _pipelined_encode(chunks, coder: ErasureCoder, outputs,
-                      depth: int = 2, accs=None):
+                      depth: int = 2, accs=None,
+                      clock: StageClock | None = None):
     """Double-buffered encode pipeline (SURVEY §2.3 'double-buffered
-    host→HBM DMA + batched kernel launches'):
+    host→HBM DMA + batched kernel launches'), each step a stage of
+    `clock` (stats/roofline.py STAGES):
 
-      reader thread:  pread chunk k+1          (overlaps everything)
-      main thread:    dispatch encode(k)       (async on device coders)
-                      write data shards of k   (independent of parity)
-                      force + write parity of k-depth+1
+      reader thread:  pread chunk k+1          seal.stack
+      main thread:    wait for chunk k         seal.stack_wait
+                      dispatch encode(k)       seal.dispatch
+                      write data shards of k   seal.write_data
+                      force parity of k-depth+1    seal.drain
+                      write it                 seal.write_parity
 
     Device coders dispatch asynchronously, so up to `depth` encodes are
     in flight while the next chunk is being read — pread, host→device,
@@ -202,13 +214,22 @@ def _pipelined_encode(chunks, coder: ErasureCoder, outputs,
     import queue
     import threading
 
+    if clock is None:
+        clock = StageClock()
     q: "queue.Queue" = queue.Queue(maxsize=depth)
     cancelled = threading.Event()
     error: list[BaseException] = []
 
     def read_loop() -> None:
         try:
-            for data in chunks:
+            it = iter(chunks)
+            while True:
+                with clock("seal.stack") as st:
+                    data = next(it, None)
+                    if data is not None:
+                        st.add_bytes(data.nbytes)
+                if data is None:
+                    break
                 # Bounded puts with a cancel check: if the main thread
                 # dies (device failure, ENOSPC) while this thread is
                 # blocked on a full queue, a plain q.put would deadlock
@@ -254,35 +275,40 @@ def _pipelined_encode(chunks, coder: ErasureCoder, outputs,
         [[] for _ in range(data_shards + parity_shards)]
 
     def flush_one() -> None:
-        if not fused:
-            parity = np.asarray(inflight.popleft())
+        with clock("seal.drain") as st:
+            if fused:
+                handle, crc_handle = inflight.popleft()
+                parity = np.asarray(handle)
+                crcs = np.asarray(crc_handle)
+                for sid, row in enumerate(crcs):
+                    crc_lists[sid].extend(int(c) for c in row)
+                st.add_bytes(parity.nbytes + crcs.nbytes)
+            else:
+                parity = np.asarray(inflight.popleft())
+                st.add_bytes(parity.nbytes)
+        with clock("seal.write_parity", parity.nbytes):
             for p in range(parity_shards):
-                _shard_write(outputs[data_shards + p], data_shards + p,
-                             parity[p].tobytes(), accs)
-            return
-        handle, crc_handle = inflight.popleft()
-        parity = np.asarray(handle)
-        for sid, row in enumerate(np.asarray(crc_handle)):
-            crc_lists[sid].extend(int(c) for c in row)
-        for p in range(parity_shards):
-            sid = data_shards + p
-            _shard_write(outputs[sid], sid, parity[p].tobytes(), None)
+                sid = data_shards + p
+                _shard_write(outputs[sid], sid, parity[p].tobytes(),
+                             accs)
 
     try:
         while True:
-            data = q.get()
+            with clock("seal.stack_wait"):
+                data = q.get()
             if data is None:
                 break
             # Dispatch first: device coders return an async handle and
             # the kernel runs while we write the data shards and read
             # the next chunk.
-            if fused:
-                inflight.append(coder.encode_with_crc(data))
-            else:
-                inflight.append(coder.encode(data))
-            for i in range(data_shards):
-                _shard_write(outputs[i], i, data[i].tobytes(),
-                             None if fused else accs)
+            with clock("seal.dispatch", data.nbytes):
+                if fused:
+                    inflight.append(coder.encode_with_crc(data))
+                else:
+                    inflight.append(coder.encode(data))
+            with clock("seal.write_data", data.nbytes):
+                for i in range(data_shards):
+                    _shard_write(outputs[i], i, data[i].tobytes(), accs)
             if len(inflight) >= depth:
                 flush_one()
         while inflight:
@@ -302,18 +328,22 @@ def _pipelined_encode(chunks, coder: ErasureCoder, outputs,
 
 def rebuild_ec_files(base_file_name: str,
                      coder: ErasureCoder | None = None,
-                     chunk_size: int = DEFAULT_CHUNK) -> list[int]:
+                     chunk_size: int = DEFAULT_CHUNK,
+                     clock: StageClock | None = None) -> list[int]:
     """Recreate missing .ec?? files from survivors (RebuildEcFiles).
 
     Returns the list of generated shard ids.  Layout-agnostic: operates
     on flat shard-file columns.  Codec-aware: the codec comes from the
     `.vif` sidecar, the shard count from the codec, and only the
     codec's planned minimal read set is read from disk — an LRC
-    in-group rebuild reads 5 shard files, not every survivor.
+    in-group rebuild reads 5 shard files, not every survivor.  `clock`
+    is the job's stage clock (`rebuild.*`), as in `write_ec_files`.
     """
     if coder is None:
         coder = new_coder(codec=ec_codec_name(base_file_name))
     cd = getattr(coder, "codec", None) or get_codec("rs")
+    if clock is None:
+        clock = StageClock(cd.name)
     present: dict[int, str] = {}
     missing: list[int] = []
     for sid in range(cd.total_shards):
@@ -344,28 +374,38 @@ def rebuild_ec_files(base_file_name: str,
         for off in range(0, shard_size, chunk_size):
             take = min(chunk_size, shard_size - off)
             have = {}
-            for sid, f in ins.items():
-                buf = os.pread(f.fileno(), take, off)
-                if len(buf) != take:
-                    raise ValueError(f"short read on shard {sid}")
-                have[sid] = np.frombuffer(buf, dtype=np.uint8)
-            ec_repair_read_bytes_total.inc(take * len(have),
-                                           codec=cd.name)
-            rec = coder.reconstruct(have, wanted=missing)
+            with clock("rebuild.read", take * len(ins)):
+                for sid, f in ins.items():
+                    buf = os.pread(f.fileno(), take, off)
+                    if len(buf) != take:
+                        raise ValueError(f"short read on shard {sid}")
+                    have[sid] = np.frombuffer(buf, dtype=np.uint8)
+                ec_repair_read_bytes_total.inc(take * len(have),
+                                               codec=cd.name)
+            with clock("rebuild.dispatch", take * len(have)):
+                rec = coder.reconstruct(have, wanted=missing)
             for sid in missing:
-                _shard_write(outs[sid], sid,
-                             np.asarray(rec[sid]).tobytes(), accs)
+                with clock("rebuild.drain", take):
+                    buf = np.asarray(rec[sid]).tobytes()
+                with clock("rebuild.write", take):
+                    _shard_write(outs[sid], sid, buf, accs)
+                # One rebuilt row on the host at a time: a second live
+                # 4 MiB buffer cost the rebuild 3 % on the chip's host
+                # (fresh pages for every chunk), two cost 6 %.
+                del buf
     finally:
-        for f in ins.values():
-            f.close()
-        for f in outs.values():
-            f.close()
-    # Load-modify-save of the shared sidecar: serialize with the other
-    # writers (shard receive, scrub TOFU) or concurrent savers lose
-    # each other's entries.
-    with ecc_lock(base_file_name):
-        ecc = ShardChecksums.load(base_file_name)
-        for sid in missing:
-            ecc.set_shard(sid, accs[sid].finalize())
-        ecc.save()
+        with clock("rebuild.finish"):
+            for f in ins.values():
+                f.close()
+            for f in outs.values():
+                f.close()
+    with clock("rebuild.finish"):
+        # Load-modify-save of the shared sidecar: serialize with the
+        # other writers (shard receive, scrub TOFU) or concurrent
+        # savers lose each other's entries.
+        with ecc_lock(base_file_name):
+            ecc = ShardChecksums.load(base_file_name)
+            for sid in missing:
+                ecc.set_shard(sid, accs[sid].finalize())
+            ecc.save()
     return missing

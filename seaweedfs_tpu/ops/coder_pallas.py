@@ -41,8 +41,7 @@ def _record_roofline(kernel: str, coder, *, out_rows: int,
                      in_rows: int, n: int, crc: bool,
                      seconds: float, measured_bytes: int) -> None:
     """Feed one execution-fenced kernel wall into the roofline ledger.
-    Accounting must never take the encode path down; the ARMED check
-    stays at the call site so the disarmed cost is one flag read."""
+    Accounting must never take the encode path down."""
     try:
         _roofline.LEDGER.record(
             kernel, coder.codec.name, coder.mm, out_rows=out_rows,
@@ -50,6 +49,21 @@ def _record_roofline(kernel: str, coder, *, out_rows: int,
             measured_bytes=measured_bytes)
     except Exception:  # noqa: BLE001
         pass
+
+
+def _observe_call(kernel: str, coder, t0: float, *, out_rows: int,
+                  in_rows: int, n: int, crc: bool = False) -> None:
+    """One stopwatch per fenced coder call: the wall since `t0` goes to
+    the EC stage histogram (SeaweedFS_ec_stage_seconds, with the input
+    bytes) and, armed, to the roofline ledger (SeaweedFS_kernel_*_total,
+    with input + output bytes); disarmed, the ledger costs one flag
+    read."""
+    dt = time.perf_counter() - t0
+    observe_ec_stage(kernel, dt, in_rows * n)
+    if _roofline.ARMED:
+        _record_roofline(kernel, coder, out_rows=out_rows,
+                         in_rows=in_rows, n=n, crc=crc, seconds=dt,
+                         measured_bytes=(in_rows + out_rows) * n)
 
 
 def _prof_on() -> bool:
@@ -386,15 +400,9 @@ class PallasCoder:
         # Execution-fenced wall: a dispatch-only wall would flatter
         # the fused kernel.
         parity, crcs = jax.block_until_ready((parity, crcs))
-        dt = time.perf_counter() - t0
-        observe_ec_stage("encode_crc_kernel", dt, self.data_shards * n)
-        if _roofline.ARMED:
-            _record_roofline(
-                "encode_crc_kernel", self,
-                out_rows=self.parity_shards, in_rows=self.data_shards,
-                n=n, crc=True, seconds=dt,
-                measured_bytes=(self.data_shards
-                                + self.parity_shards) * n)
+        _observe_call("encode_crc_kernel", self, t0,
+                      out_rows=self.parity_shards,
+                      in_rows=self.data_shards, n=n, crc=True)
         return parity, crcs
 
     def encode(self, data) -> jax.Array:
@@ -407,16 +415,9 @@ class PallasCoder:
         t0 = time.perf_counter()
         out = jax.block_until_ready(
             self._apply(self._parity_pm, data, self.parity_shards))
-        dt = time.perf_counter() - t0
-        observe_ec_stage("encode_kernel", dt,
-                         data.shape[0] * data.shape[1])
-        if _roofline.ARMED:
-            n = int(data.shape[1])
-            _record_roofline(
-                "encode_kernel", self, out_rows=self.parity_shards,
-                in_rows=int(data.shape[0]), n=n, crc=False, seconds=dt,
-                measured_bytes=(int(data.shape[0])
-                                + self.parity_shards) * n)
+        _observe_call("encode_kernel", self, t0,
+                      out_rows=self.parity_shards,
+                      in_rows=int(data.shape[0]), n=int(data.shape[1]))
         return out
 
     def encode_all(self, data) -> jax.Array:
@@ -448,17 +449,10 @@ class PallasCoder:
         t0 = time.perf_counter()
         rec = jax.block_until_ready(
             self._apply(mat_pm, stacked, len(wanted)))
-        dt = time.perf_counter() - t0
-        observe_ec_stage("reconstruct_kernel", dt,
-                         stacked.shape[0] * stacked.shape[1])
-        if _roofline.ARMED:
-            n = int(stacked.shape[1])
-            _record_roofline(
-                "reconstruct_kernel", self, out_rows=len(wanted),
-                in_rows=int(stacked.shape[0]), n=n, crc=False,
-                seconds=dt,
-                measured_bytes=(int(stacked.shape[0])
-                                + len(wanted)) * n)
+        _observe_call("reconstruct_kernel", self, t0,
+                      out_rows=len(wanted),
+                      in_rows=int(stacked.shape[0]),
+                      n=int(stacked.shape[1]))
         return {w: rec[i] for i, w in enumerate(wanted)}
 
     def verify(self, shards) -> bool:

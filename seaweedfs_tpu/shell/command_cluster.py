@@ -408,6 +408,11 @@ class ClusterRoofline(Command):
         if not isinstance(doc, dict):
             raise ShellError(f"unexpected reply from {url}: {doc!r}")
         table = doc.get("kernels", [])
+        # A node's /debug/device lists the EC file pipeline's stage
+        # rows after its kernel rows: a section of their own here.
+        from ..stats.roofline import STAGES
+        stages = [r for r in table if r["kernel"] in STAGES]
+        table = [r for r in table if r["kernel"] not in STAGES]
         if flags.get("node"):
             # /debug/device rows are unmerged; apply filters locally.
             if flags.get("kernel"):
@@ -444,6 +449,15 @@ class ClusterRoofline(Command):
                     f"{'-' if p95 is None else format(p95, '6.3f')}")
         else:
             lines.append("no kernel invocations recorded yet")
+        if stages:
+            lines.append("")
+            lines.append("EC file pipeline stages (host thread):")
+            lines.append(f"{'STAGE':22} {'CODEC':12} {'COUNT':>7} "
+                         f"{'SECONDS':>9} {'BYTES':>13}")
+            for r in stages:
+                lines.append(
+                    f"{r['kernel']:22} {r['codec']:12} {r['count']:7d} "
+                    f"{r['seconds']:9.4f} {r['bytes']:13d}")
         occ_lines = []
         if flags.get("node"):
             occ = (doc.get("occupancy") or {}).get("latest", {})

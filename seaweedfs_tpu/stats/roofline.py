@@ -23,7 +23,13 @@ sibling of the PR 9 time-attribution plane:
   hand their per-batch stage spans (stack | dispatch | device | drain)
   to `note_pipeline()`, which keeps recent gantts, publishes the
   device-occupancy fraction, names the stage that starved the device,
-  and emits a `device.slow` event on sustained occupancy collapse.
+  and emits a `device.slow` event on sustained occupancy collapse;
+- the served EC file pipeline's stage clock (`StageClock`): what the
+  host thread of a seal or a rebuild is doing, stage by stage, summed
+  into one row per stage beside the kernel rows of `/debug/device`,
+  and opened as a `jax.profiler.TraceAnnotation` where JAX records no
+  span of its own, so the same stages stand on the device trace's
+  clock whenever a profiler session runs.
 
 Like the other planes the kernel catalog is closed (recording an
 uncataloged kernel raises), the ledger is a process singleton with
@@ -41,6 +47,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -86,6 +93,66 @@ KERNELS = {
 }
 
 PIPELINE_STAGES = ("stack", "dispatch", "device", "drain")
+
+# -- stage catalog -----------------------------------------------------------
+# The served EC file pipeline (ec/encoder.py, the EC admin handlers of
+# cluster/volume_server.py), named by what the host thread is doing, so
+# that a stage means the same with any coder and with or without the
+# coder's fence.  Closed like KERNELS: StageClock raises on any other
+# name.  The main-thread stages of one job are contiguous and never
+# nest: their seconds sum to the job's wall.
+
+STAGES = {
+    "seal.stack_wait":
+        "main thread blocked on the read-ahead queue: the reader did "
+        "not keep up (one more count than chunks: the end-of-stream "
+        "wait)",
+    "seal.stack":
+        "read-ahead thread, beside the main thread and in no sum: "
+        "pread of the stripe rows and the copy into the (10, n) chunk",
+    "seal.dispatch":
+        "the coder's encode call as the pipeline sees it: H2D issue, "
+        "kernel dispatch and, while the coder fences, the kernel wait",
+    "seal.write_data":
+        "tobytes + write of the data shards of one chunk",
+    "seal.drain":
+        "np.asarray of the parity and CRC handles: D2H and any wait "
+        "for the device; bytes = parity + CRC bytes brought back",
+    "seal.write_parity":
+        "write of the parity shards of one chunk",
+    "seal.finish":
+        "close of the shard files, .vif, .ecc save, .ecx",
+    "seal.mount":
+        "first mount of a volume's shards on this server "
+        "(/admin/ec/mount)",
+    "seal.delete_original":
+        "/admin/delete_volume of a volume whose shards are mounted",
+    "rebuild.read":
+        "pread of the planned survivors of one chunk",
+    "rebuild.dispatch":
+        "coder.reconstruct: the survivors' H2D, the stack, the kernel "
+        "and, while the coder fences, its wait",
+    "rebuild.drain":
+        "np.asarray + tobytes of one rebuilt row: D2H (a count per "
+        "chunk and rebuilt shard, as for rebuild.write)",
+    "rebuild.write":
+        "CRC accumulator feed and write of one rebuilt shard's chunk",
+    "rebuild.finish":
+        "close of the shard files, .ecc load-modify-save",
+    "rebuild.mount":
+        "re-load of a mounted volume's local shards, as after a "
+        "rebuild (/admin/ec/mount)",
+}
+
+# Stages in which JAX records nothing of its own get a TraceMe on the
+# profiler's host plane.  The others are counted only: dispatch and
+# drain would enclose JAX's XlaLinearize / PjitFunction / np.asarray
+# spans and take their place in a per-gap attribution; seal.stack runs
+# beside the main thread and would be credited with gaps it does not
+# cause (seal.stack_wait is what says the reader is the bound).
+ANNOTATED_STAGES = frozenset(STAGES) - {
+    "seal.stack", "seal.dispatch", "seal.drain",
+    "rebuild.dispatch", "rebuild.drain"}
 
 kernel_seconds_total = Counter(
     "SeaweedFS_kernel_seconds_total",
@@ -387,6 +454,8 @@ class RooflineLedger:
         # (kernel, codec, dtype, geometry) ->
         #   [count, seconds, bytes, macs, WindowedSketch]
         self._series: dict[tuple, list] = {}
+        # (stage, codec) -> [count, seconds, bytes]
+        self._stages: dict[tuple, list] = {}
         self._pipelines: deque = deque(maxlen=_PIPELINES_MAX)
         self._streak: dict[str, int] = {}
         self._collapsed: dict[str, bool] = {}
@@ -442,6 +511,30 @@ class RooflineLedger:
         kernel_work_total.inc(cost["macs"], kernel=kernel, codec=codec,
                               dtype=dtype)
         return row
+
+    # -- stage rows --------------------------------------------------
+
+    def add_stage(self, stage: str, codec: str, seconds: float,
+                  nbytes: int) -> None:
+        """One closed stage of the served EC file pipeline (StageClock
+        is the caller).  Totals only: no ring entry, no peaks, no
+        achieved fraction."""
+        with self._lock:
+            row = self._stages.get((stage, codec))
+            if row is None:
+                row = self._stages[(stage, codec)] = [0, 0.0, 0]
+            row[0] += 1
+            row[1] += seconds
+            row[2] += nbytes
+
+    def stage_table(self) -> list[dict]:
+        """Absolute per-stage rows, `kernel` holding the stage's name:
+        /debug/device lists them after the kernel rows."""
+        with self._lock:
+            items = sorted(self._stages.items())
+        return [{"kernel": stage, "codec": codec, "count": row[0],
+                 "seconds": round(row[1], 6), "bytes": row[2]}
+                for (stage, codec), row in items]
 
     # -- pipeline occupancy -----------------------------------------
 
@@ -584,6 +677,7 @@ class RooflineLedger:
         with self._lock:
             self._ring.clear()
             self._series.clear()
+            self._stages.clear()
             self._pipelines.clear()
             self._streak.clear()
             self._collapsed.clear()
@@ -596,6 +690,102 @@ def _rq(sketch, q: float):
 
 
 LEDGER = RooflineLedger()
+
+
+# -- the stage clock ---------------------------------------------------------
+
+class _Off:
+    """What a disarmed StageClock hands out: nothing is timed."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    def add_bytes(self, nbytes: int) -> None:
+        return None
+
+
+_OFF = _Off()
+
+
+class _Stage:
+    """One timed stage: two perf_counter() reads, one ledger row."""
+    __slots__ = ("clock", "name", "nbytes", "_note", "_t0")
+
+    def __init__(self, clock: "StageClock", name: str, nbytes: int):
+        self.clock, self.name, self.nbytes = clock, name, nbytes
+        self._note = None
+
+    def add_bytes(self, nbytes: int) -> None:
+        self.nbytes += nbytes
+
+    def __enter__(self):
+        if self.name in ANNOTATED_STAGES:
+            # A TraceMe is live only while a profiler session is, and
+            # only a process that already imported JAX can have one: a
+            # role that owns no chip (utils/jaxenv.py) imports nothing
+            # to annotate.
+            prof = sys.modules.get("jax.profiler")
+            if prof is not None:
+                self._note = prof.TraceAnnotation(self.name)
+                self._note.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        dt = time.perf_counter() - self._t0
+        if self._note is not None:
+            self._note.__exit__(*exc)
+        self.clock._add(self.name, dt, self.nbytes)
+
+
+class StageClock:
+    """The stage clock of one job of the served EC file pipeline:
+
+        clock = StageClock(codec)
+        with clock("seal.drain") as st:
+            ...
+            st.add_bytes(parity.nbytes)
+
+    Each closed stage adds (count 1, its seconds, its bytes) to the
+    ledger's row of that name and to this job's `totals()`.  It obeys
+    ARMED: disarmed, a call is that one flag check and nothing is
+    timed.  A stage the catalog does not name raises."""
+
+    def __init__(self, codec: str = ""):
+        self.codec = codec
+        self._lock = threading.Lock()   # seal.stack closes on the reader
+        self._totals: dict[str, list] = {}
+
+    def __call__(self, name: str, nbytes: int = 0):
+        if not ARMED:
+            return _OFF
+        if name not in STAGES:
+            raise ValueError(
+                f"unknown pipeline stage {name!r}; cataloged: "
+                f"{sorted(STAGES)}")
+        return _Stage(self, name, nbytes)
+
+    def _add(self, name: str, seconds: float, nbytes: int) -> None:
+        LEDGER.add_stage(name, self.codec, seconds, nbytes)
+        with self._lock:
+            acc = self._totals.get(name)
+            if acc is None:
+                acc = self._totals[name] = [0, 0.0, 0]
+            acc[0] += 1
+            acc[1] += seconds
+            acc[2] += nbytes
+
+    def totals(self) -> dict:
+        """{stage: {count, seconds, bytes}} of this job so far: what
+        rides the finish event and the admin request's server span."""
+        with self._lock:
+            return {name: {"count": c, "seconds": round(s, 6),
+                           "bytes": b}
+                    for name, (c, s, b) in sorted(self._totals.items())}
 
 
 def local_peaks() -> dict | None:
@@ -626,12 +816,14 @@ def _device_memory_stats() -> list[dict]:
 
 def debug_doc(node: str, role: str) -> dict:
     """GET /debug/device payload: measured peaks, the per-kernel
-    roofline table, recent invocations, recent pipeline gantts with
+    roofline table followed by the EC file pipeline's stage rows (same
+    list, `kernel` = the stage's name, no dtype, geometry or achieved
+    fraction), recent invocations, recent pipeline gantts with
     bubble attribution, the conservation verdict, and device memory
     stats."""
     return {"node": node, "role": role, "armed": ARMED,
             "peaks": local_peaks(),
-            "kernels": LEDGER.kernel_table(),
+            "kernels": LEDGER.kernel_table() + LEDGER.stage_table(),
             "recent": LEDGER.recent(16),
             "pipelines": LEDGER.pipelines(4),
             "occupancy": LEDGER.occupancy_summary(),

@@ -1,0 +1,277 @@
+"""The served EC file pipeline's stage clock (stats/roofline.py
+StageClock, ec/encoder.py, the EC admin handlers): a closed catalog, one
+flag check when disarmed, contiguous main-thread stages that sum to the
+job's wall, rows on /debug/device beside (never among) the kernel rows,
+the job's totals on the finish events and the admin request's span, and
+the annotated stages on the profiler's host plane.
+
+Marker: roofline (tier-1).
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import subprocess
+import sys
+import time
+
+import numpy as np
+import pytest
+
+import _stage_drive
+from seaweedfs_tpu.ec import SMALL_BLOCK_SIZE, to_ext
+from seaweedfs_tpu.ec.encoder import rebuild_ec_files, write_ec_files
+from seaweedfs_tpu.ec.integrity import ShardChecksums, file_block_crcs
+from seaweedfs_tpu.ops.coder_numpy import NumpyCoder
+from seaweedfs_tpu.ops.coder_pallas import PallasCoder
+from seaweedfs_tpu.shell import CommandEnv, run_command
+from seaweedfs_tpu.stats import roofline
+from seaweedfs_tpu.stats.roofline import ANNOTATED_STAGES, STAGES, StageClock
+
+pytestmark = pytest.mark.roofline
+
+BLOCK = SMALL_BLOCK_SIZE
+COUNTED_ONLY = {"seal.stack", "seal.dispatch", "seal.drain",
+                "rebuild.dispatch", "rebuild.drain"}
+
+
+@pytest.fixture(autouse=True)
+def _clean_ledger():
+    roofline.LEDGER.reset()
+    yield
+    roofline.set_armed(True)
+    roofline.LEDGER.reset()
+
+
+def _volume(tmp_path, name: str, nbytes: int) -> str:
+    base = str(tmp_path / name)
+    rng = np.random.default_rng(7)
+    with open(base + ".dat", "wb") as f:
+        f.write(rng.integers(0, 256, nbytes, dtype=np.uint8).tobytes())
+    with open(base + ".idx", "wb"):
+        pass
+    return base
+
+
+# -- (a) the catalog and the switch -------------------------------------------
+
+def test_stage_catalog_is_closed():
+    assert ANNOTATED_STAGES == set(STAGES) - COUNTED_ONLY
+    assert not set(STAGES) & set(roofline.KERNELS)
+    clock = StageClock("rs")
+    with pytest.raises(ValueError, match="unknown pipeline stage"):
+        clock("seal.bogus")
+    with clock("seal.drain", 3) as st:
+        st.add_bytes(4)
+    with clock("seal.drain"):
+        pass
+    got = clock.totals()
+    assert list(got) == ["seal.drain"]
+    assert got["seal.drain"]["count"] == 2
+    assert got["seal.drain"]["bytes"] == 7
+    rows = roofline.LEDGER.stage_table()
+    assert [(r["kernel"], r["codec"], r["count"], r["bytes"])
+            for r in rows] == [("seal.drain", "rs", 2, 7)]
+    assert set(rows[0]) == {"kernel", "codec", "count", "seconds",
+                            "bytes"}
+
+
+def test_disarmed_stage_clock_is_one_flag_check(tmp_path, monkeypatch):
+    """-roofline=false: a booby-trapped stage and ledger prove that no
+    stage is built, timed, annotated or recorded, and the pipeline
+    still writes its files."""
+    def boom(*a, **k):
+        raise AssertionError("stage clock reached while disarmed")
+
+    monkeypatch.setattr(roofline, "_Stage", boom)
+    monkeypatch.setattr(roofline.RooflineLedger, "add_stage", boom)
+    roofline.set_armed(False)
+    clock = StageClock("rs")
+    with clock("not even looked up") as st:
+        st.add_bytes(1)
+    base = _volume(tmp_path, "1", BLOCK + 99)
+    write_ec_files(base, coder=NumpyCoder(), clock=clock)
+    os.remove(base + to_ext(3))
+    assert rebuild_ec_files(base, coder=NumpyCoder(), clock=clock) == [3]
+    assert clock.totals() == {} and not roofline.LEDGER.stage_table()
+
+
+# -- (b) the stages of one job are contiguous and do not nest ----------------
+
+def test_main_thread_stages_sum_to_the_wall(tmp_path, monkeypatch):
+    """A seal and a rebuild of a small volume with the Pallas coder
+    (interpret mode), built before the clock starts: the main-thread
+    stages sum to 90-100 % of the function's wall and never to more,
+    every per-chunk row counts the chunks, the drain's bytes are the
+    parity and CRC bytes brought back, and the files are what the CPU
+    path writes."""
+    monkeypatch.setenv("SEAWEEDFS_TPU_EC_FUSED_CRC", "1")
+    chunks = 3
+    n = chunks * BLOCK                      # bytes of one shard
+    base = _volume(tmp_path, "1", 10 * n - 12345)
+    want = _volume(tmp_path, "2", 10 * n - 12345)
+    write_ec_files(want, coder=NumpyCoder(), chunk_size=BLOCK)
+    coder = PallasCoder(block_n=4096)
+    warm = np.zeros((10, BLOCK), np.uint8)
+    np.asarray(coder.encode_with_crc(warm)[0])      # compile outside
+    np.asarray(coder.reconstruct(
+        {s: warm[0] for s in range(14) if s not in (3, 11)},
+        wanted=[3, 11])[3])
+    roofline.LEDGER.reset()
+
+    clock = StageClock("rs")
+    t0 = time.perf_counter()
+    write_ec_files(base, coder=coder, chunk_size=BLOCK, clock=clock)
+    wall = time.perf_counter() - t0
+    got = clock.totals()
+    main = sum(v["seconds"] for k, v in got.items() if k != "seal.stack")
+    assert 0.90 * wall <= main <= wall, (main, wall, got)
+    for stage in ("seal.dispatch", "seal.write_data", "seal.drain",
+                  "seal.write_parity"):
+        assert got[stage]["count"] == chunks, stage
+    # one more wait and one more read than chunks: the end of the stream
+    assert got["seal.stack_wait"]["count"] == chunks + 1
+    assert got["seal.stack"]["count"] == chunks + 1
+    assert got["seal.stack"]["bytes"] == 10 * n
+    assert got["seal.dispatch"]["bytes"] == 10 * n
+    assert got["seal.write_data"]["bytes"] == 10 * n
+    assert got["seal.write_parity"]["bytes"] == 4 * n
+    assert got["seal.drain"]["bytes"] == 4 * n + 14 * chunks * 4
+    assert got["seal.finish"]["count"] == 2
+    ecc, ecc_want = ShardChecksums.load(base), ShardChecksums.load(want)
+    for sid in range(14):
+        with open(base + to_ext(sid), "rb") as a, \
+                open(want + to_ext(sid), "rb") as b:
+            assert a.read() == b.read(), sid
+        assert ecc.get(sid) == ecc_want.get(sid) == \
+            file_block_crcs(base + to_ext(sid))
+
+    for sid in (3, 11):
+        os.remove(base + to_ext(sid))
+    clock = StageClock("rs")
+    t0 = time.perf_counter()
+    assert rebuild_ec_files(base, coder=coder, chunk_size=BLOCK,
+                            clock=clock) == [3, 11]
+    wall = time.perf_counter() - t0
+    got = clock.totals()
+    assert set(got) == {"rebuild.read", "rebuild.dispatch",
+                        "rebuild.drain", "rebuild.write",
+                        "rebuild.finish"}
+    main = sum(v["seconds"] for v in got.values())
+    assert 0.90 * wall <= main <= wall, (main, wall, got)
+    for stage in ("rebuild.read", "rebuild.dispatch"):
+        assert got[stage]["count"] == chunks, stage
+    # drained and written one rebuilt row at a time
+    for stage in ("rebuild.drain", "rebuild.write"):
+        assert got[stage]["count"] == 2 * chunks, stage
+    assert got["rebuild.read"]["bytes"] == 10 * n
+    assert got["rebuild.drain"]["bytes"] == 2 * n
+    for sid in (3, 11):
+        with open(base + to_ext(sid), "rb") as a, \
+                open(want + to_ext(sid), "rb") as b:
+            assert a.read() == b.read(), sid
+    assert ShardChecksums.load(base).get(3) == ecc_want.get(3)
+    # both jobs fed the process rows too, under their codec
+    rows = {r["kernel"]: r for r in roofline.LEDGER.stage_table()}
+    assert rows["seal.drain"]["count"] == chunks
+    assert rows["rebuild.write"]["codec"] == "rs"
+
+
+def test_a_failed_chunk_still_closes_its_stage(tmp_path):
+    """The clock is a `with`: a coder that raises leaves no stage open
+    and the reader thread is joined."""
+    class Broken(NumpyCoder):
+        def encode(self, data):
+            raise RuntimeError("device lost")
+
+    base = _volume(tmp_path, "1", 2 * BLOCK)
+    clock = StageClock("rs")
+    with pytest.raises(RuntimeError, match="device lost"):
+        write_ec_files(base, coder=Broken(), clock=clock)
+    got = clock.totals()
+    assert got["seal.dispatch"]["count"] == 1
+    assert "seal.drain" not in got and got["seal.finish"]["count"] == 1
+
+
+# -- the operator's view ------------------------------------------------------------
+
+def test_stage_rows_ride_debug_device_events_and_the_span(
+        tmp_path, monkeypatch):
+    """Through the volume server's own handlers: one row per stage in
+    /debug/device's `kernels` (what benchmark/served.py sums by name),
+    none of them in the heartbeat's rollup or cluster.roofline's kernel
+    table, and the job's totals on the finish event and on the admin
+    request's server span (recorded with SEAWEEDFS_TPU_TRACES=1), set
+    once."""
+    monkeypatch.setenv("SEAWEEDFS_TPU_TRACES", "1")
+    got = _stage_drive.drive(str(tmp_path))
+    assert sorted(got["rebuilt"]) == _stage_drive.LOST
+    rows = [r for r in got["device"]["kernels"] if r["kernel"] in STAGES]
+    assert {r["kernel"] for r in rows} == set(STAGES)
+    for r in rows:
+        assert set(r) == {"kernel", "codec", "count", "seconds", "bytes"}
+        assert r["codec"] == "rs" and r["count"] >= 1
+    # the handlers name the mounts by what they end
+    by = {r["kernel"]: r for r in rows}
+    assert by["seal.mount"]["count"] == 1
+    assert by["rebuild.mount"]["count"] == 1
+    assert by["seal.delete_original"]["count"] == 1
+    # kernel surfaces are the kernels' alone
+    hb = roofline.LEDGER.heartbeat_view()
+    assert not [r for r in hb["kernels"] if r["kernel"] in STAGES]
+    assert not [r for r in got["device"]["recent"]
+                if r["kernel"] in STAGES]
+    for kind, prefix, route in (
+            ("ec.encode.finish", "seal.", "/admin/ec/generate"),
+            ("ec.rebuild.finish", "rebuild.", "/admin/ec/rebuild")):
+        stages = got["finish"][kind]["attrs"]["stages"]
+        assert stages and all(s.startswith(prefix) for s in stages)
+        assert f"{prefix}drain" in stages
+        assert all(set(v) == {"count", "seconds", "bytes"}
+                   for v in stages.values())
+        span = [s for s in got["spans"][kind] if route in s["name"]]
+        assert len(span) == 1 and span[0]["attrs"]["stages"] == stages
+    json.dumps(got["finish"])          # the journal's sink writes JSON
+
+
+def test_cluster_roofline_prints_stages_in_a_section_of_their_own(
+        tmp_path, monkeypatch):
+    from seaweedfs_tpu.cluster import rpc
+    clock = StageClock("rs")
+    with clock("seal.write_data", 10):
+        pass
+    PallasCoder(4, 2).encode(np.ones((4, 2048), np.uint8))
+    doc = roofline.debug_doc("n:1", "volume")
+    monkeypatch.setattr(rpc, "call", lambda url, **kw: doc)
+    out = run_command(CommandEnv("http://127.0.0.1:1"),
+                      "cluster.roofline -node n:1")
+    head, _, tail = out.partition("EC file pipeline stages")
+    assert "encode_kernel" in head and "seal.write_data" not in head
+    assert "seal.write_data" in tail and "encode_kernel" not in tail
+
+
+# -- (c) the annotated stages on the profiler's clock ---------------------------
+
+def test_annotated_stages_are_host_events_of_the_trace(tmp_path):
+    """Under `jax.profiler.start_trace` on the CPU platform (a child
+    with a time limit of its own: a profiler session is the process's),
+    the `.xplane.pb` read with benchmark/tracing.py `load()` has host
+    events named for every annotated stage, as many as the row counts
+    and as long as the row says, and none for the stages that are
+    counted only."""
+    p = subprocess.run(
+        [sys.executable, _stage_drive.__file__, str(tmp_path)],
+        capture_output=True, text=True, timeout=240,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert p.returncode == 0, p.stderr[-3000:]
+    got = json.loads(p.stdout.splitlines()[-1])
+    events, rows = got["events"], got["rows"]
+    assert set(events) == ANNOTATED_STAGES
+    assert set(rows) == set(STAGES)
+    for name, (count, seconds) in events.items():
+        assert count == rows[name]["count"], name
+        # one clock read apart on each side: microseconds a stage
+        assert rows[name]["seconds"] <= seconds + 1e-6, name
+        assert seconds - rows[name]["seconds"] <= \
+            0.02 * seconds + 200e-6 * count, name
