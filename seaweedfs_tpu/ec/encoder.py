@@ -17,6 +17,7 @@ keep outputs byte-identical:
 from __future__ import annotations
 
 import os
+import threading
 
 import numpy as np
 
@@ -33,6 +34,53 @@ from ..storage.needle_map import MemDb
 # Per-shard contiguous bytes handed to one coder call. Must divide
 # LARGE_BLOCK_SIZE and be a multiple of SMALL_BLOCK_SIZE.
 DEFAULT_CHUNK = 4 * 1024 * 1024
+
+# Host buffers of one default chunk (40 MiB) that stay with the process
+# between seals: what one job has live (`_pipelined_encode`), 200 MiB.
+# A fresh buffer costs a page fault per 4 KiB on first touch — as much
+# as reading the chunk in place saves — so that is paid once per
+# process, not once per chunk or per job.
+CHUNK_POOL_BUFFERS = 5
+
+
+class _ChunkPool:
+    """Bounded free list of flat uint8 host buffers of `nbytes` each,
+    handed out last-in-first-out so that a short job touches the
+    fewest.  A taker that finds it empty allocates; `give` keeps a
+    buffer only up to the bound, so concurrent jobs never grow it."""
+
+    def __init__(self, bound: int, nbytes: int):
+        self.bound = bound
+        self.nbytes = nbytes
+        self._lock = threading.Lock()
+        self._free: list[np.ndarray] = []
+        self._reused = 0
+        self._allocated = 0
+
+    def take(self, nbytes: int) -> np.ndarray:
+        """A buffer of at least `nbytes`; its contents are garbage."""
+        with self._lock:
+            if nbytes <= self.nbytes and self._free:
+                self._reused += 1
+                return self._free.pop()
+            self._allocated += 1
+        return np.empty(max(nbytes, self.nbytes), dtype=np.uint8)
+
+    def give(self, buf: np.ndarray) -> None:
+        """Hand back a buffer nothing reads or writes any more."""
+        with self._lock:
+            if buf.nbytes == self.nbytes and len(self._free) < self.bound:
+                self._free.append(buf)
+
+    def counts(self) -> dict:
+        """Chunks built in a reused buffer, chunks a buffer had to be
+        allocated for, bytes held for the next job (`/debug/device`)."""
+        with self._lock:
+            return {"reused": self._reused, "allocated": self._allocated,
+                    "held_bytes": len(self._free) * self.nbytes}
+
+
+CHUNK_POOL = _ChunkPool(CHUNK_POOL_BUFFERS, DATA_SHARDS * DEFAULT_CHUNK)
 
 
 def write_sorted_file_from_idx(base_file_name: str,
@@ -133,33 +181,30 @@ def write_ec_files(base_file_name: str, coder: ErasureCoder | None = None,
 def _encode_dat_file(dat, dat_size: int, coder: ErasureCoder, outputs,
                      large: int, small: int, chunk_size: int,
                      accs=None, clock: StageClock | None = None):
-    chunks = _chunk_reader(dat, dat_size, large, small, chunk_size)
-    return _pipelined_encode(chunks, coder, outputs, accs=accs,
-                             clock=clock)
+    spans = _chunk_spans(dat_size, large, small, chunk_size)
+    return _pipelined_encode(dat.fileno(), spans, coder, outputs,
+                             accs=accs, clock=clock)
 
 
-def _chunk_reader(dat, dat_size: int, large: int, small: int,
-                  chunk_size: int):
-    """Yield (DATA_SHARDS, n) uint8 stripe chunks in shard-file order —
-    the read side of the pipeline, byte-identical chunking to the
-    previous serial encoder."""
-    fd = dat.fileno()
+def _chunk_spans(dat_size: int, large: int, small: int, chunk_size: int):
+    """The chunking, in shard-file order and byte-identical to the
+    previous serial encoder: yield `(width, reads)` per
+    `(DATA_SHARDS, width)` stripe chunk.  A read
+    `(offset, row, nrows, col, n)` puts the file range
+    `[offset, offset + nrows * n)` into `chunk[row + j, col:col + n]`
+    for `j` in `range(nrows)`."""
     remaining = dat_size
     processed = 0
     # Large-block rows while more than one full large row remains
-    # (strictly greater, like the reference encodeDatFile loop).
+    # (strictly greater, like the reference encodeDatFile loop).  The
+    # ten blocks of a row are `large` apart in the file: a read each.
     chunk = min(chunk_size, large)
     if large % chunk != 0:
         raise ValueError(f"chunk {chunk} must divide block size {large}")
     while remaining > large * DATA_SHARDS:
         for b in range(0, large, chunk):
-            data = np.zeros((DATA_SHARDS, chunk), dtype=np.uint8)
-            for i in range(DATA_SHARDS):
-                raw = os.pread(fd, chunk, processed + i * large + b)
-                if raw:
-                    data[i, :len(raw)] = np.frombuffer(raw,
-                                                       dtype=np.uint8)
-            yield data
+            yield chunk, [(processed + i * large + b, i, 1, 0, chunk)
+                          for i in range(DATA_SHARDS)]
         remaining -= large * DATA_SHARDS
         processed += large * DATA_SHARDS
     # Small-block rows, many per coder call: a volume under 10GB is
@@ -168,42 +213,95 @@ def _chunk_reader(dat, dat_size: int, large: int, small: int,
     # are column-independent, so K consecutive rows stack into one
     # (10, K*small) call — same bytes, K fewer launches; each shard's
     # blocks from consecutive rows are consecutive in its shard file.
+    # The ten blocks of a row are consecutive in the file: ONE read
+    # scatters them down a column of the chunk.
     rows_per_call = max(1, chunk_size // small)
+    row_bytes = small * DATA_SHARDS
     while remaining > 0:
-        row_bytes = small * DATA_SHARDS
         nrows = min(rows_per_call, -(-remaining // row_bytes))
-        data = np.zeros((DATA_SHARDS, nrows * small), dtype=np.uint8)
-        for r in range(nrows):
-            base = processed + r * row_bytes
-            col = r * small
-            for i in range(DATA_SHARDS):
-                raw = os.pread(fd, small, base + i * small)
-                if raw:
-                    data[i, col:col + len(raw)] = \
-                        np.frombuffer(raw, dtype=np.uint8)
-        yield data
+        yield nrows * small, [
+            (processed + r * row_bytes, 0, DATA_SHARDS, r * small, small)
+            for r in range(nrows)]
         remaining -= row_bytes * nrows
         processed += row_bytes * nrows
 
 
-def _pipelined_encode(chunks, coder: ErasureCoder, outputs,
+def _pread_into(fd: int, views: list, offset: int) -> None:
+    """Fill the 1-D uint8 `views`, in order, from the file at `offset`:
+    one `preadv`, another only after a short read.  What lies past the
+    end of the file is zeroed: a reused buffer holds an older chunk's
+    bytes, and the shards' zero padding is part of the format."""
+    while views:
+        n = os.preadv(fd, views, offset)
+        if n == 0:
+            break
+        offset += n
+        while views and n >= views[0].nbytes:
+            n -= views[0].nbytes
+            views = views[1:]
+        if n:
+            views = [views[0][n:]] + views[1:]
+    for v in views:
+        v[:] = 0
+
+
+def _read_chunk(fd: int, width: int, reads,
+                flat: np.ndarray | None = None) -> np.ndarray:
+    """Build one chunk of `_chunk_spans` in place: no intermediate
+    `bytes`, no copy.  In `flat` (a buffer of at least
+    `DATA_SHARDS * width` bytes that the caller owns and may reuse) if
+    given, else in a fresh array that belongs to whoever gets the
+    chunk.  Either way the chunk is C-contiguous — a narrower last
+    chunk is viewed out of the front of `flat`, not sliced out of wider
+    rows — so `jnp.asarray` does not copy it first."""
+    if flat is None:
+        flat = np.empty(DATA_SHARDS * width, dtype=np.uint8)
+    data = flat[:DATA_SHARDS * width].reshape(DATA_SHARDS, width)
+    for offset, row, nrows, col, n in reads:
+        _pread_into(fd, [data[row + j, col:col + n] for j in range(nrows)],
+                    offset)
+    return data
+
+
+def _chunk_reader(dat, dat_size: int, large: int, small: int,
+                  chunk_size: int):
+    """Yield the `(DATA_SHARDS, n)` uint8 stripe chunks of
+    `_chunk_spans`, each a fresh array the consumer owns (the batch
+    path copies it into a staging buffer of its own)."""
+    fd = dat.fileno()
+    for width, reads in _chunk_spans(dat_size, large, small, chunk_size):
+        yield _read_chunk(fd, width, reads)
+
+
+def _pipelined_encode(fd: int, spans, coder: ErasureCoder, outputs,
                       depth: int = 2, accs=None,
                       clock: StageClock | None = None):
     """Double-buffered encode pipeline (SURVEY §2.3 'double-buffered
-    host→HBM DMA + batched kernel launches'), each step a stage of
-    `clock` (stats/roofline.py STAGES):
+    host→HBM DMA + batched kernel launches') over the chunks `spans`
+    (`_chunk_spans`) of the file `fd`, each step a stage of `clock`
+    (stats/roofline.py STAGES):
 
-      reader thread:  pread chunk k+1          seal.stack
+      reader thread:  wait for a free buffer
+                      read chunk k+1 into it   seal.stack
       main thread:    wait for chunk k         seal.stack_wait
                       dispatch encode(k)       seal.dispatch
                       write data shards of k   seal.write_data
                       force parity of k-depth+1    seal.drain
+                      hand its buffer back
                       write it                 seal.write_parity
 
     Device coders dispatch asynchronously, so up to `depth` encodes are
     in flight while the next chunk is being read — pread, host→device,
     kernel, device→host, and shard writes all overlap instead of
     serializing (the round-2/3 verdict's weak spot #3).
+
+    The chunks live in `2 * depth + 1` buffers of `CHUNK_POOL` (`depth`
+    in flight, `depth` read ahead, one being filled), and that count is
+    what bounds the read-ahead.  One ownership rule: a buffer goes back
+    to the pool, and so to the reader, only when its chunk is finished
+    — its data shards written AND its parity drained.  Until then the
+    coder may still read it: a device coder transfers asynchronously,
+    and on the CPU platform `jnp.asarray` may alias the host array.
 
     When ``accs is None`` the coder must support fused CRC
     (`encode_with_crc`) and every chunk must span whole `.ecc` blocks:
@@ -212,61 +310,38 @@ def _pipelined_encode(chunks, coder: ErasureCoder, outputs,
     accumulators passed, None is returned."""
     import collections
     import queue
-    import threading
 
     if clock is None:
         clock = StageClock()
-    q: "queue.Queue" = queue.Queue(maxsize=depth)
+    q: "queue.Queue" = queue.Queue()
+    free = threading.Semaphore(2 * depth + 1)
     cancelled = threading.Event()
     error: list[BaseException] = []
 
     def read_loop() -> None:
         try:
-            it = iter(chunks)
-            while True:
+            for width, reads in spans:
+                # Bounded waits with a cancel check: if the main thread
+                # dies (device failure, ENOSPC) it hands no buffer back,
+                # and a plain acquire would deadlock the final join
+                # forever.
+                while not free.acquire(timeout=0.2):
+                    if cancelled.is_set():
+                        return
+                buf = CHUNK_POOL.take(DATA_SHARDS * width)
                 with clock("seal.stack") as st:
-                    data = next(it, None)
-                    if data is not None:
-                        st.add_bytes(data.nbytes)
-                if data is None:
-                    break
-                # Bounded puts with a cancel check: if the main thread
-                # dies (device failure, ENOSPC) while this thread is
-                # blocked on a full queue, a plain q.put would deadlock
-                # the final join forever.
-                delivered = False
-                while not cancelled.is_set():
-                    try:
-                        q.put(data, timeout=0.2)
-                        delivered = True
-                        break
-                    except queue.Full:
-                        continue
-                if not delivered:
-                    # The chunk never reached the consumer.  Normally
-                    # the consumer cancelled because it already has its
-                    # own exception in flight (which wins below); if it
-                    # somehow finishes "cleanly", this error surfaces
-                    # instead of silently truncated shard files.
-                    error.append(RuntimeError(
-                        "ec encode cancelled with a chunk undelivered"))
-                    return
+                    data = _read_chunk(fd, width, reads, buf)
+                    st.add_bytes(data.nbytes)
+                q.put((data, buf))
         except BaseException as e:  # noqa: BLE001 — surfaced below
             error.append(e)
         finally:
-            # The end-of-stream sentinel must actually arrive (a full
-            # queue would silently drop put_nowait and deadlock the
-            # consumer); same bounded-put-with-cancel as the data path.
-            while not cancelled.is_set():
-                try:
-                    q.put(None, timeout=0.2)
-                    break
-                except queue.Full:
-                    continue
+            q.put(None)  # end of stream; the queue has no bound
     t = threading.Thread(target=read_loop, daemon=True,
                          name="ec-read-ahead")
     t.start()
     inflight: "collections.deque" = collections.deque()
+    lent: "collections.deque" = collections.deque()  # their buffers
 
     data_shards = coder.data_shards
     parity_shards = coder.parity_shards
@@ -286,6 +361,10 @@ def _pipelined_encode(chunks, coder: ErasureCoder, outputs,
             else:
                 parity = np.asarray(inflight.popleft())
                 st.add_bytes(parity.nbytes)
+        # The oldest chunk is finished (its data shards were written
+        # before this call): the reader may have its buffer.
+        CHUNK_POOL.give(lent.popleft())
+        free.release()
         with clock("seal.write_parity", parity.nbytes):
             for p in range(parity_shards):
                 sid = data_shards + p
@@ -295,9 +374,11 @@ def _pipelined_encode(chunks, coder: ErasureCoder, outputs,
     try:
         while True:
             with clock("seal.stack_wait"):
-                data = q.get()
-            if data is None:
+                item = q.get()
+            if item is None:
                 break
+            data, buf = item
+            lent.append(buf)
             # Dispatch first: device coders return an async handle and
             # the kernel runs while we write the data shards and read
             # the next chunk.
@@ -315,12 +396,14 @@ def _pipelined_encode(chunks, coder: ErasureCoder, outputs,
             flush_one()
     finally:
         cancelled.set()
-        while True:  # unblock a reader stuck on a full queue
-            try:
-                q.get_nowait()
-            except queue.Empty:
-                break
         t.join()
+        # After a failure: what never reached the coder goes back to
+        # the pool; a chunk that was in flight is dropped with its
+        # buffer, which the coder may still read.
+        while not q.empty():
+            item = q.get_nowait()
+            if item is not None:
+                CHUNK_POOL.give(item[1])
     if error:
         raise error[0]
     return dict(enumerate(crc_lists)) if fused else None

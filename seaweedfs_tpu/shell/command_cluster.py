@@ -458,6 +458,12 @@ class ClusterRoofline(Command):
                 lines.append(
                     f"{r['kernel']:22} {r['codec']:12} {r['count']:7d} "
                     f"{r['seconds']:9.4f} {r['bytes']:13d}")
+        pool = doc.get("seal_buffers")
+        if pool:
+            lines.append(
+                f"seal.stack host buffers: {pool['reused']} chunks read "
+                f"into a reused one, {pool['allocated']} into a new one, "
+                f"{pool['held_bytes'] >> 20} MiB held")
         occ_lines = []
         if flags.get("node"):
             occ = (doc.get("occupancy") or {}).get("latest", {})

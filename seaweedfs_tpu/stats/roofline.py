@@ -109,7 +109,8 @@ STAGES = {
         "wait)",
     "seal.stack":
         "read-ahead thread, beside the main thread and in no sum: "
-        "pread of the stripe rows and the copy into the (10, n) chunk",
+        "preadv of the stripe rows, in place, into a pooled (10, n) "
+        "host buffer (its wait for a free buffer is outside)",
     "seal.dispatch":
         "the coder's encode call as the pipeline sees it: H2D issue, "
         "kernel dispatch and, while the coder fences, the kernel wait",
@@ -819,8 +820,11 @@ def debug_doc(node: str, role: str) -> dict:
     roofline table followed by the EC file pipeline's stage rows (same
     list, `kernel` = the stage's name, no dtype, geometry or achieved
     fraction), recent invocations, recent pipeline gantts with
-    bubble attribution, the conservation verdict, and device memory
-    stats."""
+    bubble attribution, the conservation verdict, device memory
+    stats, and the counts of the seal's host buffer pool
+    (ec/encoder.py CHUNK_POOL: says that `seal.stack` builds its
+    chunks in reused buffers)."""
+    from ..ec.encoder import CHUNK_POOL
     return {"node": node, "role": role, "armed": ARMED,
             "peaks": local_peaks(),
             "kernels": LEDGER.kernel_table() + LEDGER.stage_table(),
@@ -828,4 +832,5 @@ def debug_doc(node: str, role: str) -> dict:
             "pipelines": LEDGER.pipelines(4),
             "occupancy": LEDGER.occupancy_summary(),
             "conservation": LEDGER.conservation(),
-            "devices": _device_memory_stats()}
+            "devices": _device_memory_stats(),
+            "seal_buffers": CHUNK_POOL.counts()}

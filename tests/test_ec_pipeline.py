@@ -364,3 +364,269 @@ def test_pipelined_encode_failure_propagates_promptly(tmp_path):
     th.join(timeout=15)
     assert not th.is_alive(), "write_ec_files deadlocked on coder failure"
     assert result == ["device fell over"]
+
+
+# -- the seal's read side: in place, into pooled host buffers -------------------
+
+CHUNK = 4 * SMALL   # four small rows per coder call: (10, 400) chunks
+
+
+@pytest.fixture
+def pool(monkeypatch):
+    """A pool of this test's own, its buffers one test chunk wide (the
+    process's holds 40 MiB buffers and other tests' leftovers)."""
+    from seaweedfs_tpu.ec import encoder
+    p = encoder._ChunkPool(encoder.CHUNK_POOL_BUFFERS, DATA_SHARDS * CHUNK)
+    monkeypatch.setattr(encoder, "CHUNK_POOL", p)
+    return p
+
+
+def _taken(p) -> int:
+    c = p.counts()
+    return c["reused"] + c["allocated"]
+
+
+def _write_dat(path, blob: bytes) -> str:
+    with open(str(path) + ".dat", "wb") as f:
+        f.write(blob)
+    return str(path)
+
+
+def _seal(base: str, coder, chunk_size: int = CHUNK) -> None:
+    write_ec_files(base, coder=coder, large_block_size=LARGE,
+                   small_block_size=SMALL, chunk_size=chunk_size)
+
+
+def _reference_shards(blob: bytes, chunk_size: int = CHUNK) -> list[bytes]:
+    """The plain reference: the parent commit's serial chunking (a
+    fresh zeroed chunk, one copy per block) through NumpyCoder."""
+    from seaweedfs_tpu.ops.coder_numpy import NumpyCoder
+    coder = NumpyCoder(10, 4)
+    shards = [bytearray() for _ in range(TOTAL_SHARDS)]
+
+    def block(off: int, n: int) -> np.ndarray:
+        out = np.zeros(n, np.uint8)
+        raw = np.frombuffer(blob[off:off + n], np.uint8)
+        out[:len(raw)] = raw
+        return out
+
+    def emit(data: np.ndarray) -> None:
+        for sid, row in enumerate(np.concatenate([data,
+                                                  coder.encode(data)])):
+            shards[sid] += row.tobytes()
+
+    remaining, processed = len(blob), 0
+    chunk = min(chunk_size, LARGE)
+    while remaining > LARGE * DATA_SHARDS:
+        for b in range(0, LARGE, chunk):
+            emit(np.stack([block(processed + i * LARGE + b, chunk)
+                           for i in range(DATA_SHARDS)]))
+        remaining -= LARGE * DATA_SHARDS
+        processed += LARGE * DATA_SHARDS
+    row_bytes = SMALL * DATA_SHARDS
+    while remaining > 0:
+        nrows = min(max(1, chunk_size // SMALL), -(-remaining // row_bytes))
+        emit(np.concatenate(
+            [np.stack([block(processed + r * row_bytes + i * SMALL, SMALL)
+                       for i in range(DATA_SHARDS)])
+             for r in range(nrows)], axis=1))
+        remaining -= row_bytes * nrows
+        processed += row_bytes * nrows
+    return [bytes(s) for s in shards]
+
+
+def _assert_sealed_like_reference(base: str, blob: bytes,
+                                  chunk_size: int = CHUNK) -> None:
+    from seaweedfs_tpu.ec.integrity import (BlockCrcAccumulator,
+                                            ShardChecksums)
+    want = _reference_shards(blob, chunk_size)
+    ecc = ShardChecksums.load(base)
+    for sid in range(TOTAL_SHARDS):
+        with open(base + to_ext(sid), "rb") as f:
+            assert f.read() == want[sid], f"shard {sid}"
+        acc = BlockCrcAccumulator()
+        acc.feed(want[sid])
+        assert ecc.get(sid) == acc.finalize(), f".ecc of shard {sid}"
+
+
+def _coder(backend: str):
+    try:
+        return new_coder(backend=backend)
+    except RuntimeError as e:   # the native library did not build
+        pytest.skip(str(e))
+
+
+@pytest.mark.parametrize("backend", ["numpy", "native", "jax"])
+@pytest.mark.parametrize("size", [
+    pytest.param(3 * DATA_SHARDS * CHUNK, id="whole_chunks"),
+    pytest.param(3 * DATA_SHARDS * CHUNK + 1, id="one_byte_more"),
+    pytest.param(3 * DATA_SHARDS * CHUNK + DATA_SHARDS * SMALL + 250,
+                 id="partial_last_row"),
+    pytest.param(37, id="under_one_row"),
+    pytest.param(2 * DATA_SHARDS * LARGE + 4321, id="large_block_rows"),
+])
+def test_seal_in_dirty_pooled_buffers_is_byte_identical(
+        tmp_path, pool, size, backend):
+    """Every shard and `.ecc` entry of a seal that runs SECOND, in
+    buffers an all-0xFF volume (and a brush, so that no thread timing
+    leaves one clean) has dirtied: a tail past the end of the `.dat`
+    that was not zeroed shows in data shards, parity and `.ecc`."""
+    coder = _coder(backend)
+    _seal(_write_dat(tmp_path / "ff", b"\xff" * (8 * DATA_SHARDS * CHUNK)),
+          new_coder(backend="numpy"))
+    held = [pool.take(1) for _ in range(pool.bound)]
+    for buf in held:
+        buf[:] = 0xFF
+        pool.give(buf)
+    assert pool.counts()["held_bytes"] == pool.bound * pool.nbytes
+    blob = random.Random(size).randbytes(size)
+    base = _write_dat(tmp_path / "v", blob)
+    _seal(base, coder)
+    _assert_sealed_like_reference(base, blob)
+
+
+def test_chunk_is_the_coders_until_its_parity_is_drained(tmp_path, pool):
+    """A coder whose handle reads its input only when drained (as an
+    unfenced device coder may): the parity is still the reference's,
+    which it is not if a buffer goes back to the reader before
+    `flush_one` has drained its chunk."""
+    import time as _t
+
+    from seaweedfs_tpu.ops.coder_numpy import NumpyCoder
+
+    class LazyCoder(NumpyCoder):
+        def encode(self, data):
+            class Handle:
+                def __array__(_self, dtype=None, copy=None):
+                    return NumpyCoder.encode(self, data)
+            _t.sleep(0.002)    # the reader runs ahead meanwhile
+            return Handle()
+
+    blob = random.Random(5).randbytes(16 * DATA_SHARDS * CHUNK)
+    base = _write_dat(tmp_path / "v", blob)
+    _seal(base, LazyCoder(10, 4))
+    _assert_sealed_like_reference(base, blob)
+
+
+def test_failure_while_the_reader_waits_for_a_buffer(tmp_path, pool):
+    """The main thread raises while the read-ahead thread waits for a
+    FREE BUFFER (all of the job's are live): the job ends promptly,
+    what never reached the coder is back in the pool, and the next job
+    seals in it as if nothing had happened."""
+    import threading
+    import time as _t
+
+    from seaweedfs_tpu.ops.coder_numpy import NumpyCoder
+
+    blob = random.Random(6).randbytes(9 * DATA_SHARDS * CHUNK)
+    base = _write_dat(tmp_path / "v", blob)
+
+    class ExplodingCoder(NumpyCoder):
+        calls = 0
+
+        def encode(self, data):
+            type(self).calls += 1
+            if type(self).calls >= 2:
+                deadline = _t.monotonic() + 10
+                while _taken(pool) < 5 and _t.monotonic() < deadline:
+                    _t.sleep(0.001)
+                _t.sleep(0.05)    # ... and it is waiting for a sixth
+                raise RuntimeError("device fell over")
+            return super().encode(data)
+
+    result: list = []
+
+    def run():
+        try:
+            _seal(base, ExplodingCoder(10, 4))
+            result.append("no-error")
+        except RuntimeError as e:
+            result.append(str(e))
+
+    th = threading.Thread(target=run, daemon=True)
+    th.start()
+    th.join(timeout=15)
+    assert not th.is_alive(), "write_ec_files deadlocked on coder failure"
+    assert result == ["device fell over"]
+    # Five were live: two in flight (dropped: a coder may still read
+    # them), three read ahead and handed back.
+    assert _taken(pool) == 5
+    assert pool.counts()["held_bytes"] == 3 * pool.nbytes
+    _seal(base, NumpyCoder(10, 4))
+    _assert_sealed_like_reference(base, blob)
+    assert pool.counts()["held_bytes"] <= pool.bound * pool.nbytes
+
+
+def test_second_seal_allocates_nothing_and_the_bound_holds(tmp_path, pool):
+    """The pool outlives the job: a first seal allocates what it has
+    live, a second one in the same process allocates 0, `/debug/device`
+    says so, and two jobs at once leave no more than the bound behind."""
+    import threading
+    import time as _t
+
+    from seaweedfs_tpu.ops.coder_numpy import NumpyCoder
+    from seaweedfs_tpu.stats import roofline
+
+    class ReaderFirstCoder(NumpyCoder):
+        """The first call of each job waits until the read-ahead thread
+        has every buffer a job may have live, so the counts below do
+        not depend on how the threads were scheduled."""
+
+        def __init__(self, want_taken: int):
+            super().__init__(10, 4)
+            self.want_taken = want_taken
+
+        def encode(self, data):
+            deadline = _t.monotonic() + 10
+            while (_taken(pool) < self.want_taken
+                   and _t.monotonic() < deadline):
+                _t.sleep(0.001)
+            return super().encode(data)
+
+    blob = random.Random(7).randbytes(9 * DATA_SHARDS * CHUNK)
+    base = _write_dat(tmp_path / "v", blob)
+    _seal(base, ReaderFirstCoder(5))
+    first = pool.counts()
+    assert first == {"reused": 4, "allocated": 5,
+                     "held_bytes": 5 * pool.nbytes}
+    _seal(base, ReaderFirstCoder(9 + 5))
+    second = pool.counts()
+    assert second["allocated"] == first["allocated"]
+    assert second["reused"] == first["reused"] + 9
+    assert roofline.debug_doc("n:1", "volume")["seal_buffers"] == second
+    _assert_sealed_like_reference(base, blob)
+
+    # Two jobs at once, ten buffers live between them.
+    bases = [_write_dat(tmp_path / f"w{i}", blob) for i in range(2)]
+    coder = ReaderFirstCoder(18 + 10)
+    jobs = [threading.Thread(target=_seal, args=(b, coder), daemon=True)
+            for b in bases]
+    for th in jobs:
+        th.start()
+    for th in jobs:
+        th.join(timeout=30)
+        assert not th.is_alive()
+    third = pool.counts()
+    assert third["allocated"] >= second["allocated"] + 5
+    assert third["held_bytes"] == pool.bound * pool.nbytes
+    for b in bases:
+        _assert_sealed_like_reference(b, blob)
+
+
+def test_batch_reader_owns_the_chunks_it_is_given(tmp_path, pool):
+    """`_chunk_reader` (the batch path's reader: no buffer passed)
+    yields arrays of its own: two chunks taken and held share no
+    memory, hold the chunking's bytes, and the pool is not asked."""
+    from seaweedfs_tpu.ec.encoder import _chunk_reader
+    blob = random.Random(8).randbytes(2 * DATA_SHARDS * CHUNK + 123)
+    base = _write_dat(tmp_path / "v", blob)
+    with open(base + ".dat", "rb") as dat:
+        chunks = list(_chunk_reader(dat, len(blob), LARGE, SMALL, CHUNK))
+    assert [c.shape for c in chunks] == [(10, CHUNK), (10, CHUNK),
+                                         (10, SMALL)]
+    assert all(c.flags.c_contiguous for c in chunks)
+    assert not np.shares_memory(chunks[0], chunks[1])
+    want = _reference_shards(blob)
+    for sid in range(DATA_SHARDS):
+        assert b"".join(c[sid].tobytes() for c in chunks) == want[sid]
+    assert _taken(pool) == 0

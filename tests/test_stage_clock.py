@@ -130,9 +130,9 @@ def test_main_thread_stages_sum_to_the_wall(tmp_path, monkeypatch):
     for stage in ("seal.dispatch", "seal.write_data", "seal.drain",
                   "seal.write_parity"):
         assert got[stage]["count"] == chunks, stage
-    # one more wait and one more read than chunks: the end of the stream
+    # one more wait than chunks (the end of the stream), a read a chunk
     assert got["seal.stack_wait"]["count"] == chunks + 1
-    assert got["seal.stack"]["count"] == chunks + 1
+    assert got["seal.stack"]["count"] == chunks
     assert got["seal.stack"]["bytes"] == 10 * n
     assert got["seal.dispatch"]["bytes"] == 10 * n
     assert got["seal.write_data"]["bytes"] == 10 * n
@@ -249,6 +249,7 @@ def test_cluster_roofline_prints_stages_in_a_section_of_their_own(
     head, _, tail = out.partition("EC file pipeline stages")
     assert "encode_kernel" in head and "seal.write_data" not in head
     assert "seal.write_data" in tail and "encode_kernel" not in tail
+    assert "seal.stack host buffers: " in tail and " MiB held" in tail
 
 
 # -- (c) the annotated stages on the profiler's clock ---------------------------
