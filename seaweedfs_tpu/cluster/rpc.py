@@ -41,6 +41,7 @@ from ..netcore.registry import ConnRegistry, CountedConn, \
 from ..stats import contention as _contention
 from ..stats import flows as _flows
 from ..stats import phases as _phases
+from ..stats import roofline as _roofline
 from ..stats.metrics import Counter, Gauge, Histogram
 from ..tenancy import context as _tenant_ctx
 from ..trace import tracer as _tracer
@@ -738,6 +739,11 @@ class JsonHttpServer:
         self.prefix_routes: list[tuple[str, str, Callable]] = []
         self.metrics = None  # (Registry, Counter, Histogram) when on
         self.slo = None      # stats.slo.SloTracker once metrics are on
+        # Set by the volume server, whose prefix routes are the fid
+        # paths: each needle request it answers is booked under one
+        # of the two request rows of stats/roofline.py, by whether an
+        # EC admin job ran in the process beside it.
+        self.needle_rows = False
         # Wire-flow attribution (stats/flows.py): the role this server
         # answers X-Weed-Role with ("master"/"volume"/"filer"/...),
         # set by enable_metrics from its subsystem name.
@@ -1230,6 +1236,14 @@ class JsonHttpServer:
         # (introspection, heartbeats, push streams) skip the gate.
         lane = None
         queue_wait = 0.0
+        # A needle request of the volume server (its fid routes take
+        # their bodies whole): clocked from here to the response
+        # written (stats/roofline.py `note_request`).
+        booked = self.needle_rows and prefix_args is not None \
+            and _roofline.ARMED
+        if booked:
+            t_req = time.perf_counter()
+            beside = _roofline.jobs_running()
         if not _admission_exempt(req_path):
             lane = self.admission.lane_for(method, headers, query)
             if info is not None:
@@ -1290,6 +1304,8 @@ class JsonHttpServer:
             # principal must not leak into the next one.
             _tenant_ctx.clear_principal()
             _flows.end_request()
+            if booked:
+                _roofline.note_request(t_req, beside, len(body))
 
     def _observe_request(self, method: str, req_path: str, status: int,
                          seconds: float, trace_id: str = "",

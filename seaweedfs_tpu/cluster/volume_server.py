@@ -308,6 +308,9 @@ class VolumeServer:
         s.prefix_route("POST", "/", self._post_needle)
         s.prefix_route("PUT", "/", self._post_needle)
         s.prefix_route("DELETE", "/", self._delete_needle)
+        # The prefix routes are the fid paths: book each request under
+        # req.beside_job or req.alone (stats/roofline.py).
+        s.needle_rows = True
         self._stop = threading.Event()
         self._hb_thread = threading.Thread(target=self._heartbeat_loop,
                                            daemon=True,
@@ -2415,6 +2418,7 @@ class VolumeServer:
         """Shard-file count of an EC volume, codec-derived."""
         return self._ec_codec(vid, base).total_shards
 
+    @_roofline.ec_job()
     def _ec_generate(self, query: dict, body: bytes) -> dict:
         """VolumeEcShardsGenerate: .dat -> shard files + .ecx + .vif.
         The codec comes from the request ("codec": "lrc"), else the
@@ -2494,6 +2498,7 @@ class VolumeServer:
         self._send_heartbeat()
         return {}
 
+    @_roofline.ec_job()
     def _ec_rebuild(self, query: dict, body: bytes) -> dict:
         req = json.loads(body)
         vid = req["volume"]

@@ -29,7 +29,10 @@ sibling of the PR 9 time-attribution plane:
   into one row per stage beside the kernel rows of `/debug/device`,
   and opened as a `jax.profiler.TraceAnnotation` where JAX records no
   span of its own, so the same stages stand on the device trace's
-  clock whenever a profiler session runs.
+  clock whenever a profiler session runs;
+- the mark that an EC admin job runs in the process (`ec_job`), and
+  two rows among the stage rows for the needle requests the volume
+  server answered beside a job and alone (`note_request`).
 
 Like the other planes the kernel catalog is closed (recording an
 uncataloged kernel raises), the ledger is a process singleton with
@@ -45,6 +48,7 @@ actually move is worse than no model.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import sys
@@ -100,7 +104,9 @@ PIPELINE_STAGES = ("stack", "dispatch", "device", "drain")
 # that a stage means the same with any coder and with or without the
 # coder's fence.  Closed like KERNELS: StageClock raises on any other
 # name.  The main-thread stages of one job are contiguous and never
-# nest: their seconds sum to the job's wall.
+# nest: their seconds sum to the job's wall.  The two `req.` rows are
+# the request plane's, booked by `note_request` on the threads that
+# answer needle requests: what a job in the same process costs them.
 
 STAGES = {
     "seal.stack_wait":
@@ -143,6 +149,15 @@ STAGES = {
     "rebuild.mount":
         "re-load of a mounted volume's local shards, as after a "
         "rebuild (/admin/ec/mount)",
+    "req.beside_job":
+        "request plane, not a job's thread: a needle request (upload, "
+        "read or delete on a fid path) the volume server answered "
+        "while an EC admin job ran in the process, at its start or at "
+        "its end; seconds = admission to the response written, bytes "
+        "= the request's body",
+    "req.alone":
+        "the same for a needle request with no EC admin job running "
+        "at either end",
 }
 
 # Stages in which JAX records nothing of its own get a TraceMe on the
@@ -150,10 +165,13 @@ STAGES = {
 # drain would enclose JAX's XlaLinearize / PjitFunction / np.asarray
 # spans and take their place in a per-gap attribution; seal.stack runs
 # beside the main thread and would be credited with gaps it does not
-# cause (seal.stack_wait is what says the reader is the bound).
+# cause (seal.stack_wait is what says the reader is the bound); a
+# request row closes hundreds of times a second on threads that cause
+# no gap of the device.
 ANNOTATED_STAGES = frozenset(STAGES) - {
     "seal.stack", "seal.dispatch", "seal.drain",
-    "rebuild.dispatch", "rebuild.drain"}
+    "rebuild.dispatch", "rebuild.drain",
+    "req.beside_job", "req.alone"}
 
 kernel_seconds_total = Counter(
     "SeaweedFS_kernel_seconds_total",
@@ -787,6 +805,46 @@ class StageClock:
             return {name: {"count": c, "seconds": round(s, 6),
                            "bytes": b}
                     for name, (c, s, b) in sorted(self._totals.items())}
+
+
+# -- EC admin jobs, and the requests answered beside them ---------------------
+# One process serves needles and runs EC jobs (the `server` role: the
+# chip's one owner).  The mark says a job runs somewhere in the
+# process; the request plane reads it at both ends of a needle request
+# and books the request under one of two rows.
+
+_jobs_lock = threading.Lock()
+_jobs_running = 0
+
+
+class ec_job(contextlib.ContextDecorator):
+    """Around one EC admin job (a seal, a rebuild), as `with ec_job():`
+    or as a decorator of its handler: the process-wide count of running
+    jobs is one higher inside, and comes down however the job ends."""
+
+    def __enter__(self):
+        global _jobs_running
+        with _jobs_lock:
+            _jobs_running += 1
+        return self
+
+    def __exit__(self, *exc) -> None:
+        global _jobs_running
+        with _jobs_lock:
+            _jobs_running -= 1
+
+
+def jobs_running() -> int:
+    return _jobs_running
+
+
+def note_request(t0: float, beside: int, nbytes: int) -> None:
+    """One needle request answered (cluster/rpc.py is the caller, and
+    checks ARMED before it reads `t0`): `beside` is `jobs_running()` as
+    the request started."""
+    LEDGER.add_stage(
+        "req.beside_job" if beside or _jobs_running else "req.alone",
+        "", time.perf_counter() - t0, nbytes)
 
 
 def local_peaks() -> dict | None:
