@@ -542,15 +542,31 @@ def served_leg(work: str, seed: int, rehearse: bool,
         platforms = {d["platform"] for d in dev["devices"]}
         check(platforms == {want_platform},
               f"served: /debug/device platforms {platforms}")
-        rows, kernel_seconds = {}, {}
+        rows, calls, kernel_seconds = {}, {}, {}
         for r in dev["kernels"]:
             rows[r["kernel"]] = rows.get(r["kernel"], 0) + r["bytes"]
+            calls[r["kernel"]] = calls.get(r["kernel"], 0) + r["count"]
             kernel_seconds[r["kernel"]] = round(
                 kernel_seconds.get(r["kernel"], 0.0) + r["seconds"], 3)
-        check(rows.get("encode_crc_kernel", 0)
-              >= summed("volume_bytes") * TOTAL_SHARDS // DATA_SHARDS,
-              f"served: encode kernel rows {rows} do not cover the "
+        # The seal's pipeline drains later, so it calls the coder
+        # unfenced and leaves no `encode_crc_kernel` row: what it
+        # leaves are its stage rows and one `seal_inflight` count per
+        # chunk it drained.
+        check(rows.get("seal.dispatch", 0) >= summed("volume_bytes")
+              and rows.get("seal.drain", 0)
+              >= summed("volume_bytes") * (TOTAL_SHARDS - DATA_SHARDS)
+              // DATA_SHARDS,
+              f"served: the seal's stage rows {rows} do not cover the "
               f"{summed('volume_bytes')} B of volumes")
+        check("encode_crc_kernel" not in rows,
+              f"served: a seal left a kernel row of an unfenced wall: "
+              f"{rows}")
+        drained = sum(dev["seal_inflight"].values())
+        check(drained == calls["seal.drain"] > 0,
+              f"served: seal_inflight {dev['seal_inflight']} against "
+              f"{calls['seal.drain']} drains")
+        # ... and a direct call (the rebuild's, the degraded reads')
+        # still fences and records.
         check(rows.get("reconstruct_kernel", 0)
               >= summed("shard_bytes") * (DATA_SHARDS + len(SERVED_LOST)),
               f"served: reconstruct kernel rows {rows} do not cover the "

@@ -83,6 +83,50 @@ class _ChunkPool:
 CHUNK_POOL = _ChunkPool(CHUNK_POOL_BUFFERS, DATA_SHARDS * DEFAULT_CHUNK)
 
 
+class _InflightCount:
+    """How `_pipelined_encode`'s drain found the oldest chunk in
+    flight, process-wide: `ready` (the device was done with it: its
+    round trip hid behind the main thread's writes) or `waited`.  All
+    `waited` means the device path, not the main thread, sets the
+    pace.  A device array is ready when it is computed; whether the
+    copy back had landed as well is what `seal.drain`'s seconds say."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._ready = 0
+        self._waited = 0
+
+    def note(self, ready: bool) -> None:
+        with self._lock:
+            if ready:
+                self._ready += 1
+            else:
+                self._waited += 1
+
+    def counts(self) -> dict:
+        """Chunks drained so far, by what the drain found
+        (`/debug/device`)."""
+        with self._lock:
+            return {"ready": self._ready, "waited": self._waited}
+
+
+SEAL_INFLIGHT = _InflightCount()
+
+
+def _request_copy_back(handle) -> None:
+    """Start the device -> host copy of a result handle that can copy
+    asynchronously (a device array); a host coder's array is here."""
+    ask = getattr(handle, "copy_to_host_async", None)
+    if ask is not None:
+        ask()
+
+
+def _is_ready(handle) -> bool:
+    """Whether `handle` is computed; a host array is."""
+    ask = getattr(handle, "is_ready", None)
+    return ask is None or bool(ask())
+
+
 def write_sorted_file_from_idx(base_file_name: str,
                                ext: str = ".ecx") -> None:
     """Generate the sorted `.ecx` from the `.idx` (WriteSortedFileFromIdx)."""
@@ -284,16 +328,24 @@ def _pipelined_encode(fd: int, spans, coder: ErasureCoder, outputs,
       reader thread:  wait for a free buffer
                       read chunk k+1 into it   seal.stack
       main thread:    wait for chunk k         seal.stack_wait
-                      dispatch encode(k)       seal.dispatch
+                      issue H2D of k, launch,
+                        request D2H            seal.dispatch
                       write data shards of k   seal.write_data
-                      force parity of k-depth+1    seal.drain
+                      collect parity of k-depth+1  seal.drain
                       hand its buffer back
                       write it                 seal.write_parity
 
-    Device coders dispatch asynchronously, so up to `depth` encodes are
-    in flight while the next chunk is being read — pread, host→device,
-    kernel, device→host, and shard writes all overlap instead of
-    serializing (the round-2/3 verdict's weak spot #3).
+    This is the one caller of the coder that drains later, so it is
+    the one that asks for the unfenced call (`encode_unfenced`, where
+    the coder has one; `RooflineLedger.record` takes fenced walls
+    only, so that call records no kernel row) and for the copy back
+    (where the handle can copy asynchronously).  The device round trip
+    of chunk k — host→device, kernel, device→host — then runs beside
+    the data-shard writes of k, and the drain of a chunk dispatched a
+    whole iteration earlier finds its bytes on the host
+    (`SEAL_INFLIGHT` counts how often).  A host coder computes inside
+    dispatch and its arrays count as ready.  A device error surfaces
+    at the drain.
 
     The chunks live in `2 * depth + 1` buffers of `CHUNK_POOL` (`depth`
     in flight, `depth` read ahead, one being filled), and that count is
@@ -346,21 +398,21 @@ def _pipelined_encode(fd: int, spans, coder: ErasureCoder, outputs,
     data_shards = coder.data_shards
     parity_shards = coder.parity_shards
     fused = accs is None
+    unfenced = getattr(coder, "encode_unfenced", None)
     crc_lists: list[list[int]] = \
         [[] for _ in range(data_shards + parity_shards)]
 
     def flush_one() -> None:
         with clock("seal.drain") as st:
+            handles = inflight.popleft()
+            SEAL_INFLIGHT.note(all(_is_ready(h) for h in handles))
+            parity = np.asarray(handles[0])
+            st.add_bytes(parity.nbytes)
             if fused:
-                handle, crc_handle = inflight.popleft()
-                parity = np.asarray(handle)
-                crcs = np.asarray(crc_handle)
+                crcs = np.asarray(handles[1])
                 for sid, row in enumerate(crcs):
                     crc_lists[sid].extend(int(c) for c in row)
-                st.add_bytes(parity.nbytes + crcs.nbytes)
-            else:
-                parity = np.asarray(inflight.popleft())
-                st.add_bytes(parity.nbytes)
+                st.add_bytes(crcs.nbytes)
         # The oldest chunk is finished (its data shards were written
         # before this call): the reader may have its buffer.
         CHUNK_POOL.give(lent.popleft())
@@ -379,14 +431,18 @@ def _pipelined_encode(fd: int, spans, coder: ErasureCoder, outputs,
                 break
             data, buf = item
             lent.append(buf)
-            # Dispatch first: device coders return an async handle and
-            # the kernel runs while we write the data shards and read
-            # the next chunk.
+            # Dispatch first: a device coder's transfer, kernel and
+            # copy back run while we write the data shards.
             with clock("seal.dispatch", data.nbytes):
-                if fused:
-                    inflight.append(coder.encode_with_crc(data))
+                if unfenced is not None:
+                    handles = unfenced(data, crc=fused)
+                elif fused:
+                    handles = coder.encode_with_crc(data)
                 else:
-                    inflight.append(coder.encode(data))
+                    handles = (coder.encode(data),)
+                for h in handles:
+                    _request_copy_back(h)
+                inflight.append(handles)
             with clock("seal.write_data", data.nbytes):
                 for i in range(data_shards):
                     _shard_write(outputs[i], i, data[i].tobytes(), accs)

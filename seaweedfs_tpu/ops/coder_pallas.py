@@ -67,12 +67,13 @@ def _observe_call(kernel: str, coder, t0: float, *, out_rows: int,
 
 
 def _prof_on() -> bool:
-    """Per-stage device-time histograms (SEAWEEDFS_TPU_EC_PROF=0 to
-    disable).  Profiling fences each call with block_until_ready — in
-    the serving paths results are staged to host right away so the
-    fence costs nothing; raw-throughput benchmarks that pipeline
-    dispatches (bench.py drives apply_bitmatrix_pallas directly and is
-    unaffected) can turn it off."""
+    """Whether `reconstruct`, this switch's one reader, fences and
+    records its call (SEAWEEDFS_TPU_EC_PROF=0 to disable).  Its callers
+    (the rebuild's serial loop, degraded reads) stage each result to
+    the host right away, so the fence costs them nothing.  The encodes
+    do not ask: `encode` / `encode_with_crc` always fence and record,
+    and the one caller that drains later, the seal's pipeline, calls
+    `encode_unfenced`."""
     return os.environ.get("SEAWEEDFS_TPU_EC_PROF", "1") \
         not in ("0", "false")
 
@@ -368,6 +369,28 @@ class PallasCoder:
         evenly divide the sidecar block."""
         return crc_fold.BLOCK % self.block_n == 0
 
+    def _on_device(self, data) -> jax.Array:
+        """`data` as a (data_shards, n) uint8 device array; for a host
+        array this issues the transfer and returns."""
+        data = jnp.asarray(data, jnp.uint8)
+        if data.shape[0] != self.data_shards:
+            raise ValueError(
+                f"expected {self.data_shards} data shards, "
+                f"got {data.shape[0]}")
+        return data
+
+    def _launch_crc(self, data: jax.Array) -> tuple[jax.Array, jax.Array]:
+        if not self.fused_crc_ok:
+            raise ValueError(
+                f"block_n {self.block_n} does not divide the .ecc "
+                f"block {crc_fold.BLOCK}")
+        if self._crc_consts is None:
+            self._crc_consts = crc_kernel_consts(self.block_n)
+        return apply_bitmatrix_crc_pallas(
+            self._parity_pm, data, *self._crc_consts,
+            self.parity_shards, self.data_shards,
+            interpret=self.interpret, block_n=self.block_n, mm=self.mm)
+
     def encode_with_crc(self, data) -> tuple[jax.Array, jax.Array]:
         """Encode AND checksum in one fused kernel.
 
@@ -378,40 +401,19 @@ class PallasCoder:
         must start block-aligned in its shard files (the encoder's
         chunks are and do).
         """
-        if not self.fused_crc_ok:
-            raise ValueError(
-                f"block_n {self.block_n} does not divide the .ecc "
-                f"block {crc_fold.BLOCK}")
-        data = jnp.asarray(data, jnp.uint8)
-        if data.shape[0] != self.data_shards:
-            raise ValueError(
-                f"expected {self.data_shards} data shards, "
-                f"got {data.shape[0]}")
-        if self._crc_consts is None:
-            self._crc_consts = crc_kernel_consts(self.block_n)
-        n = int(data.shape[1])
+        data = self._on_device(data)
         t0 = time.perf_counter()
-        parity, crcs = apply_bitmatrix_crc_pallas(
-            self._parity_pm, data, *self._crc_consts,
-            self.parity_shards, self.data_shards,
-            interpret=self.interpret, block_n=self.block_n, mm=self.mm)
-        if not _prof_on():
-            return parity, crcs
         # Execution-fenced wall: a dispatch-only wall would flatter
         # the fused kernel.
-        parity, crcs = jax.block_until_ready((parity, crcs))
+        parity, crcs = jax.block_until_ready(self._launch_crc(data))
         _observe_call("encode_crc_kernel", self, t0,
                       out_rows=self.parity_shards,
-                      in_rows=self.data_shards, n=n, crc=True)
+                      in_rows=self.data_shards, n=int(data.shape[1]),
+                      crc=True)
         return parity, crcs
 
     def encode(self, data) -> jax.Array:
-        data = jnp.asarray(data, jnp.uint8)
-        if data.shape[0] != self.data_shards:
-            raise ValueError(
-                f"expected {self.data_shards} data shards, got {data.shape[0]}")
-        if not _prof_on():
-            return self._apply(self._parity_pm, data, self.parity_shards)
+        data = self._on_device(data)
         t0 = time.perf_counter()
         out = jax.block_until_ready(
             self._apply(self._parity_pm, data, self.parity_shards))
@@ -419,6 +421,21 @@ class PallasCoder:
                       out_rows=self.parity_shards,
                       in_rows=int(data.shape[0]), n=int(data.shape[1]))
         return out
+
+    def encode_unfenced(self, data, crc: bool = False) -> tuple:
+        """`encode` (or, with `crc`, `encode_with_crc`) for the caller
+        that drains later (ec/encoder.py `_pipelined_encode`): issues
+        the transfer and the kernel and returns the handles, `(parity,)`
+        or `(parity, crcs)`, waiting for neither.  It records no row:
+        `RooflineLedger.record` takes execution-fenced walls only, and a
+        dispatch-only wall would read as an impossible rate.  A device
+        error surfaces where the caller collects the handles."""
+        data = self._on_device(data)
+        if _roofline.ARMED:
+            _roofline.LEDGER.mark_device()
+        if crc:
+            return self._launch_crc(data)
+        return (self._apply(self._parity_pm, data, self.parity_shards),)
 
     def encode_all(self, data) -> jax.Array:
         data = jnp.asarray(data, jnp.uint8)

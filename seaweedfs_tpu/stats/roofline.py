@@ -118,13 +118,15 @@ STAGES = {
         "preadv of the stripe rows, in place, into a pooled (10, n) "
         "host buffer (its wait for a free buffer is outside)",
     "seal.dispatch":
-        "the coder's encode call as the pipeline sees it: H2D issue, "
-        "kernel dispatch and, while the coder fences, the kernel wait",
+        "the coder's encode call as the pipeline makes it: H2D issue, "
+        "kernel launch, request of the copy back; a device coder is "
+        "waited for in seal.drain, a host coder computes here",
     "seal.write_data":
         "tobytes + write of the data shards of one chunk",
     "seal.drain":
-        "np.asarray of the parity and CRC handles: D2H and any wait "
-        "for the device; bytes = parity + CRC bytes brought back",
+        "np.asarray of the parity and CRC handles of the oldest chunk "
+        "in flight: collects what dispatch asked back, waits only for "
+        "what is not back yet; bytes = parity + CRC bytes collected",
     "seal.write_parity":
         "write of the parity shards of one chunk",
     "seal.finish":
@@ -475,6 +477,7 @@ class RooflineLedger:
         self._series: dict[tuple, list] = {}
         # (stage, codec) -> [count, seconds, bytes]
         self._stages: dict[tuple, list] = {}
+        self._unfenced = False
         self._pipelines: deque = deque(maxlen=_PIPELINES_MAX)
         self._streak: dict[str, int] = {}
         self._collapsed: dict[str, bool] = {}
@@ -686,17 +689,24 @@ class RooflineLedger:
         return {"kernels": self.kernel_table(),
                 "occupancy": self.occupancy_summary()}
 
+    def mark_device(self) -> None:
+        """A kernel was launched whose wall nobody fenced (the seal's
+        pipeline, `PallasCoder.encode_unfenced`): it gets no row, but
+        `has_rows` has its answer."""
+        self._unfenced = True
+
     def has_rows(self) -> bool:
-        """Whether this process has recorded any kernel — i.e. has a
-        live JAX backend of its own."""
+        """Whether this process has run any kernel — i.e. has a live
+        JAX backend of its own."""
         with self._lock:
-            return bool(self._series)
+            return bool(self._series) or self._unfenced
 
     def reset(self) -> None:
         with self._lock:
             self._ring.clear()
             self._series.clear()
             self._stages.clear()
+            self._unfenced = False
             self._pipelines.clear()
             self._streak.clear()
             self._collapsed.clear()
@@ -879,10 +889,12 @@ def debug_doc(node: str, role: str) -> dict:
     list, `kernel` = the stage's name, no dtype, geometry or achieved
     fraction), recent invocations, recent pipeline gantts with
     bubble attribution, the conservation verdict, device memory
-    stats, and the counts of the seal's host buffer pool
-    (ec/encoder.py CHUNK_POOL: says that `seal.stack` builds its
-    chunks in reused buffers)."""
-    from ..ec.encoder import CHUNK_POOL
+    stats, the counts of the seal's host buffer pool (ec/encoder.py
+    CHUNK_POOL: says that `seal.stack` builds its chunks in reused
+    buffers), and how the seal's drains found the oldest chunk in
+    flight (SEAL_INFLIGHT: `ready`, the device done with it, or
+    `waited`)."""
+    from ..ec.encoder import CHUNK_POOL, SEAL_INFLIGHT
     return {"node": node, "role": role, "armed": ARMED,
             "peaks": local_peaks(),
             "kernels": LEDGER.kernel_table() + LEDGER.stage_table(),
@@ -891,4 +903,5 @@ def debug_doc(node: str, role: str) -> dict:
             "occupancy": LEDGER.occupancy_summary(),
             "conservation": LEDGER.conservation(),
             "devices": _device_memory_stats(),
-            "seal_buffers": CHUNK_POOL.counts()}
+            "seal_buffers": CHUNK_POOL.counts(),
+            "seal_inflight": SEAL_INFLIGHT.counts()}

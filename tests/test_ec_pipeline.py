@@ -630,3 +630,139 @@ def test_batch_reader_owns_the_chunks_it_is_given(tmp_path, pool):
     for sid in range(DATA_SHARDS):
         assert b"".join(c[sid].tobytes() for c in chunks) == want[sid]
     assert _taken(pool) == 0
+
+
+# -- the device round trip of a chunk runs beside the data-shard writes -------
+
+@pytest.fixture
+def inflight(monkeypatch):
+    """A drain count of this test's own (the process's has every other
+    test's seals in it)."""
+    from seaweedfs_tpu.ec import encoder
+    c = encoder._InflightCount()
+    monkeypatch.setattr(encoder, "SEAL_INFLIGHT", c)
+    return c
+
+
+class _HandleCoder:
+    """NumpyCoder behind handles that behave as a device array does:
+    `encode` returns at once, the handle can be asked to copy back and
+    whether it is ready, and is computed when materialised.  Every
+    call goes into one event log, with the chunk's number."""
+
+    def __init__(self, log: list, ready: bool = True,
+                 fail_at: int | None = None):
+        from seaweedfs_tpu.ops.coder_numpy import NumpyCoder
+        self._np = NumpyCoder(10, 4)
+        self.data_shards, self.parity_shards = 10, 4
+        self.total_shards, self.codec = 14, self._np.codec
+        self.log, self.ready, self.fail_at = log, ready, fail_at
+        self.chunks: list[np.ndarray] = []
+
+    def encode(self, data):
+        coder, k = self, len(self.chunks)
+        self.chunks.append(data)
+        self.log.append(("call", k))
+
+        class Handle:
+            def copy_to_host_async(_self):
+                coder.log.append(("copy_back", k))
+
+            def is_ready(_self):
+                coder.log.append(("is_ready", k))
+                return coder.ready
+
+            def __array__(_self, dtype=None, copy=None):
+                coder.log.append(("array", k))
+                if k == coder.fail_at:
+                    raise RuntimeError("device fell over")
+                return coder._np.encode(data)
+        return Handle()
+
+
+def test_copy_back_is_requested_at_dispatch_and_collected_at_drain(
+        tmp_path, pool, inflight, monkeypatch):
+    """One event log of the main thread, whole: per chunk the coder is
+    called, the copy back is requested once — before that chunk's
+    first data-shard write — and the handle is asked whether it is
+    ready and materialised only in the drain, one iteration later,
+    after the NEXT chunk's data shards were written."""
+    from seaweedfs_tpu.ec import encoder
+    log: list = []
+    real_write = encoder._shard_write
+
+    def logged_write(f, sid, buf, accs):
+        log.append(("write", sid))
+        real_write(f, sid, buf, accs)
+
+    monkeypatch.setattr(encoder, "_shard_write", logged_write)
+    chunks = 4
+    blob = random.Random(9).randbytes(chunks * DATA_SHARDS * CHUNK)
+    base = _write_dat(tmp_path / "v", blob)
+    _seal(base, _HandleCoder(log))
+
+    def drained(k):
+        return [("is_ready", k), ("array", k)] + \
+            [("write", sid) for sid in range(DATA_SHARDS, TOTAL_SHARDS)]
+
+    want: list = []
+    for k in range(chunks):
+        want += [("call", k), ("copy_back", k)]
+        want += [("write", sid) for sid in range(DATA_SHARDS)]
+        if k:
+            want += drained(k - 1)
+    want += drained(chunks - 1)
+    assert log == want
+    assert inflight.counts() == {"ready": chunks, "waited": 0}
+    _assert_sealed_like_reference(base, blob)
+
+
+@pytest.mark.parametrize("handles,want", [
+    pytest.param("ready", {"ready": 5, "waited": 0}, id="ready"),
+    pytest.param("not_ready", {"ready": 0, "waited": 5}, id="not_ready"),
+    pytest.param("arrays", {"ready": 5, "waited": 0}, id="plain_arrays"),
+])
+def test_seal_inflight_counts_what_the_drain_found(
+        tmp_path, pool, inflight, handles, want):
+    """`seal_inflight` of `/debug/device`: a chunk whose handle says it
+    is ready counts `ready`, one that does not `waited`, and a host
+    coder's plain array (no `is_ready`, nothing to copy back) `ready`."""
+    from seaweedfs_tpu.ops.coder_numpy import NumpyCoder
+    from seaweedfs_tpu.stats import roofline
+    coder = NumpyCoder(10, 4) if handles == "arrays" \
+        else _HandleCoder([], ready=handles == "ready")
+    blob = random.Random(10).randbytes(5 * DATA_SHARDS * CHUNK)
+    base = _write_dat(tmp_path / "v", blob)
+    _seal(base, coder)
+    assert inflight.counts() == want
+    assert roofline.debug_doc("n:1", "volume")["seal_inflight"] == want
+    _assert_sealed_like_reference(base, blob)
+
+
+def test_a_handle_that_fails_at_the_drain_fails_the_job(
+        tmp_path, pool, inflight):
+    """An unfenced device coder's error surfaces where the handle is
+    collected: the job raises it, the reader thread is joined, and the
+    buffers of the chunks in flight (the failed one and the one
+    dispatched after it, which the coder may still read) are dropped,
+    not handed back to the pool."""
+    import threading
+    log: list = []
+    coder = _HandleCoder(log, fail_at=1)
+    blob = random.Random(11).randbytes(9 * DATA_SHARDS * CHUNK)
+    base = _write_dat(tmp_path / "v", blob)
+    with pytest.raises(RuntimeError, match="device fell over"):
+        _seal(base, coder)
+    assert not [th for th in threading.enumerate()
+                if th.name == "ec-read-ahead"]
+    # chunk 1 failed in the drain that follows chunk 2's dispatch
+    assert [e for e in log if e[0] == "call"] == \
+        [("call", 0), ("call", 1), ("call", 2)]
+    assert log[-1] == ("array", 1)
+    for data in coder.chunks[1:]:
+        assert not any(np.shares_memory(data, buf) for buf in pool._free)
+    c = pool.counts()
+    assert c["held_bytes"] <= (c["allocated"] - 2) * pool.nbytes
+    assert inflight.counts() == {"ready": 2, "waited": 0}
+    _seal(base, _HandleCoder([]))
+    _assert_sealed_like_reference(base, blob)

@@ -237,6 +237,61 @@ def test_real_encode_records_and_conserves(fake_peaks):
         roofline.LEDGER.reset()
 
 
+@pytest.mark.parametrize("fused,kernel", [
+    pytest.param("1", "encode_crc_kernel", id="fused_crc"),
+    pytest.param("0", "encode_kernel", id="byte_accumulators"),
+])
+def test_a_seal_records_no_kernel_row_and_a_direct_call_does(
+        fake_peaks, monkeypatch, tmp_path, fused, kernel):
+    """The ledger is fed fenced walls only: the seal's pipeline, which
+    drains later, asks the coder for the unfenced call and leaves no
+    `encode*` row (a dispatch-only wall would read as an impossible
+    rate), yet the process is known to drive a device; a direct call
+    still fences and records.  The sealed files are the CPU path's."""
+    from seaweedfs_tpu.ec import SMALL_BLOCK_SIZE as BLOCK, to_ext
+    from seaweedfs_tpu.ec import encoder
+    from seaweedfs_tpu.ec.integrity import ShardChecksums
+    from seaweedfs_tpu.ops.coder_numpy import NumpyCoder
+
+    monkeypatch.setenv("SEAWEEDFS_TPU_EC_FUSED_CRC", fused)
+    monkeypatch.setattr(encoder, "SEAL_INFLIGHT", encoder._InflightCount())
+    blob = np.random.default_rng(3).integers(
+        0, 256, 2 * 10 * BLOCK - 4321, dtype=np.uint8).tobytes()
+    bases = [str(tmp_path / name) for name in ("pallas", "numpy")]
+    for base in bases:
+        with open(base + ".dat", "wb") as f:
+            f.write(blob)
+    pc = PallasCoder(block_n=4096)
+    roofline.LEDGER.reset()
+    roofline.set_armed(True)
+    try:
+        encoder.write_ec_files(bases[0], coder=pc, chunk_size=BLOCK)
+        assert not roofline.LEDGER.recent()
+        assert not roofline.LEDGER.kernel_table()
+        assert roofline.LEDGER.has_rows()
+        doc = roofline.debug_doc("n:1", "volume")
+        assert doc["devices"] and doc["peaks"]
+        got = doc["seal_inflight"]
+        assert got["ready"] + got["waited"] == 2
+        rows = {r["kernel"]: r["count"] for r in doc["kernels"]}
+        assert rows["seal.dispatch"] == rows["seal.drain"] == 2
+        data = np.zeros((10, BLOCK), np.uint8)
+        if fused == "1":
+            pc.encode_with_crc(data)
+        else:
+            pc.encode(data)
+        assert [r["kernel"] for r in roofline.LEDGER.recent()] == [kernel]
+    finally:
+        roofline.LEDGER.reset()
+    encoder.write_ec_files(bases[1], coder=NumpyCoder(), chunk_size=BLOCK)
+    ecc = [ShardChecksums.load(base) for base in bases]
+    for sid in range(14):
+        with open(bases[0] + to_ext(sid), "rb") as a, \
+                open(bases[1] + to_ext(sid), "rb") as b:
+            assert a.read() == b.read(), sid
+        assert ecc[0].get(sid) == ecc[1].get(sid), sid
+
+
 def test_disarmed_path_is_one_flag_check(monkeypatch):
     """-roofline=false reduces every call site to the ARMED check: a
     booby-trapped record hook proves the accounting code is never

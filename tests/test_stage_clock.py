@@ -254,6 +254,21 @@ def test_cluster_roofline_prints_stages_in_a_section_of_their_own(
     assert "encode_kernel" in head and "seal.write_data" not in head
     assert "seal.write_data" in tail and "encode_kernel" not in tail
     assert "seal.stack host buffers: " in tail and " MiB held" in tail
+    assert "seal.drain: " in tail and " waited for" in tail
+
+
+def test_debug_device_serves_seal_inflight(tmp_path, monkeypatch):
+    """Through the volume server's own handlers: `/debug/device` says,
+    beside `seal_buffers`, how the seal's drains found the oldest chunk
+    in flight, one count per drained chunk."""
+    from seaweedfs_tpu.ec import encoder
+    monkeypatch.setattr(encoder, "SEAL_INFLIGHT", encoder._InflightCount())
+    got = _stage_drive.drive(str(tmp_path))["device"]
+    drains = next(r for r in got["kernels"] if r["kernel"] == "seal.drain")
+    assert set(got["seal_inflight"]) == {"ready", "waited"}
+    assert sum(got["seal_inflight"].values()) == drains["count"] >= 1
+    assert set(got["seal_buffers"]) == {"reused", "allocated",
+                                        "held_bytes"}
 
 
 # -- (c) the annotated stages on the profiler's clock ---------------------------
