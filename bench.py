@@ -20,13 +20,7 @@ Design notes:
 - The metric is a chip metric.  Without a TPU the script exits non-zero
   and prints no result: a CPU timing is never reported under its name.
 
-`python bench.py --e2e` additionally measures the real pipelines (see
-bench_e2e) — CPU `ec.encode` of a generated volume, device
-`write_ec_files` end-to-end including disk + transfer, and the `weed
-benchmark` HTTP write/read path — and prints one JSON line per result.
-
-All diagnostics go to stderr; stdout carries exactly one JSON line per
-metric.
+All diagnostics go to stderr; stdout carries exactly one JSON line.
 """
 
 from __future__ import annotations
@@ -225,9 +219,6 @@ def bench_tpu(dev) -> dict | None:
 
 
 def main() -> int:
-    if "--e2e" in sys.argv:
-        import bench_e2e
-        return bench_e2e.main()
     from seaweedfs_tpu.utils import jaxenv
     jaxenv.place_compile_cache()
     import jax

@@ -1803,13 +1803,11 @@ class MasterServer:
                                  "violations": violations}}
 
     def _cluster_device(self, query: dict, body: bytes) -> dict:
-        """GET /cluster/device — the device roofline rollup: every
+        """GET /cluster/device — the device kernel rollup: every
         node's heartbeat-carried kernel rows merged into one cluster
-        table keyed by (kernel, codec, dtype, geometry), per-node
-        pipeline occupancy with collapse verdicts, and — only when this
-        process has itself run a kernel — its own probed peaks (a
-        separate master owns no chip and must not claim one to answer).
-        ?codec= / ?kernel= filter the table."""
+        table keyed by (kernel, codec, dtype, geometry), and per-node
+        pipeline occupancy with collapse verdicts.  ?codec= / ?kernel=
+        filter the table."""
         from ..stats import roofline as _roofline
         if not self.is_leader():
             return self._proxy_to_leader("/cluster/device", query,
@@ -1847,21 +1845,13 @@ class MasterServer:
                 m = merged.setdefault(key, {
                     "kernel": key[0], "codec": key[1],
                     "dtype": key[2], "geometry": key[3], "count": 0,
-                    "seconds": 0.0, "bytes": 0, "work": 0,
-                    "achieved_p50": None, "nodes": 0})
+                    "seconds": 0.0, "bytes": 0, "work": 0, "nodes": 0})
                 m["count"] += row.get("count", 0)
                 m["seconds"] = round(
                     m["seconds"] + row.get("seconds", 0.0), 6)
                 m["bytes"] += row.get("bytes", 0)
                 m["work"] += row.get("work", 0)
                 m["nodes"] += 1
-                p50 = row.get("achieved_p50")
-                if p50 is not None:
-                    # Worst node's median: the headline should surface
-                    # the laggard, not average it away.
-                    cur = m["achieved_p50"]
-                    m["achieved_p50"] = p50 if cur is None \
-                        else min(cur, p50)
         # In-process multi-role stacks run kernels in the master
         # process itself; fold the local ledger in under our own url.
         local = _roofline.LEDGER.heartbeat_view()
@@ -1872,7 +1862,6 @@ class MasterServer:
         table = sorted(merged.values(),
                        key=lambda m: (-m["seconds"], m["kernel"]))
         return {"ts": time.time(), "leader": self.url(),
-                "peaks": _roofline.local_peaks(),
                 "nodes": nodes, "kernels": table,
                 "warnings": warnings}
 

@@ -767,9 +767,9 @@ class JsonHttpServer:
         # ledger + budget verdicts (admission-exempt via /debug/).
         self.route("GET", "/debug/flows", lambda q, b: _flows.debug_doc(
             f"{self.host}:{self.port}", self.flow_role))
-        # Device roofline (stats/roofline.py): per-kernel achieved
-        # fractions, pipeline occupancy, probed peaks — on every role
-        # (any process can run EC kernels in-process).
+        # Device kernel ledger (stats/roofline.py): per-kernel rows
+        # and pipeline occupancy — on every role (any process can run
+        # EC kernels in-process).
         self.route("GET", "/debug/device", self._debug_device)
 
     def _debug_device(self, query: dict, body) -> dict:
@@ -1563,8 +1563,8 @@ class JsonHttpServer:
 # -- pooled HTTP client ------------------------------------------------------
 # The reference's hot path assumes connection reuse (its Go http.Client
 # pools transport connections; operation/upload_content.go:67).  A fresh
-# TCP handshake per RPC capped the write path at ~360 req/s in bench_e2e,
-# and http.client's email.parser header handling costs another
+# TCP handshake per RPC capped the write path at ~360 req/s, and
+# http.client's email.parser header handling costs another
 # ~0.25ms/request; this is a raw-socket keep-alive pool.
 
 _client_ssl_context = None

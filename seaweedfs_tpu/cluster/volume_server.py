@@ -293,9 +293,9 @@ class VolumeServer:
         self.hot = HotKeyTracker()
         s.route("GET", "/debug/hot", self._debug_hot)
         s.route("GET", "/debug/tenants", self._debug_tenants)
-        # Device roofline plane (stats/roofline.py): per-kernel
-        # achieved-fraction table, pipeline occupancy gantts, probed
-        # peaks and device memory stats.
+        # Device kernel ledger (stats/roofline.py): per-kernel and
+        # per-stage rows, pipeline occupancy gantts and device memory
+        # stats.
         s.route("GET", "/debug/device", self._debug_device)
         s.route("GET", "/admin/volume_file", self._volume_file)
         s.route("POST", "/admin/copy_volume", self._copy_volume)
@@ -1883,11 +1883,10 @@ class VolumeServer:
         return out
 
     def _debug_device(self, query: dict, body: bytes) -> dict:
-        """GET /debug/device — the device roofline plane: probed
-        peaks, per-kernel achieved-fraction table, recent invocations,
-        pipeline occupancy gantts with bubble attribution, the
-        analytic-vs-measured byte conservation verdict, and
-        jax.local_devices() memory stats."""
+        """GET /debug/device — the device kernel ledger: per-kernel
+        and per-stage rows, recent invocations, pipeline occupancy
+        gantts with bubble attribution, the analytic-vs-measured byte
+        conservation verdict, and jax.local_devices() memory stats."""
         return _roofline.debug_doc(self.url(), "volume")
 
     def _ui(self, query: dict, body: bytes):

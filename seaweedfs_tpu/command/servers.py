@@ -93,7 +93,12 @@ def _wait_forever(servers: list, grace: float | None = None) -> int:
     signal.signal(signal.SIGINT, handler)
     signal.signal(signal.SIGTERM, handler)
     try:
-        stop.wait()
+        # Timed, and again: the kernel may hand the signal to ANY
+        # thread, and CPython runs `handler` in this one the next time
+        # it executes bytecode — never, inside an untimed wait, which
+        # only a signal delivered to this very thread interrupts.
+        while not stop.wait(0.5):
+            pass
     finally:
         # Graceful lifecycle: SIGTERM/SIGINT first DRAINS every role
         # that supports it — refuse new writes (503 + Retry-After so

@@ -35,12 +35,15 @@ from ..storage.needle_map import MemDb
 # LARGE_BLOCK_SIZE and be a multiple of SMALL_BLOCK_SIZE.
 DEFAULT_CHUNK = 4 * 1024 * 1024
 
+# Chunks `_pipelined_encode` keeps in flight between dispatch and drain.
+SEAL_DEPTH = 2
+
 # Host buffers of one default chunk (40 MiB) that stay with the process
 # between seals: what one job has live (`_pipelined_encode`), 200 MiB.
 # A fresh buffer costs a page fault per 4 KiB on first touch — as much
 # as reading the chunk in place saves — so that is paid once per
 # process, not once per chunk or per job.
-CHUNK_POOL_BUFFERS = 5
+CHUNK_POOL_BUFFERS = 2 * SEAL_DEPTH + 1
 
 
 class _ChunkPool:
@@ -318,8 +321,7 @@ def _chunk_reader(dat, dat_size: int, large: int, small: int,
 
 
 def _pipelined_encode(fd: int, spans, coder: ErasureCoder, outputs,
-                      depth: int = 2, accs=None,
-                      clock: StageClock | None = None):
+                      accs=None, clock: StageClock | None = None):
     """Double-buffered encode pipeline (SURVEY §2.3 'double-buffered
     host→HBM DMA + batched kernel launches') over the chunks `spans`
     (`_chunk_spans`) of the file `fd`, each step a stage of `clock`
@@ -331,7 +333,7 @@ def _pipelined_encode(fd: int, spans, coder: ErasureCoder, outputs,
                       issue H2D of k, launch,
                         request D2H            seal.dispatch
                       write data shards of k   seal.write_data
-                      collect parity of k-depth+1  seal.drain
+                      collect parity of k-1    seal.drain
                       hand its buffer back
                       write it                 seal.write_parity
 
@@ -347,10 +349,11 @@ def _pipelined_encode(fd: int, spans, coder: ErasureCoder, outputs,
     dispatch and its arrays count as ready.  A device error surfaces
     at the drain.
 
-    The chunks live in `2 * depth + 1` buffers of `CHUNK_POOL` (`depth`
-    in flight, `depth` read ahead, one being filled), and that count is
-    what bounds the read-ahead.  One ownership rule: a buffer goes back
-    to the pool, and so to the reader, only when its chunk is finished
+    The chunks live in the `CHUNK_POOL_BUFFERS` buffers of `CHUNK_POOL`
+    (`SEAL_DEPTH` in flight, as many read ahead, one being filled), and
+    that count is what bounds the read-ahead.  One ownership rule: a
+    buffer goes back to the pool, and so to the reader, only when its
+    chunk is finished
     — its data shards written AND its parity drained.  Until then the
     coder may still read it: a device coder transfers asynchronously,
     and on the CPU platform `jnp.asarray` may alias the host array.
@@ -366,7 +369,7 @@ def _pipelined_encode(fd: int, spans, coder: ErasureCoder, outputs,
     if clock is None:
         clock = StageClock()
     q: "queue.Queue" = queue.Queue()
-    free = threading.Semaphore(2 * depth + 1)
+    free = threading.Semaphore(CHUNK_POOL_BUFFERS)
     cancelled = threading.Event()
     error: list[BaseException] = []
 
@@ -446,7 +449,7 @@ def _pipelined_encode(fd: int, spans, coder: ErasureCoder, outputs,
             with clock("seal.write_data", data.nbytes):
                 for i in range(data_shards):
                     _shard_write(outputs[i], i, data[i].tobytes(), accs)
-            if len(inflight) >= depth:
+            if len(inflight) >= SEAL_DEPTH:
                 flush_one()
         while inflight:
             flush_one()

@@ -22,7 +22,6 @@ The same kernel serves encode (B = parity bit-matrix) and reconstruction
 from __future__ import annotations
 
 import functools
-import os
 import time
 
 import jax
@@ -65,17 +64,6 @@ def _observe_call(kernel: str, coder, t0: float, *, out_rows: int,
                          in_rows=in_rows, n=n, crc=crc, seconds=dt,
                          measured_bytes=(in_rows + out_rows) * n)
 
-
-def _prof_on() -> bool:
-    """Whether `reconstruct`, this switch's one reader, fences and
-    records its call (SEAWEEDFS_TPU_EC_PROF=0 to disable).  Its callers
-    (the rebuild's serial loop, degraded reads) stage each result to
-    the host right away, so the fence costs them nothing.  The encodes
-    do not ask: `encode` / `encode_with_crc` always fence and record,
-    and the one caller that drains later, the seal's pipeline, calls
-    `encode_unfenced`."""
-    return os.environ.get("SEAWEEDFS_TPU_EC_PROF", "1") \
-        not in ("0", "false")
 
 # Lane-dimension tile: one grid step processes k x BLOCK_N bytes.
 # 8k x BLOCK_N bf16 bit planes = 80*4096*2B = 640KB VMEM for RS(10,4) —
@@ -321,18 +309,15 @@ class PallasCoder:
     def __init__(self, data_shards: int = 10, parity_shards: int = 4,
                  matrix_kind: str = "vandermonde",
                  interpret: bool | None = None,
-                 block_n: int | None = None, mm: str | None = None,
-                 codec=None):
+                 mm: str | None = None, codec=None):
         from ..codecs import get_codec, rs_codec
         from .coder_jax import plane_major
 
-        self.block_n = block_n or int(
-            os.environ.get("SEAWEEDFS_TPU_BLOCK_N", BLOCK_N))
+        self.block_n = BLOCK_N
         # int8 is exact for 0/1 bit planes (int32 accumulation;
         # correctness-gated vs NumpyCoder in tests/test_ecpipe.py) and
         # the MXU's faster dtype; bf16 stays the off-TPU default.
-        self.mm = mm or os.environ.get("SEAWEEDFS_TPU_MM") \
-            or ("int8" if _on_tpu() else "bf16")
+        self.mm = mm or ("int8" if _on_tpu() else "bf16")
         self.codec = rs_codec(data_shards, parity_shards, matrix_kind) \
             if codec is None else get_codec(codec)
         self.data_shards = self.codec.data_shards
@@ -380,10 +365,6 @@ class PallasCoder:
         return data
 
     def _launch_crc(self, data: jax.Array) -> tuple[jax.Array, jax.Array]:
-        if not self.fused_crc_ok:
-            raise ValueError(
-                f"block_n {self.block_n} does not divide the .ecc "
-                f"block {crc_fold.BLOCK}")
         if self._crc_consts is None:
             self._crc_consts = crc_kernel_consts(self.block_n)
         return apply_bitmatrix_crc_pallas(
@@ -460,9 +441,8 @@ class PallasCoder:
             return {}
         mat_pm, used = self._decode_mat_pm(present, tuple(wanted))
         stacked = jnp.stack([jnp.asarray(shards[s], jnp.uint8) for s in used])
-        if not _prof_on():
-            rec = self._apply(mat_pm, stacked, len(wanted))
-            return {w: rec[i] for i, w in enumerate(wanted)}
+        # The callers (the rebuild's serial loop, degraded reads) stage
+        # each result to the host at once: the fence costs them nothing.
         t0 = time.perf_counter()
         rec = jax.block_until_ready(
             self._apply(mat_pm, stacked, len(wanted)))

@@ -12,7 +12,11 @@ interface seam that picks a backend at startup.  Backends:
 
 Selection: SEAWEEDFS_TPU_CODER env var, else pallas on TPU, else native
 if built, else jax (`default_backend`; no fallback when the device
-cannot be reached).
+cannot be reached).  That variable and SEAWEEDFS_TPU_EC_FUSED_CRC
+(ops/crc_fold.py) are the hot layer's only hand-set switches: they are
+how the benchmark's CPU rehearsal, `chip_smoke.py --rehearse-cpu` and
+the tests run the device path where there is no chip, and how the
+fused CRC will be measured against the CPU pass (ROADMAP A8).
 All backends share the same API: encode / encode_all / reconstruct / verify,
 operating on (shards, n) uint8 arrays; results are byte-identical.
 """

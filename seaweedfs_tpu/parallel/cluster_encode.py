@@ -65,24 +65,15 @@ from .stream_pipeline import PipelineRecorder, run_pipeline
 _COL_ALIGN = 2048
 
 
-def pipeline_depth(depth: int | None = None) -> int:
-    """Chunks in flight between prefetch and drain.  0 = the fully
-    serialized legacy loop (the measured baseline in bench_e2e.py)."""
-    if depth is not None:
-        return depth
-    return int(os.environ.get("SEAWEEDFS_TPU_EC_PIPELINE_DEPTH", "2"))
-
+# Chunks in flight between prefetch and drain (stream_pipeline.py).
+PIPELINE_DEPTH = 2
 
 fused_crc_enabled = crc_fold.fused_crc_enabled
 
-
-def scatter_budget_bytes() -> int:
-    """Cap on concurrent in-flight shard payload bytes during scatter —
-    a 30GB volume batch must not hold ~14 whole shard files in memory
-    at once (shards are read inside the budgeted workers, not up
-    front)."""
-    return int(os.environ.get("SEAWEEDFS_TPU_EC_SCATTER_BUDGET",
-                              str(256 << 20)))
+# Cap on concurrent in-flight shard payload bytes during scatter — a
+# 30GB volume batch must not hold ~14 whole shard files in memory at
+# once (shards are read inside the budgeted workers, not up front).
+SCATTER_BUDGET_BYTES = 256 << 20
 
 
 class _ByteBudget:
@@ -155,13 +146,11 @@ class _BufferPool:
 
 def batch_encode(env, vids, mesh=None, max_batch_bytes=1 << 28,
                  workers: int = 8, chunk_size: int = DEFAULT_CHUNK,
-                 progress=None, codec=None,
-                 depth: int | None = None) -> list[str]:
+                 progress=None, codec=None) -> list[str]:
     """EC-encode `vids` across the cluster in mesh-batched steps.
     Returns one human-readable line per volume.  `codec` selects the
     erasure codec ("rs" default / "lrc"): the generator matrix, shard
     count, and the .vif codec id pushed to every holder derive from it.
-    `depth` overrides the stream-pipeline depth (0 = serialized).
 
     env: duck-typed cluster view (shell CommandEnv): volume_locations,
     data_nodes, vs_call.
@@ -181,7 +170,6 @@ def batch_encode(env, vids, mesh=None, max_batch_bytes=1 << 28,
             f"chunk_size {chunk_size} must divide the large block "
             f"size {LARGE_BLOCK_SIZE}")
     codec = get_codec(codec)
-    depth = pipeline_depth(depth)
     if mesh is None:
         mesh = make_mesh()
     # One size map per batch call — not an O(volumes x nodes) rescan
@@ -214,8 +202,7 @@ def batch_encode(env, vids, mesh=None, max_batch_bytes=1 << 28,
                 total += sizes.get(targets[i][0], 0)
                 i += 1
             messages += _encode_batch_group(env, mesh, pool, batch,
-                                            chunk_size, progress,
-                                            codec, depth)
+                                            chunk_size, progress, codec)
     finally:
         # cancel_futures: queued fetch/scatter work from a failed batch
         # must not keep running (and keep connections pinned) after the
@@ -249,7 +236,7 @@ def _fetch_volume(tmpdir: str, vid: int, locs: list[str]) -> str:
 
 
 def _encode_batch_group(env, mesh, pool, batch, chunk_size,
-                        progress, codec, depth) -> list[str]:
+                        progress, codec) -> list[str]:
     """Fetch, mesh-encode, scatter one sub-batch of volumes — journaled
     as ec.encode.start/finish with per-stage byte/second attrs, under a
     root span so the timeline row links to a /debug/traces trace."""
@@ -263,7 +250,7 @@ def _encode_batch_group(env, mesh, pool, batch, chunk_size,
         try:
             out = _encode_batch_group_inner(env, mesh, pool, batch,
                                             chunk_size, progress,
-                                            stages, codec, depth)
+                                            stages, codec)
         except Exception as e:
             emit_event("ec.encode.finish", severity="error",
                        volumes=vids, batch=True, codec=codec.name,
@@ -272,14 +259,14 @@ def _encode_batch_group(env, mesh, pool, batch, chunk_size,
                        **stage_attrs(stages))
             raise
         emit_event("ec.encode.finish", volumes=vids, batch=True,
-                   codec=codec.name, pipeline_depth=depth,
+                   codec=codec.name, pipeline_depth=PIPELINE_DEPTH,
                    seconds=round(time.perf_counter() - t0, 6),
                    **stage_attrs(stages))
         return out
 
 
 def _encode_batch_group_inner(env, mesh, pool, batch, chunk_size,
-                              progress, stages, codec, depth) -> list[str]:
+                              progress, stages, codec) -> list[str]:
     """Fetch, stream-encode, scatter one sub-batch of volumes."""
     from ..shell.command_ec import balanced_distribution, collect_ec_nodes
     vol_axis = mesh.shape["vol"]
@@ -320,7 +307,7 @@ def _encode_batch_group_inner(env, mesh, pool, batch, chunk_size,
                             min(chunk_size, LARGE_BLOCK_SIZE)), align)
         v_cap = _pad_to(len(bases), vol_axis)
         cancel = threading.Event()
-        buffers = _BufferPool(max(2, depth + 1),
+        buffers = _BufferPool(PIPELINE_DEPTH + 1,
                               (v_cap, DATA_SHARDS, n_cap),
                               cancel=cancel)
         # Always-on (bounded) production recorder: per-batch stage
@@ -439,7 +426,7 @@ def _encode_batch_group_inner(env, mesh, pool, batch, chunk_size,
                     rec.note_span("drain", bi, t_wr, t_wr1)
                 buffers.release(buf)
 
-            run_pipeline(produce(), dispatch, drain, depth=depth,
+            run_pipeline(produce(), dispatch, drain, depth=PIPELINE_DEPTH,
                          cancel=cancel, recorder=rec)
             for w in writers:
                 w.finish()
@@ -463,7 +450,7 @@ def _encode_batch_group_inner(env, mesh, pool, batch, chunk_size,
         # pass over the pushed bytes), then shards under the byte
         # budget, then .ecx/.vif, mount, delete the originals
         # (command_ec_encode.go flow).
-        budget = _ByteBudget(scatter_budget_bytes())
+        budget = _ByteBudget(SCATTER_BUDGET_BYTES)
         for b_idx, ((vid, locs), base) in enumerate(zip(batch, bases)):
             plan = balanced_distribution(collect_ec_nodes(env),
                                          n_shards=codec.total_shards)

@@ -250,10 +250,9 @@ def _pad_to(n: int, align: int) -> int:
 
 def batch_rebuild(env, vids=None, mesh=None, max_batch_bytes=1 << 28,
                   workers: int = 16, matrix_kind: str = "vandermonde",
-                  progress=None, depth: int | None = None) -> list[str]:
+                  progress=None) -> list[str]:
     """Rebuild all missing EC shards across the cluster in mesh-batched
     compiled steps.  Returns one human-readable line per volume.
-    `depth` overrides the stream-pipeline depth (0 = serialized).
 
     env: duck-typed cluster view (shell CommandEnv): ec_shard_locations,
     data_nodes, vs_call.
@@ -273,7 +272,7 @@ def batch_rebuild(env, vids=None, mesh=None, max_batch_bytes=1 << 28,
             messages += _rebuild_group(
                 env, mesh, pool, picker, get_codec(codec_name),
                 present, missing, entries, max_batch_bytes,
-                matrix_kind, progress, depth)
+                matrix_kind, progress)
     finally:
         # cancel_futures: a failed group must not leave queued shard
         # fetches/pushes running (and holders busy) after the
@@ -284,7 +283,7 @@ def batch_rebuild(env, vids=None, mesh=None, max_batch_bytes=1 << 28,
 
 def _rebuild_group(env, mesh, pool, picker, codec, present, missing,
                    entries, max_batch_bytes, matrix_kind,
-                   progress, depth: int | None = None) -> list[str]:
+                   progress) -> list[str]:
     """One (codec, survivor-signature) group — journaled as
     ec.rebuild.start/finish with per-stage byte/second attrs plus the
     planner's planned-vs-RS read accounting, under a root span so the
@@ -303,7 +302,7 @@ def _rebuild_group(env, mesh, pool, picker, codec, present, missing,
             out = _rebuild_group_inner(env, mesh, pool, picker, codec,
                                        present, missing, entries,
                                        max_batch_bytes, matrix_kind,
-                                       progress, stages, report, depth)
+                                       progress, stages, report)
         except Exception as e:
             emit_event("ec.rebuild.finish", severity="error",
                        volumes=vids, batch=True, missing=list(missing),
@@ -323,15 +322,14 @@ def _rebuild_group(env, mesh, pool, picker, codec, present, missing,
 
 def _rebuild_group_inner(env, mesh, pool, picker, codec, present,
                          missing, entries, max_batch_bytes, matrix_kind,
-                         progress, stages, report,
-                         depth: int | None = None) -> list[str]:
+                         progress, stages, report) -> list[str]:
     """Streamed rebuild of one survivor-signature group: the producer
     gathers + stacks the NEXT sub-batch's shards over HTTP while the
     device decodes the current one and the drain thread scatters
     completed shards — gather, decode and scatter overlap instead of
     serializing (stream_pipeline.py; sums of the batch_* stage
     histograms exceed the wall clock when the overlap is working)."""
-    from .cluster_encode import fused_crc_enabled, pipeline_depth
+    from .cluster_encode import PIPELINE_DEPTH, fused_crc_enabled
     # The codec's planned read set, not "first data_shards survivors":
     # an in-group LRC loss gathers 5 shards per volume instead of 10.
     _mat, used = codec.decode_matrix(present, missing)
@@ -343,7 +341,6 @@ def _rebuild_group_inner(env, mesh, pool, picker, codec, present,
     block = SMALL_BLOCK_SIZE
     align = block * col_axis if fused \
         else _pad_to(_COL_ALIGN, col_axis * 8)
-    depth = pipeline_depth(depth)
     out: list[str] = []
     saved = f" ({codec.name}: read {len(used)} shards vs " \
             f"{codec.data_shards} for RS)" \
@@ -471,7 +468,8 @@ def _rebuild_group_inner(env, mesh, pool, picker, codec, present,
         if rec is not None:
             rec.note_span("drain", bi, t_scatter, t_send)
 
-    run_pipeline(produce(), dispatch, drain, depth=depth, recorder=rec)
+    run_pipeline(produce(), dispatch, drain, depth=PIPELINE_DEPTH,
+                 recorder=rec)
     if rec is not None:
         _roofline.LEDGER.note_pipeline("rebuild", rec)
     return out

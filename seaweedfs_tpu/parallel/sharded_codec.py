@@ -452,9 +452,8 @@ def assert_no_collectives(mesh: Mesh, parity_shards: int,
                           shape: tuple[int, int, int]) -> str:
     """Compile the sharded batch-encode step for `shape` and assert the
     HLO contains no cross-chip collectives — parity and CRCs are
-    columnwise, so no cross-chip bytes should exist to move.  Shared by
-    the ecpipe test suite and bench_e2e's MULTICHIP row (one copy, one
-    collective-name list).  Returns the HLO text."""
+    columnwise, so no cross-chip bytes should exist to move.  Returns
+    the HLO text."""
     import re
 
     from ..codecs import get_codec

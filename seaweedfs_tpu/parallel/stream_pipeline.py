@@ -17,9 +17,6 @@ host memory — the "reusable pinned host buffer" discipline is the
 caller's (cluster_encode keeps a buffer pool sized to the pipeline
 depth and recycles a buffer only after its chunk drains).
 
-``depth=0`` degenerates to the fully serialized loop — the measured
-baseline `bench_e2e.py` compares against.
-
 The ``recorder`` hook exists for the overlap regression test: every
 stage transition is recorded with an injectable clock (no sleeps, no
 wall-time flakiness) so a test can assert the next H2D was issued
@@ -235,19 +232,6 @@ def run_pipeline(items: Iterable[Any],
     can share it: when any stage dies, the flag is set and the
     producer's own blocking waits can observe it instead of waiting on
     a release that will never come."""
-    if depth <= 0:
-        n = 0
-        for i, item in enumerate(items):
-            if recorder:
-                recorder.record("produced", i)
-                recorder.record("dispatched", i)
-            handle = dispatch(item)
-            drain(handle)
-            if recorder:
-                recorder.record("drained", i)
-            n += 1
-        return n
-
     q_in: "queue.Queue" = queue.Queue(maxsize=depth)
     q_out: "queue.Queue" = queue.Queue(maxsize=depth)
     cancelled = cancel if cancel is not None else threading.Event()

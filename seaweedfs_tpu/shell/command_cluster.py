@@ -376,19 +376,16 @@ class ClusterFlows(Command):
 class ClusterRoofline(Command):
     name = "cluster.roofline"
     help = ("cluster.roofline [-node host:port] [-kernel K] [-codec C] "
-            "[-save out.json] [-diff baseline.json] — the device "
-            "roofline rollup from the master's /cluster/device (or one "
-            "node's /debug/device with -node): probed peaks, the "
-            "per-kernel table (count, seconds, bytes, GF(2) work, "
-            "achieved fraction of roofline p50/p95), per-node pipeline "
-            "occupancy with bubble attribution, and collapse "
-            "warnings.  -save writes the table as JSON; -diff ranks "
-            "achieved-fraction deltas vs a saved baseline (the "
-            "kernel-regression gate)")
+            "— the device kernel rollup from the master's "
+            "/cluster/device (or one node's /debug/device with -node): "
+            "the per-kernel table (count, fenced seconds, bytes, GF(2) "
+            "work), the EC file pipeline's stage rows, per-node "
+            "pipeline occupancy with bubble attribution, and collapse "
+            "warnings.  A kernel's share of its roofline is the "
+            "benchmark's to compute, from the device trace")
 
     def do(self, args: list[str], env: CommandEnv) -> str:
         flags, _rest = self.parse_flags(args)
-        import json as _json
         if flags.get("node"):
             node = flags["node"]
             base = node if "://" in node else f"http://{node}"
@@ -422,31 +419,16 @@ class ClusterRoofline(Command):
                 table = [r for r in table
                          if r["codec"] == flags["codec"]]
         lines = []
-        peaks = doc.get("peaks") or {}
-        mm = peaks.get("matmul_flops") or {}
-        if peaks:
-            mmtxt = "  ".join(
-                f"{d}={v / 1e9:.1f}GF/s" for d, v in sorted(mm.items())
-                if v)
-            lines.append(
-                f"peaks[{peaks.get('backend', '?')}]: {mmtxt}  "
-                f"membw={peaks.get('membw_bps', 0) / 1e9:.2f}GB/s  "
-                f"h2d={peaks.get('h2d_bps', 0) / 1e9:.2f}GB/s")
         if table:
-            lines.append("")
             lines.append(f"{'KERNEL':22} {'CODEC':12} {'DTYPE':5} "
                          f"{'GEOMETRY':16} {'COUNT':>7} {'SECONDS':>9} "
-                         f"{'BYTES':>13} {'WORK':>15} {'P50':>6} "
-                         f"{'P95':>6}")
+                         f"{'BYTES':>13} {'WORK':>15}")
             for r in table:
-                p50, p95 = r.get("achieved_p50"), r.get("achieved_p95")
                 lines.append(
                     f"{r['kernel']:22} {r['codec']:12} {r['dtype']:5} "
                     f"{r['geometry']:16} {r['count']:7d} "
                     f"{r['seconds']:9.4f} {r['bytes']:13d} "
-                    f"{r['work']:15d} "
-                    f"{'-' if p50 is None else format(p50, '6.3f')} "
-                    f"{'-' if p95 is None else format(p95, '6.3f')}")
+                    f"{r['work']:15d}")
         else:
             lines.append("no kernel invocations recorded yet")
         if stages:
@@ -493,48 +475,6 @@ class ClusterRoofline(Command):
             lines.extend(occ_lines)
         for w in doc.get("warnings", []):
             lines.append(f"  !! {w}")
-        if flags.get("save"):
-            with open(flags["save"], "w") as f:
-                _json.dump({"ts": time.time(), "kernels": table}, f,
-                           indent=2, sort_keys=True)
-            lines.append("")
-            lines.append(f"wrote {len(table)} kernel rows to "
-                         f"{flags['save']}")
-        if flags.get("diff"):
-            try:
-                with open(flags["diff"]) as f:
-                    base_doc = _json.load(f)
-            except (OSError, ValueError) as e:
-                raise ShellError(
-                    f"cannot read baseline {flags['diff']}: {e}") \
-                    from None
-            base = {(r["kernel"], r["codec"], r["dtype"],
-                     r["geometry"]): r
-                    for r in base_doc.get("kernels", [])}
-            cur = {(r["kernel"], r["codec"], r["dtype"],
-                    r["geometry"]): r for r in table}
-            deltas = []
-            for key in set(base) | set(cur):
-                b = (base.get(key) or {}).get("achieved_p50")
-                c = (cur.get(key) or {}).get("achieved_p50")
-                if b is None and c is None or b == c:
-                    continue
-                deltas.append((key, b, c,
-                               (c or 0.0) - (b or 0.0)))
-            deltas.sort(key=lambda d: d[3])
-            lines.append("")
-            lines.append(f"{'DELTA':>7}  {'BASE':>6}  {'NOW':>6}  "
-                         "KERNEL/CODEC/DTYPE/GEOMETRY (achieved p50; "
-                         "negative = regression)")
-            for key, b, c, d in deltas:
-                lines.append(
-                    f"{d:+7.3f}  "
-                    f"{'-' if b is None else format(b, '6.3f')}  "
-                    f"{'-' if c is None else format(c, '6.3f')}  "
-                    f"{'/'.join(key)}")
-            if not deltas:
-                lines.append("no achieved-fraction movement vs "
-                             "baseline")
         return "\n".join(lines)
 
 

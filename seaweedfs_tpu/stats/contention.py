@@ -307,8 +307,8 @@ def setup_contention_routes(server) -> None:
     Also mounts POST /debug/attribution?enabled=0|1 — the restart-free
     kill switch for the whole plane (lock metering + phase ledger +
     continuous profiler).  Operationally: disarm to rule the plane out
-    while chasing a regression; it is also how BENCH_load_r02 prices
-    the plane A/B on ONE cluster instance, immune to instance-level
+    while chasing a regression; it also lets one cluster instance be
+    measured with and without the plane, immune to instance-level
     variance (allocator layout, ASLR) that dwarfs a 2% effect."""
 
     def _locks(query: dict, body: bytes):

@@ -30,10 +30,10 @@ The closed ledger rides three surfaces:
 
 Because `handler` is computed as the residual of the dispatch wall,
 the non-queue phases always sum to the observed request seconds — the
-"budget sums to the wall" invariant tests and BENCH_load gate on.
+"budget sums to the wall" invariant the tests gate on.
 
 Cost design — this runs on EVERY request, so the plane must price in
-low single-digit microseconds (the BENCH_load_r02 <3% gate):
+low single-digit microseconds:
 
 - one Ledger per serving THREAD, reused across its keep-alive
   requests (no per-request allocation); phases accumulate into a

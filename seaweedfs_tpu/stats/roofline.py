@@ -1,24 +1,16 @@
-"""Device roofline plane: per-kernel work accounting and achieved
-fraction of the device's measured peaks.
+"""Device kernel ledger and the EC file pipeline's stage clock.
 
-ROADMAP item 4 (XOR elimination, sparser RS realizations, deeper
-pipeline overlap) is gated on claims that need device-side evidence:
-"encode sits at N% of the shape ceiling" must come from measurement,
-not hand math.  This module is that evidence plane, the device-side
-sibling of the PR 9 time-attribution plane:
+What a process that runs EC kernels counts about them, served at
+`GET /debug/device` and merged by the master at `/cluster/device`:
 
 - an analytic per-invocation cost model — bit-matrix geometry
   (out_rows, in_rows, n, batch) -> bytes moved, GF(2) MACs,
   arithmetic intensity — mirroring the `pl.CostEstimate` the Pallas
   kernels declare (ops/coder_pallas.py);
-- a once-per-process `probe_peaks()` micro-bench (device matmul peak
-  per mm dtype, on-device memory bandwidth, H2D/D2H transfer, host
-  stream bandwidth), cached to disk keyed by backend + device kind so
-  a process restart does not re-pay the probe;
-- a bounded invocation ring + windowed achieved-fraction sketches
-  keyed by (kernel, codec, dtype, geometry), fed by every
-  execution-fenced kernel call (the fence is the caller's job — a
-  dispatch-only wall would flatter the kernel);
+- a bounded invocation ring and absolute totals (count, seconds,
+  bytes, work) keyed by (kernel, codec, dtype, geometry), fed by
+  every execution-fenced kernel call (the fence is the caller's job —
+  a dispatch-only wall would flatter the kernel);
 - always-on pipeline occupancy: `cluster_encode`/`cluster_rebuild`
   hand their per-batch stage spans (stack | dispatch | device | drain)
   to `note_pipeline()`, which keeps recent gantts, publishes the
@@ -33,6 +25,11 @@ sibling of the PR 9 time-attribution plane:
 - the mark that an EC admin job runs in the process (`ec_job`), and
   two rows among the stage rows for the needle requests the volume
   server answered beside a job and alone (`note_request`).
+
+The program computes no share of a roofline: its walls are host fences
+and it knows no peak of the device.  That number is the benchmark's,
+from the device trace against published peaks (`benchmark/work.py`),
+and it reads from here the rows' count, seconds and bytes only.
 
 Like the other planes the kernel catalog is closed (recording an
 uncataloged kernel raises), the ledger is a process singleton with
@@ -49,7 +46,6 @@ actually move is worse than no model.
 from __future__ import annotations
 
 import contextlib
-import json
 import os
 import sys
 import threading
@@ -57,7 +53,6 @@ import time
 from collections import deque
 
 from .metrics import Counter, Gauge
-from .sketch import WindowedSketch
 
 # -- arming ------------------------------------------------------------------
 # One module-level flag; disarmed call sites pay exactly this check
@@ -222,8 +217,8 @@ def cost_model(out_rows: int, in_rows: int, n: int, *, batch: int = 1,
 
     Returns bytes moved (read + written payload), GF(2) MACs (one MAC
     = one AND+XOR bit op on a byte lane), flops (2*MACs, the matmul
-    convention the probe and the Pallas CostEstimate both use), and
-    arithmetic intensity (flops per byte)."""
+    convention the Pallas CostEstimate uses), and arithmetic intensity
+    (flops per byte)."""
     b = int(batch)
     nbytes = (in_rows + out_rows) * n * b
     macs = 8 * out_rows * 8 * in_rows * n * b
@@ -247,205 +242,6 @@ def geometry_key(out_rows: int, in_rows: int, n: int,
     return f"{out_rows}x{in_rows}x{n}"
 
 
-# -- GF(2) work: dense vs post-elimination -----------------------------------
-# The bench publishes effective (post-elimination) XOR work beside the
-# dense count per codec, so matrix-scheduling work (arxiv 2108.02692,
-# arxiv 1312.5155) lands against an already-published baseline column.
-
-
-def dense_gf2_work(bitmatrix) -> int:
-    """XOR count of the naive schedule: each output bit-row of weight
-    w costs w-1 XORs (w ANDs are free against constant 0/1 entries)."""
-    import numpy as np
-    bm = (np.asarray(bitmatrix) & 1).astype(np.uint8)
-    weights = bm.sum(axis=1)
-    return int(np.maximum(weights.astype(np.int64) - 1, 0).sum())
-
-
-def effective_gf2_work(bitmatrix, max_rounds: int = 100000) -> int:
-    """XOR count after greedy common-subexpression elimination (Paar's
-    algorithm): repeatedly factor out the column pair shared by the
-    most output rows.  Deterministic (ties break to the smallest
-    pair), exact on the matrices we ship (tens of rows/columns)."""
-    import numpy as np
-    bm = (np.asarray(bitmatrix) & 1).astype(np.uint8)
-    rows = [set(np.flatnonzero(r).tolist()) for r in bm]
-    next_col = bm.shape[1]
-    extracted = 0
-    for _ in range(max_rounds):
-        counts: dict[tuple[int, int], int] = {}
-        for r in rows:
-            rs = sorted(r)
-            for i in range(len(rs)):
-                for j in range(i + 1, len(rs)):
-                    p = (rs[i], rs[j])
-                    counts[p] = counts.get(p, 0) + 1
-        if not counts:
-            break
-        best = max(counts.values())
-        if best < 2:
-            break
-        pair = min(p for p, c in counts.items() if c == best)
-        a, b = pair
-        for r in rows:
-            if a in r and b in r:
-                r.discard(a)
-                r.discard(b)
-                r.add(next_col)
-        next_col += 1
-        extracted += 1
-    return extracted + sum(max(len(r) - 1, 0) for r in rows)
-
-
-# -- peak probing ------------------------------------------------------------
-
-_PEAKS_VERSION = 2
-_PROBE_DTYPES = ("int8", "bf16")
-_peaks_lock = threading.Lock()
-_peaks: dict | None = None
-
-
-def _cache_dir() -> str:
-    d = os.environ.get("SEAWEEDFS_TPU_ROOFLINE_CACHE", "")
-    if d:
-        return d
-    from ..utils import jaxenv
-    return os.path.join(jaxenv.cache_root(), "roofline")
-
-
-def _cache_path(backend: str, kind: str) -> str:
-    safe = "".join(c if c.isalnum() or c in "-_." else "_"
-                   for c in f"{backend}_{kind}")
-    return os.path.join(_cache_dir(), f"roofline_peaks_{safe}.json")
-
-
-def _best_of(fn, reps: int = 3) -> float:
-    best = float("inf")
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        fn()
-        best = min(best, time.perf_counter() - t0)
-    return best
-
-
-def _probe_matmul(jnp, jax, dtype: str, m: int = 256) -> float:
-    """Measured matmul flops/s for one mm dtype (int8 accumulating to
-    int32, bf16 to f32 — the two dtypes PallasCoder dispatches)."""
-    if dtype == "int8":
-        a = jnp.ones((m, m), jnp.int8)
-        acc = jnp.int32
-    else:
-        a = jnp.ones((m, m), jnp.bfloat16)
-        acc = jnp.float32
-
-    @jax.jit
-    def mm(x, y):
-        return jax.lax.dot_general(
-            x, y, (((1,), (0,)), ((), ())), preferred_element_type=acc)
-
-    jax.block_until_ready(mm(a, a))  # compile outside the clock
-    t = _best_of(lambda: jax.block_until_ready(mm(a, a)))
-    return 2.0 * m ** 3 / max(t, 1e-9)
-
-
-def _probe_membw(jnp, jax, nbytes: int = 1 << 23) -> float:
-    """On-device streaming bandwidth: one read + one write pass."""
-    x = jnp.ones((nbytes,), jnp.uint8)
-
-    @jax.jit
-    def touch(v):
-        return v + 1
-
-    jax.block_until_ready(touch(x))
-    t = _best_of(lambda: jax.block_until_ready(touch(x)))
-    return 2.0 * nbytes / max(t, 1e-9)
-
-
-def _probe_transfers(np, jax, nbytes: int = 1 << 23) -> tuple:
-    host = np.ones(nbytes, np.uint8)
-    dev = jax.block_until_ready(jax.device_put(host))
-    h2d = nbytes / max(
-        _best_of(lambda: jax.block_until_ready(jax.device_put(host))),
-        1e-9)
-    d2h = nbytes / max(_best_of(lambda: np.asarray(dev)), 1e-9)
-    stream = 2.0 * nbytes / max(_best_of(host.copy), 1e-9)
-    return h2d, d2h, stream
-
-
-def probe_peaks(force: bool = False) -> dict:
-    """Once-per-process measured device peaks, disk-cached keyed by
-    (backend, device kind) so restarts skip the micro-bench.  Every
-    probe is best-of-3 with compile outside the clock; failures
-    degrade to a zeroed doc rather than taking the caller down."""
-    global _peaks
-    with _peaks_lock:
-        if _peaks is not None and not force:
-            return _peaks
-        try:
-            import jax
-            import jax.numpy as jnp
-            import numpy as np
-            backend = jax.default_backend()
-            devs = jax.local_devices()
-            kind = devs[0].device_kind if devs else "unknown"
-        except Exception:  # noqa: BLE001 — no usable device stack
-            _peaks = {"version": _PEAKS_VERSION, "backend": "none",
-                      "device_kind": "none", "matmul_flops": {},
-                      "membw_bps": 0.0, "h2d_bps": 0.0, "d2h_bps": 0.0,
-                      "host_stream_bps": 0.0, "error": "jax unavailable"}
-            return _peaks
-
-        path = _cache_path(backend, kind)
-        if not force:
-            try:
-                with open(path, encoding="utf-8") as f:
-                    doc = json.load(f)
-                if doc.get("version") == _PEAKS_VERSION:
-                    _peaks = doc
-                    return _peaks
-            except Exception:  # noqa: BLE001 — absent/stale cache
-                pass
-
-        t_start = time.perf_counter()
-        doc = {"version": _PEAKS_VERSION, "backend": backend,
-               "device_kind": kind, "matmul_flops": {},
-               "membw_bps": 0.0, "h2d_bps": 0.0, "d2h_bps": 0.0,
-               "host_stream_bps": 0.0}
-        try:
-            for dt in _PROBE_DTYPES:
-                doc["matmul_flops"][dt] = _probe_matmul(jnp, jax, dt)
-            doc["membw_bps"] = _probe_membw(jnp, jax)
-            h2d, d2h, stream = _probe_transfers(np, jax)
-            doc["h2d_bps"], doc["d2h_bps"] = h2d, d2h
-            doc["host_stream_bps"] = stream
-        except Exception as e:  # noqa: BLE001 — probes are best-effort
-            doc["error"] = f"{type(e).__name__}: {e}"
-        doc["probe_seconds"] = round(time.perf_counter() - t_start, 3)
-
-        try:
-            os.makedirs(_cache_dir(), exist_ok=True)
-            tmp = path + ".tmp"
-            with open(tmp, "w", encoding="utf-8") as f:
-                json.dump(doc, f)
-            os.replace(tmp, path)
-        except Exception:  # noqa: BLE001 — read-only home is fine
-            pass
-        _peaks = doc
-        return _peaks
-
-
-def roofline_floor_seconds(flops: float, nbytes: float,
-                           peaks: dict, dtype: str) -> float | None:
-    """The roofline lower bound on wall time: compute-limited OR
-    bandwidth-limited, whichever binds.  None when the probe failed
-    (an achieved fraction against a made-up peak is noise)."""
-    pf = (peaks.get("matmul_flops") or {}).get(dtype) or 0.0
-    bw = peaks.get("membw_bps") or 0.0
-    if pf <= 0.0 or bw <= 0.0:
-        return None
-    return max(flops / pf, nbytes / bw)
-
-
 # -- occupancy collapse detection --------------------------------------------
 
 _COLLAPSE_OCCUPANCY = 0.35  # device-busy fraction below this ...
@@ -461,19 +257,18 @@ _GANTT_LAST = 8        # batches of gantt carried per pipeline doc
 
 class RooflineLedger:
     """Process-global per-kernel accounting: bounded invocation ring,
-    absolute per-series totals, windowed achieved-fraction sketches,
-    and recent pipeline-occupancy docs.
+    absolute per-series totals, and recent pipeline-occupancy docs.
 
-    The clock is injected (tests advance sketch windows and collapse
-    streaks without sleeping); `record()` is the single kernel entry
-    point and `note_pipeline()` the single occupancy entry point."""
+    The clock is injected (tests advance collapse streaks without
+    sleeping); `record()` is the single kernel entry point and
+    `note_pipeline()` the single occupancy entry point."""
 
     def __init__(self, clock=time.monotonic):
         self.clock = clock
         self._lock = threading.Lock()
         self._ring: deque = deque(maxlen=_RING_MAX)
         # (kernel, codec, dtype, geometry) ->
-        #   [count, seconds, bytes, macs, WindowedSketch]
+        #   [count, seconds, bytes, macs]
         self._series: dict[tuple, list] = {}
         # (stage, codec) -> [count, seconds, bytes]
         self._stages: dict[tuple, list] = {}
@@ -498,33 +293,22 @@ class RooflineLedger:
         geom = geometry_key(out_rows, in_rows, n, batch)
         secs = max(float(seconds), 1e-9)
 
-        peaks = probe_peaks()
-        floor = roofline_floor_seconds(cost["flops"], cost["bytes"],
-                                       peaks, dtype)
-        achieved = None if floor is None else min(floor / secs, 1.0)
-
         row = {"ts": round(self.clock(), 6), "kernel": kernel,
                "codec": codec, "dtype": dtype, "geometry": geom,
                "seconds": round(secs, 9), "bytes": cost["bytes"],
                "macs": cost["macs"], "intensity":
                    round(cost["intensity"], 3),
-               "achieved": None if achieved is None
-                   else round(achieved, 6),
                "measured_bytes": measured_bytes, "node": node}
         key = (kernel, codec, dtype, geom)
         with self._lock:
             self._ring.append(row)
             series = self._series.get(key)
             if series is None:
-                series = self._series[key] = [
-                    0, 0.0, 0, 0,
-                    WindowedSketch(min_value=1e-6, clock=self.clock)]
+                series = self._series[key] = [0, 0.0, 0, 0]
             series[0] += 1
             series[1] += secs
             series[2] += cost["bytes"]
             series[3] += cost["macs"]
-            if achieved is not None:
-                series[4].observe(achieved)
 
         kernel_seconds_total.inc(secs, kernel=kernel, codec=codec,
                                  dtype=dtype)
@@ -539,8 +323,7 @@ class RooflineLedger:
     def add_stage(self, stage: str, codec: str, seconds: float,
                   nbytes: int) -> None:
         """One closed stage of the served EC file pipeline (StageClock
-        is the caller).  Totals only: no ring entry, no peaks, no
-        achieved fraction."""
+        is the caller).  Totals only: no ring entry."""
         with self._lock:
             row = self._stages.get((stage, codec))
             if row is None:
@@ -644,17 +427,10 @@ class RooflineLedger:
         """Absolute per-series rollup (idempotent heartbeat rows)."""
         with self._lock:
             items = sorted(self._series.items())
-            out = []
-            for (kernel, codec, dtype, geom), s in items:
-                sk = s[4]
-                out.append({"kernel": kernel, "codec": codec,
-                            "dtype": dtype, "geometry": geom,
-                            "count": s[0],
-                            "seconds": round(s[1], 6),
-                            "bytes": s[2], "work": s[3],
-                            "achieved_p50": _rq(sk, 0.5),
-                            "achieved_p95": _rq(sk, 0.95)})
-        return out
+        return [{"kernel": kernel, "codec": codec, "dtype": dtype,
+                 "geometry": geom, "count": s[0],
+                 "seconds": round(s[1], 6), "bytes": s[2], "work": s[3]}
+                for (kernel, codec, dtype, geom), s in items]
 
     def recent(self, n: int = 32) -> list[dict]:
         with self._lock:
@@ -711,11 +487,6 @@ class RooflineLedger:
             self._streak.clear()
             self._collapsed.clear()
             self._last_emit = 0.0
-
-
-def _rq(sketch, q: float):
-    v = sketch.quantile(q)
-    return None if v is None else round(v, 6)
 
 
 LEDGER = RooflineLedger()
@@ -857,17 +628,11 @@ def note_request(t0: float, beside: int, nbytes: int) -> None:
         "", time.perf_counter() - t0, nbytes)
 
 
-def local_peaks() -> dict | None:
-    """Peaks of THIS process's device, or None when it has run no
-    kernel: probing initialises the JAX backend, and a role that owns
-    no chip (utils/jaxenv.py) must not claim one to answer a GET."""
-    return probe_peaks() if LEDGER.has_rows() else None
-
-
 def _device_memory_stats() -> list[dict]:
     """jax.local_devices() with memory stats where the backend reports
-    them; empty for a process that has run no kernel (see
-    `local_peaks`)."""
+    them; empty for a process that has run no kernel: asking
+    initialises the JAX backend, and a role that owns no chip
+    (utils/jaxenv.py) must not claim one to answer a GET."""
     if not LEDGER.has_rows():
         return []
     import jax
@@ -884,19 +649,15 @@ def _device_memory_stats() -> list[dict]:
 
 
 def debug_doc(node: str, role: str) -> dict:
-    """GET /debug/device payload: measured peaks, the per-kernel
-    roofline table followed by the EC file pipeline's stage rows (same
-    list, `kernel` = the stage's name, no dtype, geometry or achieved
-    fraction), recent invocations, recent pipeline gantts with
-    bubble attribution, the conservation verdict, device memory
-    stats, the counts of the seal's host buffer pool (ec/encoder.py
-    CHUNK_POOL: says that `seal.stack` builds its chunks in reused
-    buffers), and how the seal's drains found the oldest chunk in
-    flight (SEAL_INFLIGHT: `ready`, the device done with it, or
-    `waited`)."""
+    """GET /debug/device payload: the per-kernel table followed by the
+    EC file pipeline's stage rows (same list, `kernel` = the stage's
+    name, no dtype, geometry or work), recent invocations, recent
+    pipeline gantts with bubble attribution, the conservation verdict,
+    device memory stats, the counts of the seal's host buffer pool
+    (ec/encoder.py CHUNK_POOL) and how the seal's drains found the
+    oldest chunk in flight (SEAL_INFLIGHT: `ready` or `waited`)."""
     from ..ec.encoder import CHUNK_POOL, SEAL_INFLIGHT
     return {"node": node, "role": role, "armed": ARMED,
-            "peaks": local_peaks(),
             "kernels": LEDGER.kernel_table() + LEDGER.stage_table(),
             "recent": LEDGER.recent(16),
             "pipelines": LEDGER.pipelines(4),

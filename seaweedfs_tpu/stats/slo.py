@@ -176,8 +176,8 @@ class SloTracker:
                  alpha: float = 0.01):
         from collections import deque
         # The canonical SRE windows (5m fast / 1h slow), overridable by
-        # env for harnesses that must drive a burn inside seconds
-        # (bench_load.py) — never something a test sleeps through.
+        # env for harnesses that must drive a burn inside seconds —
+        # never something a test sleeps through.
         if short_window is None:
             short_window = _env_float(
                 "SEAWEEDFS_TPU_SLO_SHORT_WINDOW", 300.0)

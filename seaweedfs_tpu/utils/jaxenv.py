@@ -30,8 +30,8 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
 
 
 def cache_root() -> str:
-    """Directory of every on-disk cache the device path keeps: JAX's
-    persistent compilation cache and the roofline peak probe.  Placed
+    """Directory of the one on-disk cache the device path keeps, JAX's
+    persistent compilation cache.  Placed
     from outside with JAX_COMPILATION_CACHE_DIR; otherwise a fixed,
     git-ignored path in the checkout (the path is part of JAX's cache
     key, so it must not move between runs)."""

@@ -73,9 +73,12 @@ def test_phase_ledger_sums_to_wall_on_slow_request():
         covered = sum(v for k, v in ph.items() if k != "queue")
         assert covered >= 0.9 * wall
         assert covered <= wall + 0.01
-        assert 0.10 <= ph["disk"] <= 0.16
-        assert 0.06 <= ph["rpc_downstream"] <= 0.12
-        assert 0.06 <= ph["handler"] <= 0.14
+        # Each phase holds at least its sleep.  How far a sleep overran
+        # is the machine's load, not the ledger's: from above the phases
+        # are held together, by the wall (`covered`, just checked).
+        assert ph["disk"] >= 0.10
+        assert ph["rpc_downstream"] >= 0.06
+        assert ph["handler"] >= 0.06
         # The live phase sketches feed the labeled gauge.
         vals = server.slo.phase_gauge_values()
         assert ("phasetest", "/slowop", "disk", "0.99") in vals
@@ -447,7 +450,7 @@ def test_cluster_profile_merges_across_subprocess_cluster(tmp_path):
                "-max=10", f"-mserver=127.0.0.1:{mport}"])
         vports.append(vport)
     try:
-        deadline = time.time() + 60
+        deadline = time.time() + 180  # three interpreters beside 6 workers
         want = [f"http://127.0.0.1:{p}" for p in [mport] + vports]
         for url in want:
             while True:
@@ -470,6 +473,12 @@ def test_cluster_profile_merges_across_subprocess_cluster(tmp_path):
         from seaweedfs_tpu.shell.env import CommandEnv
         out_file = tmp_path / "cluster.collapsed"
         cenv = CommandEnv(f"http://127.0.0.1:{mport}")
+        # The shell walks the nodes the MASTER lists: a volume server
+        # that answers its own port may not have registered yet.
+        deadline = time.time() + 120
+        while len(cenv.debug_servers({})) < 3:
+            assert time.time() < deadline, "volume servers never registered"
+            time.sleep(0.2)
         text = ClusterProfile().do(
             ["-seconds", "0.5", "-o", str(out_file)], cenv)
         assert "node(s)" in text

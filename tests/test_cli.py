@@ -175,6 +175,33 @@ def test_guard():
     inactive.check_jwt("", "3,ab")  # no-op when no key configured
 
 
+def test_environment_names_read_by_the_package_are_the_documented_ones():
+    """README's "Environment variables" table is the one list of the
+    `SEAWEEDFS_TPU_*` names: every name the package's source holds is a
+    row of it, and it has no row the source does not read."""
+    import re
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    name = re.compile(r"SEAWEEDFS_TPU_[A-Z_]+")
+    read = set()
+    for here, _dirs, files in os.walk(os.path.join(root, "seaweedfs_tpu")):
+        for f in files:
+            if f.endswith(".py"):
+                with open(os.path.join(here, f), encoding="utf-8") as src:
+                    read |= set(name.findall(src.read()))
+    with open(os.path.join(root, "README.md"), encoding="utf-8") as f:
+        readme = f.read()
+    section = readme.split("## Environment variables\n", 1)[1]
+    section = section.split("\n## ", 1)[0]
+    rows = [line.split("|")[1] for line in section.splitlines()
+            if line.startswith("| `SEAWEEDFS_TPU_")]
+    documented = {name.search(cell).group(0) for cell in rows}
+    assert len(rows) == len(documented), "a name has two rows"
+    assert documented == read, (sorted(read - documented),
+                                sorted(documented - read))
+    # one table: a name explained elsewhere in the README is in it too
+    assert set(name.findall(readme)) <= documented
+
+
 def test_glog(capsys):
     from seaweedfs_tpu.utils import glog
     glog.setup(verbosity=1)

@@ -258,9 +258,19 @@ def _rlog_status(vs, vid):
 
 
 def _wait_shipped(vs, vid, timeout=20.0):
+    """Until the change log has nothing pending AND the shipper's lag
+    view says so too.  The view is what the next heartbeat carries to
+    the master's healthz, and the shipper brings it up to date only
+    after it has moved the log's watermark and journaled the ack: a
+    caller that watched the log alone could heartbeat the lag of before
+    the ship — past a 50 ms SLO on a loaded machine."""
     def ok():
         st = _rlog_status(vs, vid)
-        return bool(st) and st["pending"] == 0 and st["last_seq"] > 0
+        if not st or st["pending"] or not st["last_seq"]:
+            return False
+        seen = vs.shipper.lag_view()["volumes"].get(str(vid))
+        return seen is not None and seen["lag_seq"] == 0 \
+            and seen["acked_seq"] == st["acked_seq"]
     _wait(ok, timeout, f"volume {vid} never fully shipped: "
                        f"{_rlog_status(vs, vid)}")
 

@@ -471,6 +471,52 @@ def test_drain_refuses_new_writes_finishes_inflight(tmp_path):
         master.stop()
 
 
+def test_a_sigterm_that_lands_on_another_thread_still_stops_the_server():
+    """The kernel may hand a process-directed signal to any thread.
+    CPython then runs the handler in the main thread the next time it
+    executes bytecode — never, if it sits in an untimed lock acquire.
+    `_wait_forever` must come back (drain, stop) all the same: this is
+    what left a `volume` process running a minute after its SIGTERM in
+    the rolling restart below."""
+    from seaweedfs_tpu.command import servers
+
+    assert threading.current_thread() is threading.main_thread()
+    main = threading.main_thread().ident
+    stopped = []
+    rescued = []
+    parked = threading.Event()
+
+    def bystander() -> None:
+        parked.wait(30)
+
+    def sender() -> None:
+        time.sleep(0.2)     # the main thread is in its wait by now
+        signal.pthread_kill(other.ident, signal.SIGTERM)
+        time.sleep(5.0)
+        if not stopped:
+            # Unrepaired, only a signal to the main thread itself ends
+            # the wait: end it, and fail below.
+            rescued.append(True)
+            signal.pthread_kill(main, signal.SIGTERM)
+
+    class Role:
+        def stop(self) -> None:
+            stopped.append(True)
+
+    other = threading.Thread(target=bystander, daemon=True)
+    other.start()
+    saved = {s: signal.getsignal(s)
+             for s in (signal.SIGINT, signal.SIGTERM)}
+    threading.Thread(target=sender, daemon=True).start()
+    try:
+        assert servers._wait_forever([Role()]) == 0
+    finally:
+        parked.set()
+        for s, h in saved.items():
+            signal.signal(s, h)
+    assert stopped and not rescued
+
+
 def _spawn_volume_subprocess(tmp_path, idx: int, port: int,
                              master_port: int):
     d = tmp_path / f"vsdata{idx}"
@@ -485,7 +531,7 @@ def _spawn_volume_subprocess(tmp_path, idx: int, port: int,
          f"-port={port}", f"-dir={d}", "-max=50",
          f"-mserver=127.0.0.1:{master_port}",
          "-shutdown.grace=10"],
-        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONFAULTHANDLER="1"),
         stdout=log, stderr=subprocess.STDOUT)
 
 
@@ -604,7 +650,17 @@ def test_rolling_restart_zero_acked_loss_zero_client_errors(tmp_path):
         for i, port in enumerate(ports):
             proc = procs[i]
             os.kill(proc.pid, signal.SIGTERM)
-            proc.wait(timeout=60)
+            try:
+                proc.wait(timeout=60)
+            except subprocess.TimeoutExpired:
+                # Say what kept it: SIGABRT makes the child's
+                # faulthandler write every thread's stack to its log.
+                os.kill(proc.pid, signal.SIGABRT)
+                proc.wait(timeout=30)
+                tail = (tmp_path / f"vs{i}.log").read_bytes()[-6000:]
+                raise AssertionError(
+                    f"volume subprocess {i} outlived its SIGTERM by "
+                    f"60 s:\n{tail.decode(errors='replace')}") from None
             procs[i] = _spawn_volume_subprocess(
                 tmp_path, i, port, master.server.port)
             node = f"127.0.0.1:{port}"

@@ -714,39 +714,3 @@ def test_acceptance_slow_fault_exemplar_trace_burn_healthz(tmp_path):
                 os.environ.pop(k, None)
             else:
                 os.environ[k] = v
-
-
-# -- load-harness smoke (subprocess cluster; seconds, CPU-only) --------------
-
-@pytest.mark.slow
-def test_bench_load_quick_mode(tmp_path):
-    """bench_load.py quick mode: a real subprocess cluster, a short
-    open-loop mixed workload, client/server quantile cross-check and
-    the fault-phase acceptance checks — the gating BENCH series'
-    machinery, shrunk to seconds."""
-    import json
-    import subprocess
-    import sys
-    out_path = tmp_path / "BENCH_load_smoke.json"
-    env = dict(os.environ, BENCH_LOAD_QUICK="1", JAX_PLATFORMS="cpu")
-    proc = subprocess.run(
-        [sys.executable, os.path.join(os.path.dirname(__file__), "..",
-                                      "bench_load.py"),
-         "-o", str(out_path)],
-        env=env, capture_output=True, text=True, timeout=300)
-    assert proc.returncode == 0, proc.stderr[-4000:]
-    doc = json.loads(out_path.read_text())
-    assert doc["achieved_rps"] > 0
-    assert doc["client"]["read"]["p99"] > 0
-    assert doc["server"]["read"]["p99"] > 0
-    assert doc["agreement"]["read"]["within_bound"], doc["agreement"]
-    fc = doc["fault_checks"]
-    assert fc["exemplar_recorded"] and fc["trace_resolved"]
-    assert fc["healthz_degraded"] and fc["slo_burn_emitted"]
-    # Round 2: the time-attribution acceptance rows.
-    pb = doc["phase_budget"]
-    assert pb["exemplars_with_phases"] > 0
-    assert pb["budget_ok"], pb
-    assert doc["cluster_profile"]["merged_ok"], doc["cluster_profile"]
-    ov = doc["attribution_overhead"]
-    assert ov["on"]["median_rps"] > 0 and ov["off"]["median_rps"] > 0

@@ -115,7 +115,7 @@ def test_main_thread_stages_sum_to_the_wall(tmp_path, monkeypatch):
     base = _volume(tmp_path, "1", 10 * n - 12345)
     want = _volume(tmp_path, "2", 10 * n - 12345)
     write_ec_files(want, coder=NumpyCoder(), chunk_size=BLOCK)
-    coder = PallasCoder(block_n=4096)
+    coder = PallasCoder()
     warm = np.zeros((10, BLOCK), np.uint8)
     np.asarray(coder.encode_with_crc(warm)[0])      # compile outside
     np.asarray(coder.reconstruct(
