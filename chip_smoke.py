@@ -565,12 +565,24 @@ def served_leg(work: str, seed: int, rehearse: bool,
         check(drained == calls["seal.drain"] > 0,
               f"served: seal_inflight {dev['seal_inflight']} against "
               f"{calls['seal.drain']} drains")
-        # ... and a direct call (the rebuild's, the degraded reads')
-        # still fences and records.
-        check(rows.get("reconstruct_kernel", 0)
-              >= summed("shard_bytes") * (DATA_SHARDS + len(SERVED_LOST)),
-              f"served: reconstruct kernel rows {rows} do not cover the "
-              f"rebuilt shards")
+        # The rebuild runs on the same pipeline, unfenced too: the
+        # survivors it dispatched, the rebuilt rows it drained and one
+        # `rebuild_inflight` count per chunk.
+        check(rows.get("rebuild.dispatch", 0)
+              >= summed("shard_bytes") * DATA_SHARDS
+              and rows.get("rebuild.drain", 0)
+              >= summed("shard_bytes") * len(SERVED_LOST),
+              f"served: the rebuild's stage rows {rows} do not cover "
+              f"the rebuilt shards")
+        drained = sum(dev["rebuild_inflight"].values())
+        check(drained == calls["rebuild.drain"] > 0,
+              f"served: rebuild_inflight {dev['rebuild_inflight']} "
+              f"against {calls['rebuild.drain']} drains")
+        # ... and a direct call (the degraded reads') still fences and
+        # records.
+        check(rows.get("reconstruct_kernel", 0) > 0,
+              f"served: the degraded reads left no reconstruct kernel "
+              f"row: {rows}")
         check(dev["conservation"]["ok"], f"served: {dev['conservation']}")
     finally:
         stop_child(p)

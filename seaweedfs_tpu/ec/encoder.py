@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import os
 import threading
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
@@ -38,12 +39,34 @@ DEFAULT_CHUNK = 4 * 1024 * 1024
 # Chunks `_pipelined_encode` keeps in flight between dispatch and drain.
 SEAL_DEPTH = 2
 
+# Chunks `rebuild_ec_files` keeps in flight: its main thread spends a
+# third of a chunk's device round trip per chunk, so the window has to
+# be as deep as the round trip is long (PERF.md section 6, PR 31, has
+# the chip's readings at 2, 3, 4 and 5).
+REBUILD_DEPTH = 4
+
+# Pooled buffers one job has live: its chunks in flight, the chunks
+# read ahead of them, one being filled.  The seal's main thread is the
+# slow side (it writes 40 MiB a chunk), so it is read ahead as deep as
+# its window; the rebuild's reader is the fast side.
+SEAL_BUFFERS = 2 * SEAL_DEPTH + 1
+REBUILD_BUFFERS = REBUILD_DEPTH + 2
+
+# Threads the rebuild's reader spreads the survivors of one chunk over.
+# One thread copies the page cache into a pooled buffer at a third of
+# the pace the serial loop read into its one warm 4 MiB of heap, and
+# then sets the job's pace; the survivors are separate files, on
+# separate disks where a volume server has them, so their reads go out
+# side by side (PERF.md section 6, PR 31: one thread 17 ms a chunk,
+# five 7 ms beside the pipeline, ten no better).
+REBUILD_READERS = 5
+
 # Host buffers of one default chunk (40 MiB) that stay with the process
-# between seals: what one job has live (`_pipelined_encode`), 200 MiB.
-# A fresh buffer costs a page fault per 4 KiB on first touch — as much
-# as reading the chunk in place saves — so that is paid once per
-# process, not once per chunk or per job.
-CHUNK_POOL_BUFFERS = 2 * SEAL_DEPTH + 1
+# between jobs: what the larger of the two jobs has live
+# (`_run_pipeline`).  A fresh buffer costs a page fault per 4 KiB on
+# first touch — as much as reading the chunk in place saves — so that
+# is paid once per process, not once per chunk or per job.
+CHUNK_POOL_BUFFERS = max(SEAL_BUFFERS, REBUILD_BUFFERS)
 
 
 class _ChunkPool:
@@ -87,12 +110,13 @@ CHUNK_POOL = _ChunkPool(CHUNK_POOL_BUFFERS, DATA_SHARDS * DEFAULT_CHUNK)
 
 
 class _InflightCount:
-    """How `_pipelined_encode`'s drain found the oldest chunk in
-    flight, process-wide: `ready` (the device was done with it: its
-    round trip hid behind the main thread's writes) or `waited`.  All
-    `waited` means the device path, not the main thread, sets the
-    pace.  A device array is ready when it is computed; whether the
-    copy back had landed as well is what `seal.drain`'s seconds say."""
+    """How a job's drain found the oldest chunk in flight,
+    process-wide, one count for the seals and one for the rebuilds:
+    `ready` (the device was done with it: its round trip hid behind
+    the main thread's work) or `waited`.  All `waited` means the device
+    path, not the main thread, sets the pace.  A device array is ready
+    when it is computed; whether the copy back had landed as well is
+    what the drain stage's seconds say."""
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -114,6 +138,7 @@ class _InflightCount:
 
 
 SEAL_INFLIGHT = _InflightCount()
+REBUILD_INFLIGHT = _InflightCount()
 
 
 def _request_copy_back(handle) -> None:
@@ -139,14 +164,15 @@ def write_sorted_file_from_idx(base_file_name: str,
         out.write(db.to_sorted_bytes())
 
 
-def _shard_write(f, sid: int, buf: bytes, accs) -> None:
-    """One shard-file write: feed the integrity accumulator with the
-    TRUE bytes first, then write — possibly through the volume.corrupt
-    bit-rot injector — so the recorded `.ecc` checksums describe what
-    the encoder intended and any on-disk divergence is detectable."""
+def _shard_write(f, sid: int, buf, accs) -> None:
+    """One shard-file write of `buf` (`bytes`, or a contiguous uint8
+    row): feed the integrity accumulator with the TRUE bytes first,
+    then write — possibly through the volume.corrupt bit-rot injector —
+    so the recorded `.ecc` checksums describe what the encoder intended
+    and any on-disk divergence is detectable."""
     if accs is not None:
         accs[sid].feed(buf)
-    if _fault.ARMED and buf:
+    if _fault.ARMED and len(buf):
         try:
             _fault.hit("volume.corrupt", shard=sid)
         except _fault.FaultInjected:
@@ -320,62 +346,43 @@ def _chunk_reader(dat, dat_size: int, large: int, small: int,
         yield _read_chunk(fd, width, reads)
 
 
-def _pipelined_encode(fd: int, spans, coder: ErasureCoder, outputs,
-                      accs=None, clock: StageClock | None = None):
-    """Double-buffered encode pipeline (SURVEY §2.3 'double-buffered
-    host→HBM DMA + batched kernel launches') over the chunks `spans`
-    (`_chunk_spans`) of the file `fd`, each step a stage of `clock`
-    (stats/roofline.py STAGES):
+def _run_pipeline(chunks, fill, dispatch, flush, *, depth: int,
+                  buffers: int, clock: StageClock, wait_stage: str,
+                  fill_stage: str) -> None:
+    """The read-ahead pipeline of both EC file jobs (the seal's
+    `_pipelined_encode`, `rebuild_ec_files`): the skeleton that owns
+    the two threads, the pooled buffers and the window of chunks in
+    flight; what a chunk IS comes in as three functions.
 
-      reader thread:  wait for a free buffer
-                      read chunk k+1 into it   seal.stack
-      main thread:    wait for chunk k         seal.stack_wait
-                      issue H2D of k, launch,
-                        request D2H            seal.dispatch
-                      write data shards of k   seal.write_data
-                      collect parity of k-1    seal.drain
-                      hand its buffer back
-                      write it                 seal.write_parity
+      reader thread:  wait for a free buffer (the job has `buffers`)
+                      data = fill(what, buffer)         `fill_stage`
+      main thread:    wait for the next chunk           `wait_stage`
+                      handles = dispatch(data)
+                      with `depth` chunks in flight:
+                        flush(oldest handles, release)
 
-    This is the one caller of the coder that drains later, so it is
-    the one that asks for the unfenced call (`encode_unfenced`, where
-    the coder has one; `RooflineLedger.record` takes fenced walls
-    only, so that call records no kernel row) and for the copy back
-    (where the handle can copy asynchronously).  The device round trip
-    of chunk k — host→device, kernel, device→host — then runs beside
-    the data-shard writes of k, and the drain of a chunk dispatched a
-    whole iteration earlier finds its bytes on the host
-    (`SEAL_INFLIGHT` counts how often).  A host coder computes inside
-    dispatch and its arrays count as ready.  A device error surfaces
-    at the drain.
-
-    The chunks live in the `CHUNK_POOL_BUFFERS` buffers of `CHUNK_POOL`
-    (`SEAL_DEPTH` in flight, as many read ahead, one being filled), and
-    that count is what bounds the read-ahead.  One ownership rule: a
-    buffer goes back to the pool, and so to the reader, only when its
-    chunk is finished
-    — its data shards written AND its parity drained.  Until then the
-    coder may still read it: a device coder transfers asynchronously,
-    and on the CPU platform `jnp.asarray` may alias the host array.
-
-    When ``accs is None`` the coder must support fused CRC
-    (`encode_with_crc`) and every chunk must span whole `.ecc` blocks:
-    the kernel emits every shard's per-block CRC32-C as a second output
-    and this function returns the per-shard CRC lists.  With byte
-    accumulators passed, None is returned."""
+    `chunks` yields `(nbytes, what)` per chunk: the bytes of buffer it
+    needs and what `fill` is to read into it.  `dispatch` and `flush`
+    time their own stages.  `flush` collects the oldest chunk's
+    handles, THEN calls `release()`, then writes.  One ownership rule:
+    a chunk's buffer goes back to `CHUNK_POOL`, and so to the reader,
+    only in that `release()` — when the chunk's results are drained.
+    Until then the coder may still read it: a device coder transfers
+    asynchronously, and on the CPU platform `jnp.asarray` may alias the
+    host array.  An error on either thread ends the job: the reader is
+    cancelled and joined, what never reached the coder goes back to the
+    pool, and the first error is raised."""
     import collections
     import queue
 
-    if clock is None:
-        clock = StageClock()
     q: "queue.Queue" = queue.Queue()
-    free = threading.Semaphore(CHUNK_POOL_BUFFERS)
+    free = threading.Semaphore(buffers)
     cancelled = threading.Event()
     error: list[BaseException] = []
 
     def read_loop() -> None:
         try:
-            for width, reads in spans:
+            for nbytes, what in chunks:
                 # Bounded waits with a cancel check: if the main thread
                 # dies (device failure, ENOSPC) it hands no buffer back,
                 # and a plain acquire would deadlock the final join
@@ -383,9 +390,9 @@ def _pipelined_encode(fd: int, spans, coder: ErasureCoder, outputs,
                 while not free.acquire(timeout=0.2):
                     if cancelled.is_set():
                         return
-                buf = CHUNK_POOL.take(DATA_SHARDS * width)
-                with clock("seal.stack") as st:
-                    data = _read_chunk(fd, width, reads, buf)
+                buf = CHUNK_POOL.take(nbytes)
+                with clock(fill_stage) as st:
+                    data = fill(what, buf)
                     st.add_bytes(data.nbytes)
                 q.put((data, buf))
         except BaseException as e:  # noqa: BLE001 — surfaced below
@@ -398,61 +405,23 @@ def _pipelined_encode(fd: int, spans, coder: ErasureCoder, outputs,
     inflight: "collections.deque" = collections.deque()
     lent: "collections.deque" = collections.deque()  # their buffers
 
-    data_shards = coder.data_shards
-    parity_shards = coder.parity_shards
-    fused = accs is None
-    unfenced = getattr(coder, "encode_unfenced", None)
-    crc_lists: list[list[int]] = \
-        [[] for _ in range(data_shards + parity_shards)]
-
-    def flush_one() -> None:
-        with clock("seal.drain") as st:
-            handles = inflight.popleft()
-            SEAL_INFLIGHT.note(all(_is_ready(h) for h in handles))
-            parity = np.asarray(handles[0])
-            st.add_bytes(parity.nbytes)
-            if fused:
-                crcs = np.asarray(handles[1])
-                for sid, row in enumerate(crcs):
-                    crc_lists[sid].extend(int(c) for c in row)
-                st.add_bytes(crcs.nbytes)
-        # The oldest chunk is finished (its data shards were written
-        # before this call): the reader may have its buffer.
+    def release() -> None:
         CHUNK_POOL.give(lent.popleft())
         free.release()
-        with clock("seal.write_parity", parity.nbytes):
-            for p in range(parity_shards):
-                sid = data_shards + p
-                _shard_write(outputs[sid], sid, parity[p].tobytes(),
-                             accs)
 
     try:
         while True:
-            with clock("seal.stack_wait"):
+            with clock(wait_stage):
                 item = q.get()
             if item is None:
                 break
             data, buf = item
             lent.append(buf)
-            # Dispatch first: a device coder's transfer, kernel and
-            # copy back run while we write the data shards.
-            with clock("seal.dispatch", data.nbytes):
-                if unfenced is not None:
-                    handles = unfenced(data, crc=fused)
-                elif fused:
-                    handles = coder.encode_with_crc(data)
-                else:
-                    handles = (coder.encode(data),)
-                for h in handles:
-                    _request_copy_back(h)
-                inflight.append(handles)
-            with clock("seal.write_data", data.nbytes):
-                for i in range(data_shards):
-                    _shard_write(outputs[i], i, data[i].tobytes(), accs)
-            if len(inflight) >= SEAL_DEPTH:
-                flush_one()
+            inflight.append(dispatch(data))
+            if len(inflight) >= depth:
+                flush(inflight.popleft(), release)
         while inflight:
-            flush_one()
+            flush(inflight.popleft(), release)
     finally:
         cancelled.set()
         t.join()
@@ -465,6 +434,100 @@ def _pipelined_encode(fd: int, spans, coder: ErasureCoder, outputs,
                 CHUNK_POOL.give(item[1])
     if error:
         raise error[0]
+
+
+def _pipelined_encode(fd: int, spans, coder: ErasureCoder, outputs,
+                      accs=None, clock: StageClock | None = None):
+    """Double-buffered encode pipeline (SURVEY §2.3 'double-buffered
+    host→HBM DMA + batched kernel launches') over the chunks `spans`
+    (`_chunk_spans`) of the file `fd`, on `_run_pipeline`, each step a
+    stage of `clock` (stats/roofline.py STAGES):
+
+      reader thread:  wait for a free buffer
+                      read chunk k+1 into it   seal.stack
+      main thread:    wait for chunk k         seal.stack_wait
+                      issue H2D of k, launch,
+                        request D2H            seal.dispatch
+                      write data shards of k   seal.write_data
+                      collect parity of k-1    seal.drain
+                      hand its buffer back
+                      write it                 seal.write_parity
+
+    This is a caller of the coder that drains later, so it asks for the
+    unfenced call (`encode_unfenced`, where the coder has one;
+    `RooflineLedger.record` takes fenced walls only, so that call
+    records no kernel row) and for the copy back (where the handle can
+    copy asynchronously).  The device round trip of chunk k —
+    host→device, kernel, device→host — then runs beside the data-shard
+    writes of k, and the drain of a chunk dispatched a whole iteration
+    earlier finds its bytes on the host (`SEAL_INFLIGHT` counts how
+    often).  A host coder computes inside dispatch and its arrays count
+    as ready.  A device error surfaces at the drain.
+
+    The chunks live in `SEAL_BUFFERS` buffers of `CHUNK_POOL`
+    (`SEAL_DEPTH` in flight, as many read ahead, one being filled), and
+    that count is what bounds the read-ahead.  A buffer goes back to
+    the pool only when its chunk is finished — its data shards written
+    AND its parity drained.
+
+    When ``accs is None`` the coder must support fused CRC
+    (`encode_with_crc`) and every chunk must span whole `.ecc` blocks:
+    the kernel emits every shard's per-block CRC32-C as a second output
+    and this function returns the per-shard CRC lists.  With byte
+    accumulators passed, None is returned."""
+    if clock is None:
+        clock = StageClock()
+    data_shards = coder.data_shards
+    parity_shards = coder.parity_shards
+    fused = accs is None
+    unfenced = getattr(coder, "encode_unfenced", None)
+    crc_lists: list[list[int]] = \
+        [[] for _ in range(data_shards + parity_shards)]
+
+    def fill(span, buf):
+        return _read_chunk(fd, *span, buf)
+
+    def dispatch(data):
+        # Dispatch first: a device coder's transfer, kernel and copy
+        # back run while we write the data shards.
+        with clock("seal.dispatch", data.nbytes):
+            if unfenced is not None:
+                handles = unfenced(data, crc=fused)
+            elif fused:
+                handles = coder.encode_with_crc(data)
+            else:
+                handles = (coder.encode(data),)
+            for h in handles:
+                _request_copy_back(h)
+        with clock("seal.write_data", data.nbytes):
+            for i in range(data_shards):
+                _shard_write(outputs[i], i, data[i].tobytes(), accs)
+        return handles
+
+    def flush(handles, release) -> None:
+        with clock("seal.drain") as st:
+            SEAL_INFLIGHT.note(all(_is_ready(h) for h in handles))
+            parity = np.asarray(handles[0])
+            st.add_bytes(parity.nbytes)
+            if fused:
+                crcs = np.asarray(handles[1])
+                for sid, row in enumerate(crcs):
+                    crc_lists[sid].extend(int(c) for c in row)
+                st.add_bytes(crcs.nbytes)
+        # The oldest chunk is finished (its data shards were written
+        # before this call): the reader may have its buffer.
+        release()
+        with clock("seal.write_parity", parity.nbytes):
+            for p in range(parity_shards):
+                sid = data_shards + p
+                _shard_write(outputs[sid], sid, parity[p].tobytes(),
+                             accs)
+
+    _run_pipeline(((DATA_SHARDS * width, (width, reads))
+                   for width, reads in spans),
+                  fill, dispatch, flush, depth=SEAL_DEPTH,
+                  buffers=SEAL_BUFFERS, clock=clock,
+                  wait_stage="seal.stack_wait", fill_stage="seal.stack")
     return dict(enumerate(crc_lists)) if fused else None
 
 
@@ -478,9 +541,32 @@ def rebuild_ec_files(base_file_name: str,
     on flat shard-file columns.  Codec-aware: the codec comes from the
     `.vif` sidecar, the shard count from the codec, and only the
     codec's planned minimal read set is read from disk — an LRC
-    in-group rebuild reads 5 shard files, not every survivor.  `clock`
-    is the job's stage clock (`rebuild.*`), as in `write_ec_files`.
-    """
+    in-group rebuild reads 5 shard files, not every survivor.
+
+    The chunks run on `_run_pipeline`, as the seal's do, each step a
+    stage of `clock` (the job's stage clock, as in `write_ec_files`):
+
+      reader thread:  wait for a free buffer
+                      preadv chunk k+1 of every   beside.rebuild_read
+                        planned survivor into it,
+                        REBUILD_READERS at once:
+                        one (survivors, n) array
+      main thread:    wait for chunk k            rebuild.read
+                      ONE H2D of k, launch,
+                        request D2H               rebuild.dispatch
+                      collect the rebuilt rows
+                        of k-(REBUILD_DEPTH-1)    rebuild.drain
+                      hand its buffer back
+                      CRC + write them            rebuild.write
+
+    The coder is called unfenced where it can be
+    (`reconstruct_unfenced`: no kernel row, see `_pipelined_encode`)
+    and the rows are collected `REBUILD_DEPTH - 1` chunks later, by
+    when their round trip — two to three of this loop's turns — is
+    over (`REBUILD_INFLIGHT` counts how often).  A host coder
+    reconstructs inside dispatch.  The chunks live in `REBUILD_BUFFERS`
+    buffers of `CHUNK_POOL`; a narrower read set (LRC in-group: 5
+    survivors) takes the front of one."""
     if coder is None:
         coder = new_coder(codec=ec_codec_name(base_file_name))
     cd = getattr(coder, "codec", None) or get_codec("rs")
@@ -512,30 +598,61 @@ def rebuild_ec_files(base_file_name: str,
     ins = {sid: open(present[sid], "rb") for sid in needed}
     outs = {sid: open(base_file_name + to_ext(sid), "wb") for sid in missing}
     accs = {sid: BlockCrcAccumulator() for sid in missing}
-    try:
+    unfenced = getattr(coder, "reconstruct_unfenced", None)
+
+    readers = ThreadPoolExecutor(REBUILD_READERS,
+                                 thread_name_prefix="ec-rebuild-read")
+
+    def fill(span, buf):
+        off, take = span
+        data = buf[:len(needed) * take].reshape(len(needed), take)
+
+        def read_row(row, sid) -> None:
+            if os.preadv(ins[sid].fileno(), [row], off) != take:
+                raise ValueError(f"short read on shard {sid}")
+
+        for _ in readers.map(read_row, data, needed):
+            pass
+        ec_repair_read_bytes_total.inc(data.nbytes, codec=cd.name)
+        return data
+
+    def dispatch(data):
+        with clock("rebuild.dispatch", data.nbytes):
+            if unfenced is not None:
+                handles = (unfenced(needed, data, missing),)
+            else:
+                rec = coder.reconstruct(dict(zip(needed, data)),
+                                        wanted=missing)
+                handles = tuple(rec[sid] for sid in missing)
+            for h in handles:
+                _request_copy_back(h)
+        return handles
+
+    def flush(handles, release) -> None:
+        with clock("rebuild.drain") as st:
+            REBUILD_INFLIGHT.note(all(_is_ready(h) for h in handles))
+            # one (rebuilt, n) array, or a host coder's row per shard
+            rows = [row for h in handles
+                    for row in np.atleast_2d(np.asarray(h))]
+            nbytes = sum(row.nbytes for row in rows)
+            st.add_bytes(nbytes)
+        release()
+        with clock("rebuild.write", nbytes):
+            for sid, row in zip(missing, rows):
+                _shard_write(outs[sid], sid, row, accs)
+
+    def spans():
         for off in range(0, shard_size, chunk_size):
             take = min(chunk_size, shard_size - off)
-            have = {}
-            with clock("rebuild.read", take * len(ins)):
-                for sid, f in ins.items():
-                    buf = os.pread(f.fileno(), take, off)
-                    if len(buf) != take:
-                        raise ValueError(f"short read on shard {sid}")
-                    have[sid] = np.frombuffer(buf, dtype=np.uint8)
-                ec_repair_read_bytes_total.inc(take * len(have),
-                                               codec=cd.name)
-            with clock("rebuild.dispatch", take * len(have)):
-                rec = coder.reconstruct(have, wanted=missing)
-            for sid in missing:
-                with clock("rebuild.drain", take):
-                    buf = np.asarray(rec[sid]).tobytes()
-                with clock("rebuild.write", take):
-                    _shard_write(outs[sid], sid, buf, accs)
-                # One rebuilt row on the host at a time: a second live
-                # 4 MiB buffer cost the rebuild 3 % on the chip's host
-                # (fresh pages for every chunk), two cost 6 %.
-                del buf
+            yield len(needed) * take, (off, take)
+
+    try:
+        _run_pipeline(spans(), fill, dispatch, flush, depth=REBUILD_DEPTH,
+                      buffers=REBUILD_BUFFERS, clock=clock,
+                      wait_stage="rebuild.read",
+                      fill_stage="beside.rebuild_read")
     finally:
+        readers.shutdown()
         with clock("rebuild.finish"):
             for f in ins.values():
                 f.close()

@@ -428,21 +428,24 @@ class PallasCoder:
         pm = self._plane_major(np.asarray(bmat), len(wanted), len(used))
         return jnp.asarray(pm, jnp.bfloat16), used
 
+    def _check_wanted(self, wanted) -> None:
+        bad = [w for w in wanted if not 0 <= w < self.total_shards]
+        if bad:
+            raise ValueError(
+                f"shard ids {bad} out of range [0, {self.total_shards})")
+
     def reconstruct(self, shards: dict[int, jax.Array],
                     wanted: list[int] | None = None) -> dict[int, jax.Array]:
         present = tuple(sorted(shards))
         if wanted is None:
             wanted = [s for s in range(self.total_shards) if s not in shards]
-        bad = [w for w in wanted if not 0 <= w < self.total_shards]
-        if bad:
-            raise ValueError(
-                f"shard ids {bad} out of range [0, {self.total_shards})")
+        self._check_wanted(wanted)
         if not wanted:
             return {}
         mat_pm, used = self._decode_mat_pm(present, tuple(wanted))
         stacked = jnp.stack([jnp.asarray(shards[s], jnp.uint8) for s in used])
-        # The callers (the rebuild's serial loop, degraded reads) stage
-        # each result to the host at once: the fence costs them nothing.
+        # The callers (degraded reads) stage each result to the host at
+        # once: the fence costs them nothing.
         t0 = time.perf_counter()
         rec = jax.block_until_ready(
             self._apply(mat_pm, stacked, len(wanted)))
@@ -451,6 +454,27 @@ class PallasCoder:
                       in_rows=int(stacked.shape[0]),
                       n=int(stacked.shape[1]))
         return {w: rec[i] for i, w in enumerate(wanted)}
+
+    def reconstruct_unfenced(self, present, stacked, wanted) -> jax.Array:
+        """`reconstruct` for the caller that drains later (ec/encoder.py
+        `rebuild_ec_files`) and has the survivors in ONE host array
+        already: row j of the (len(present), n) uint8 `stacked` is
+        shard `present[j]`, ids ascending.  Issues one transfer and the
+        kernel and returns the (len(wanted), n) handle, row i shard
+        `wanted[i]`, waiting for neither: no per-shard transfer and no
+        stack on the device.  Like `encode_unfenced` it records no row,
+        and a device error surfaces where the caller collects the
+        handle."""
+        present, wanted = tuple(present), tuple(wanted)
+        self._check_wanted(wanted)
+        mat_pm, used = self._decode_mat_pm(present, wanted)
+        if used != present:
+            # More survivors than the decode reads: it takes its own.
+            stacked = stacked[[present.index(s) for s in used]]
+        if _roofline.ARMED:
+            _roofline.LEDGER.mark_device()
+        return self._apply(mat_pm, jnp.asarray(stacked, jnp.uint8),
+                           len(wanted))
 
     def verify(self, shards) -> bool:
         shards = jnp.asarray(shards, jnp.uint8)

@@ -446,11 +446,12 @@ class ClusterRoofline(Command):
                 f"seal.stack host buffers: {pool['reused']} chunks read "
                 f"into a reused one, {pool['allocated']} into a new one, "
                 f"{pool['held_bytes'] >> 20} MiB held")
-        inflight = doc.get("seal_inflight")
-        if inflight:
-            lines.append(
-                f"seal.drain: {inflight['ready']} chunks were ready "
-                f"on the device, {inflight['waited']} waited for")
+        for job in ("seal", "rebuild"):
+            inflight = doc.get(f"{job}_inflight")
+            if inflight:
+                lines.append(
+                    f"{job}.drain: {inflight['ready']} chunks were ready "
+                    f"on the device, {inflight['waited']} waited for")
         occ_lines = []
         if flags.get("node"):
             occ = (doc.get("occupancy") or {}).get("latest", {})

@@ -99,9 +99,12 @@ PIPELINE_STAGES = ("stack", "dispatch", "device", "drain")
 # that a stage means the same with any coder and with or without the
 # coder's fence.  Closed like KERNELS: StageClock raises on any other
 # name.  The main-thread stages of one job are contiguous and never
-# nest: their seconds sum to the job's wall.  The two `req.` rows are
-# the request plane's, booked by `note_request` on the threads that
-# answer needle requests: what a job in the same process costs them.
+# nest: their seconds sum to the job's wall (`seal.stack` and
+# `beside.rebuild_read` are the read-ahead threads', beside it; the
+# second is named so that no sum over `rebuild.` takes it for a
+# main-thread row).  The two `req.` rows are the request plane's,
+# booked by `note_request` on the threads that answer needle requests:
+# what a job in the same process costs them.
 
 STAGES = {
     "seal.stack_wait":
@@ -132,20 +135,32 @@ STAGES = {
     "seal.delete_original":
         "/admin/delete_volume of a volume whose shards are mounted",
     "rebuild.read":
-        "pread of the planned survivors of one chunk",
+        "main thread blocked on the read-ahead queue: the reader did "
+        "not keep up (one more count than chunks: the end-of-stream "
+        "wait); the reads themselves are beside.rebuild_read",
     "rebuild.dispatch":
-        "coder.reconstruct: the survivors' H2D, the stack, the kernel "
-        "and, while the coder fences, its wait",
+        "the coder's reconstruct call as the pipeline makes it: ONE "
+        "H2D of the survivors' (k, n) chunk, kernel launch, request of "
+        "the copy back; a device coder is waited for in rebuild.drain, "
+        "a host coder reconstructs here",
     "rebuild.drain":
-        "np.asarray + tobytes of one rebuilt row: D2H (a count per "
-        "chunk and rebuilt shard, as for rebuild.write)",
+        "np.asarray of the rebuilt rows of the oldest chunk in "
+        "flight, dispatched REBUILD_DEPTH - 1 chunks earlier: "
+        "collects what dispatch asked back, waits only for what is "
+        "not back yet; bytes = rebuilt bytes collected",
     "rebuild.write":
-        "CRC accumulator feed and write of one rebuilt shard's chunk",
+        "CRC accumulator feed and write of one chunk's rebuilt rows, "
+        "as views of the collected array",
     "rebuild.finish":
         "close of the shard files, .ecc load-modify-save",
     "rebuild.mount":
         "re-load of a mounted volume's local shards, as after a "
         "rebuild (/admin/ec/mount)",
+    "beside.rebuild_read":
+        "the rebuild's read-ahead thread, beside the main thread and "
+        "in no sum: preadv of one chunk of every planned survivor, "
+        "several at once, in place, into a pooled (k, n) host buffer "
+        "(its wait for a free buffer is outside)",
     "req.beside_job":
         "request plane, not a job's thread: a needle request (upload, "
         "read or delete on a fid path) the volume server answered "
@@ -162,12 +177,13 @@ STAGES = {
 # drain would enclose JAX's XlaLinearize / PjitFunction / np.asarray
 # spans and take their place in a per-gap attribution; seal.stack runs
 # beside the main thread and would be credited with gaps it does not
-# cause (seal.stack_wait is what says the reader is the bound); a
+# cause (seal.stack_wait is what says the reader is the bound), and
+# so would beside.rebuild_read (rebuild.read is what says it); a
 # request row closes hundreds of times a second on threads that cause
 # no gap of the device.
 ANNOTATED_STAGES = frozenset(STAGES) - {
     "seal.stack", "seal.dispatch", "seal.drain",
-    "rebuild.dispatch", "rebuild.drain",
+    "rebuild.dispatch", "rebuild.drain", "beside.rebuild_read",
     "req.beside_job", "req.alone"}
 
 kernel_seconds_total = Counter(
@@ -466,9 +482,10 @@ class RooflineLedger:
                 "occupancy": self.occupancy_summary()}
 
     def mark_device(self) -> None:
-        """A kernel was launched whose wall nobody fenced (the seal's
-        pipeline, `PallasCoder.encode_unfenced`): it gets no row, but
-        `has_rows` has its answer."""
+        """A kernel was launched whose wall nobody fenced (the EC file
+        pipeline's `PallasCoder.encode_unfenced` and
+        `reconstruct_unfenced`): it gets no row, but `has_rows` has its
+        answer."""
         self._unfenced = True
 
     def has_rows(self) -> bool:
@@ -653,10 +670,12 @@ def debug_doc(node: str, role: str) -> dict:
     EC file pipeline's stage rows (same list, `kernel` = the stage's
     name, no dtype, geometry or work), recent invocations, recent
     pipeline gantts with bubble attribution, the conservation verdict,
-    device memory stats, the counts of the seal's host buffer pool
-    (ec/encoder.py CHUNK_POOL) and how the seal's drains found the
-    oldest chunk in flight (SEAL_INFLIGHT: `ready` or `waited`)."""
-    from ..ec.encoder import CHUNK_POOL, SEAL_INFLIGHT
+    device memory stats, the counts of the EC file pipeline's host
+    buffer pool (ec/encoder.py CHUNK_POOL, under its first name) and
+    how the drains of the seals and of the rebuilds found the oldest
+    chunk in flight (SEAL_INFLIGHT, REBUILD_INFLIGHT: `ready` or
+    `waited`)."""
+    from ..ec import encoder
     return {"node": node, "role": role, "armed": ARMED,
             "kernels": LEDGER.kernel_table() + LEDGER.stage_table(),
             "recent": LEDGER.recent(16),
@@ -664,5 +683,6 @@ def debug_doc(node: str, role: str) -> dict:
             "occupancy": LEDGER.occupancy_summary(),
             "conservation": LEDGER.conservation(),
             "devices": _device_memory_stats(),
-            "seal_buffers": CHUNK_POOL.counts(),
-            "seal_inflight": SEAL_INFLIGHT.counts()}
+            "seal_buffers": encoder.CHUNK_POOL.counts(),
+            "seal_inflight": encoder.SEAL_INFLIGHT.counts(),
+            "rebuild_inflight": encoder.REBUILD_INFLIGHT.counts()}

@@ -766,3 +766,442 @@ def test_a_handle_that_fails_at_the_drain_fails_the_job(
     assert inflight.counts() == {"ready": 2, "waited": 0}
     _seal(base, _HandleCoder([]))
     _assert_sealed_like_reference(base, blob)
+
+
+# -- the rebuild runs on the same pipeline, a deeper window in flight ----------
+
+def _sealed(tmp_path, codec: str = "rs", chunks: float = 3.5):
+    """Shards of a random volume, `chunks` rebuild chunks of CHUNK bytes
+    each (a half: the last one narrower), sealed by the reference
+    coder: `(base, {sid: its bytes})`."""
+    from seaweedfs_tpu.ec.integrity import ShardChecksums
+    size = int(chunks * DATA_SHARDS * CHUNK)
+    blob = random.Random(size).randbytes(size - 17)
+    base = _write_dat(tmp_path / codec, blob)
+    _seal(base, new_coder(backend="numpy", codec=codec))
+    shards = {}
+    for sid in range(TOTAL_SHARDS):
+        with open(base + to_ext(sid), "rb") as f:
+            shards[sid] = f.read()
+    assert len(shards[0]) == int(chunks * CHUNK)
+    assert sorted(ShardChecksums.load(base).shards) == sorted(shards)
+    return base, shards
+
+
+def _lose(base: str, lost) -> None:
+    """As `/admin/ec/delete_shards` leaves a volume: the files and
+    their `.ecc` entries gone."""
+    from seaweedfs_tpu.ec.integrity import ShardChecksums
+    ecc = ShardChecksums.load(base)
+    for sid in lost:
+        os.remove(base + to_ext(sid))
+        ecc.drop_shard(sid)
+    ecc.save()
+
+
+def _assert_rebuilt_like_reference(base: str, shards: dict, lost,
+                                   codec: str = "rs") -> None:
+    """The plain reference: NumpyCoder over the whole surviving shards
+    at once, no chunks, no pipeline — and what it gives is what was
+    sealed.  Every `.ecc` entry, the survivors' too, is the crc32c of
+    those bytes."""
+    from seaweedfs_tpu.ec.integrity import (BlockCrcAccumulator,
+                                            ShardChecksums)
+    want = new_coder(backend="numpy", codec=codec).reconstruct(
+        {sid: np.frombuffer(raw, np.uint8)
+         for sid, raw in shards.items() if sid not in lost},
+        wanted=list(lost))
+    ecc = ShardChecksums.load(base)
+    for sid, raw in shards.items():
+        if sid in lost:
+            assert want[sid].tobytes() == raw
+        with open(base + to_ext(sid), "rb") as f:
+            assert f.read() == raw, f"shard {sid}"
+        acc = BlockCrcAccumulator()
+        acc.feed(raw)
+        assert ecc.get(sid) == acc.finalize(), f".ecc of shard {sid}"
+
+
+def _dirty(pool) -> None:
+    """Every buffer the pool may hold, allocated and full of 0xFF."""
+    held = [pool.take(1) for _ in range(pool.bound)]
+    for buf in held:
+        buf[:] = 0xFF
+        pool.give(buf)
+
+
+@pytest.fixture
+def rebuild_inflight(monkeypatch):
+    from seaweedfs_tpu.ec import encoder
+    c = encoder._InflightCount()
+    monkeypatch.setattr(encoder, "REBUILD_INFLIGHT", c)
+    return c
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax", "pallas"])
+@pytest.mark.parametrize("codec,lost", [
+    pytest.param("rs", [3], id="one_lost"),
+    pytest.param("rs", [3, 11], id="two_lost"),
+    pytest.param("rs", [0, 5, 11], id="three_lost"),
+    pytest.param("rs", [0, 5, 11, 13], id="four_lost"),
+    pytest.param("lrc", [8], id="lrc_in_group"),
+])
+def test_rebuild_in_dirty_pooled_buffers_is_byte_identical(
+        tmp_path, pool, backend, codec, lost):
+    """Rebuilt shards and `.ecc` against the reference for one to four
+    lost shards, a shard size that is no multiple of the chunk (the
+    last chunk is half as wide) and an LRC in-group loss (5 survivors
+    read: half a buffer), in buffers full of another job's bytes."""
+    from seaweedfs_tpu.stats.metrics import ec_repair_read_bytes_total
+    base, shards = _sealed(tmp_path, codec)
+    _lose(base, lost)
+    _dirty(pool)
+    before = ec_repair_read_bytes_total.value(codec=codec)
+    got = rebuild_ec_files(base, coder=new_coder(backend=backend,
+                                                 codec=codec),
+                           chunk_size=CHUNK)
+    assert got == lost
+    reads = 5 if codec == "lrc" else DATA_SHARDS
+    assert ec_repair_read_bytes_total.value(codec=codec) - before == \
+        reads * len(shards[0])
+    _assert_rebuilt_like_reference(base, shards, lost, codec)
+
+
+def test_unfenced_reconstruct_is_one_call_and_no_kernel_row():
+    """`PallasCoder.reconstruct_unfenced` on ONE stacked host array:
+    the rows `reconstruct` gives — for exactly the survivors the decode
+    reads, for more of them (it takes its own) and for an LRC group's
+    five — as one array, and no fenced row in the ledger."""
+    from seaweedfs_tpu.ops.coder_numpy import NumpyCoder
+    from seaweedfs_tpu.ops.coder_pallas import PallasCoder
+    from seaweedfs_tpu.stats import roofline
+    rng = np.random.default_rng(31)
+    data = rng.integers(0, 256, (DATA_SHARDS, 5000), dtype=np.uint8)
+    for codec, present, wanted in [
+            ("rs", [0, 1, 2, 4, 5, 6, 7, 8, 9, 10], [3, 11]),
+            ("rs", [0, 1, 2, 4, 5, 6, 7, 8, 9, 10, 12, 13], [11, 3]),
+            ("lrc", [5, 6, 7, 9, 11], [8])]:
+        shards = np.asarray(NumpyCoder(codec=codec).encode_all(data))
+        coder = PallasCoder(codec=codec)
+        counted = sum(r["count"] for r in roofline.LEDGER.kernel_table())
+        got = coder.reconstruct_unfenced(
+            present, np.ascontiguousarray(shards[present]), wanted)
+        assert got.shape == (len(wanted), 5000)
+        assert np.array_equal(np.asarray(got), shards[wanted])
+        assert sum(r["count"] for r in
+                   roofline.LEDGER.kernel_table()) == counted
+        assert roofline.LEDGER.has_rows()
+    with pytest.raises(ValueError, match="out of range"):
+        coder.reconstruct_unfenced([5, 6, 7, 9, 11], shards[:5], [14])
+
+
+class _RebuildHandleCoder:
+    """`_HandleCoder` for the rebuild: `reconstruct_unfenced` returns at
+    once a handle that behaves as a device array does and reads the
+    stacked chunk only when it is materialised."""
+
+    def __init__(self, log: list, ready: bool = True,
+                 fail_at: int | None = None, before_call=None):
+        from seaweedfs_tpu.ops.coder_numpy import NumpyCoder
+        self._np = NumpyCoder(10, 4)
+        self.codec = self._np.codec
+        self.log, self.ready, self.fail_at = log, ready, fail_at
+        self.before_call = before_call
+        self.chunks: list[np.ndarray] = []
+
+    def reconstruct_unfenced(self, present, stacked, wanted):
+        coder, k = self, len(self.chunks)
+        if self.before_call is not None:
+            self.before_call(k)
+        self.chunks.append(stacked)
+        self.log.append(("call", k))
+
+        class Handle:
+            def copy_to_host_async(_self):
+                coder.log.append(("copy_back", k))
+
+            def is_ready(_self):
+                coder.log.append(("is_ready", k))
+                return coder.ready
+
+            def __array__(_self, dtype=None, copy=None):
+                coder.log.append(("array", k))
+                if k == coder.fail_at:
+                    raise RuntimeError("device fell over")
+                rec = coder._np.reconstruct(dict(zip(present, stacked)),
+                                            wanted=list(wanted))
+                return np.stack([rec[sid] for sid in wanted])
+        return Handle()
+
+
+def test_rebuilt_rows_are_collected_the_window_later(
+        tmp_path, pool, rebuild_inflight, monkeypatch):
+    """One event log of the main thread, whole: per chunk ONE coder
+    call on the stacked survivors and the copy back requested at once;
+    the handle is asked whether it is ready and materialised only
+    `REBUILD_DEPTH - 1` dispatches later, its rows written after that,
+    and the tail is drained oldest first."""
+    from seaweedfs_tpu.ec import encoder
+    chunks, lost = 9, [3, 11]
+    base, shards = _sealed(tmp_path, chunks=chunks)
+    _lose(base, lost)
+    log: list = []
+    real_write = encoder._shard_write
+
+    def logged_write(f, sid, buf, accs):
+        log.append(("write", sid))
+        real_write(f, sid, buf, accs)
+
+    monkeypatch.setattr(encoder, "_shard_write", logged_write)
+    coder = _RebuildHandleCoder(log)
+    assert rebuild_ec_files(base, coder=coder, chunk_size=CHUNK) == lost
+    later = encoder.REBUILD_DEPTH - 1
+    assert 1 < later < chunks
+
+    def drained(k):
+        return [("is_ready", k), ("array", k)] + \
+            [("write", sid) for sid in lost]
+
+    want: list = []
+    for k in range(chunks):
+        want += [("call", k), ("copy_back", k)]
+        if k >= later:
+            want += drained(k - later)
+    for k in range(chunks - later, chunks):
+        want += drained(k)
+    assert log == want
+    assert [c.shape for c in coder.chunks] == [(DATA_SHARDS, CHUNK)] * chunks
+    assert rebuild_inflight.counts() == {"ready": chunks, "waited": 0}
+    _assert_rebuilt_like_reference(base, shards, lost)
+
+
+@pytest.mark.parametrize("handles,want", [
+    pytest.param("ready", {"ready": 5, "waited": 0}, id="ready"),
+    pytest.param("not_ready", {"ready": 0, "waited": 5}, id="not_ready"),
+    pytest.param("arrays", {"ready": 5, "waited": 0}, id="plain_arrays"),
+])
+def test_rebuild_inflight_counts_what_the_drain_found(
+        tmp_path, pool, rebuild_inflight, inflight, handles, want):
+    """`rebuild_inflight` of `/debug/device`, as `seal_inflight`: the
+    rebuild's own count (the seal's stays 0), a host coder's rows
+    `ready`."""
+    from seaweedfs_tpu.ops.coder_numpy import NumpyCoder
+    from seaweedfs_tpu.stats import roofline
+    coder = NumpyCoder(10, 4) if handles == "arrays" \
+        else _RebuildHandleCoder([], ready=handles == "ready")
+    base, shards = _sealed(tmp_path, chunks=5)
+    _lose(base, [3, 11])
+    seals = inflight.counts()
+    rebuild_ec_files(base, coder=coder, chunk_size=CHUNK)
+    assert rebuild_inflight.counts() == want
+    doc = roofline.debug_doc("n:1", "volume")
+    assert doc["rebuild_inflight"] == want
+    assert doc["seal_inflight"] == seals
+    _assert_rebuilt_like_reference(base, shards, [3, 11])
+
+
+def test_survivors_are_the_coders_until_the_rows_are_drained(
+        tmp_path, pool):
+    """A handle that reads its stacked survivors only when drained (as
+    an unfenced device coder may), the reader running ahead meanwhile:
+    the rows are still the reference's, which they are not if a buffer
+    goes back to the reader before its chunk's rows are collected."""
+    import time as _t
+    base, shards = _sealed(tmp_path, chunks=16)
+    _lose(base, [3, 11])
+    coder = _RebuildHandleCoder([], before_call=lambda k: _t.sleep(0.002))
+    rebuild_ec_files(base, coder=coder, chunk_size=CHUNK)
+    _assert_rebuilt_like_reference(base, shards, [3, 11])
+
+
+def _all_buffers_read_ahead(pool, taken: int) -> None:
+    """Wait until the pool has handed out `taken` buffers: the reader
+    thread has every buffer its job may have live."""
+    import time as _t
+    deadline = _t.monotonic() + 10
+    while _taken(pool) < taken and _t.monotonic() < deadline:
+        _t.sleep(0.001)
+    assert _taken(pool) >= taken
+
+
+@pytest.mark.parametrize("fault", ["short_read", "drain", "write"])
+def test_a_failing_rebuild_ends_promptly_and_hands_its_buffers_back(
+        tmp_path, pool, rebuild_inflight, monkeypatch, fault):
+    """A short read on the reader thread, a handle that fails where it
+    is collected, a write that fails (a full disk): each raises out of
+    `rebuild_ec_files` promptly, the reader thread is joined, the
+    buffers that never reached the coder are back in the pool and those
+    of the chunks in flight (which a coder may still read) are not; the
+    next rebuild runs as if nothing had happened."""
+    import threading
+    import time as _t
+
+    from seaweedfs_tpu.ec import encoder
+    depth, buffers = encoder.REBUILD_DEPTH, encoder.REBUILD_BUFFERS
+    base, shards = _sealed(tmp_path, chunks=12)
+    lost = [3, 11]
+    _lose(base, lost)
+    # chunk 0's buffer is handed back before its rows are written, so
+    # by then the reader can have taken one more than it may hold
+    coder = _RebuildHandleCoder(
+        [], fail_at=1 if fault == "drain" else None,
+        before_call=lambda k: k == depth - 1 and
+        _all_buffers_read_ahead(pool, buffers))
+    if fault == "short_read":
+        real_preadv, calls = os.preadv, []
+
+        def short(fd, views, offset):
+            calls.append(offset)
+            n = real_preadv(fd, views, offset)
+            return n - 1 if len(calls) == 2 * DATA_SHARDS + 4 else n
+        monkeypatch.setattr(encoder.os, "preadv", short)
+        error, dropped = ValueError, 1        # the one being filled
+    elif fault == "drain":
+        error, dropped = RuntimeError, depth   # chunks 1 .. depth
+    else:
+        real_write = encoder._shard_write
+
+        def full_disk(f, sid, buf, accs):
+            _all_buffers_read_ahead(pool, buffers + 1)
+            raise OSError(28, "No space left on device")
+        monkeypatch.setattr(encoder, "_shard_write", full_disk)
+        error, dropped = OSError, depth - 1    # chunks 1 .. depth - 1
+    result: list = []
+
+    def run():
+        try:
+            rebuild_ec_files(base, coder=coder, chunk_size=CHUNK)
+            result.append("no-error")
+        except error as e:
+            result.append(e)
+
+    th = threading.Thread(target=run, daemon=True)
+    t0 = _t.monotonic()
+    th.start()
+    th.join(timeout=15)
+    assert not th.is_alive(), "rebuild_ec_files hung on the failure"
+    assert _t.monotonic() - t0 < 5
+    assert isinstance(result[0], error), result
+    assert not [t for t in threading.enumerate()
+                if t.name.startswith(("ec-read-ahead", "ec-rebuild-read"))]
+    c = pool.counts()
+    assert c["allocated"] <= buffers
+    assert c["held_bytes"] == (c["allocated"] - dropped) * pool.nbytes
+    for data in coder.chunks[-dropped:] if fault != "short_read" else []:
+        assert not any(np.shares_memory(data, buf) for buf in pool._free)
+    monkeypatch.undo()
+    for sid in lost:
+        os.remove(base + to_ext(sid))
+    assert rebuild_ec_files(base, coder=_RebuildHandleCoder([]),
+                            chunk_size=CHUNK) == lost
+    _assert_rebuilt_like_reference(base, shards, lost)
+
+
+def test_the_survivors_of_a_chunk_are_read_side_by_side(
+        tmp_path, pool, monkeypatch):
+    """The reader spreads a chunk's reads over `REBUILD_READERS`
+    threads: that many are inside `preadv` at once (a barrier only all
+    of them together pass), none of them the job's thread or the
+    read-ahead thread, and none is left when the job ends."""
+    import threading
+
+    from seaweedfs_tpu.ec import encoder
+    readers = encoder.REBUILD_READERS
+    assert 1 < readers and DATA_SHARDS % readers == 0
+    base, shards = _sealed(tmp_path)
+    _lose(base, [3, 11])
+    barrier = threading.Barrier(readers, timeout=10)
+    real_preadv, names = os.preadv, set()
+
+    def together(fd, views, offset):
+        names.add(threading.current_thread().name)
+        barrier.wait()
+        return real_preadv(fd, views, offset)
+
+    monkeypatch.setattr(encoder.os, "preadv", together)
+    rebuild_ec_files(base, coder=new_coder(backend="numpy"),
+                     chunk_size=CHUNK)
+    monkeypatch.undo()
+    assert len(names) == readers
+    assert all(n.startswith("ec-rebuild-read") for n in names)
+    assert not [t for t in threading.enumerate()
+                if t.name.startswith(("ec-read-ahead", "ec-rebuild-read"))]
+    _assert_rebuilt_like_reference(base, shards, [3, 11])
+
+
+def test_second_rebuild_allocates_nothing(tmp_path, pool, monkeypatch):
+    """The pool is the process's: a first rebuild allocates what it has
+    live (`REBUILD_BUFFERS`), a second allocates 0 — nothing per chunk,
+    nothing per volume — and `/debug/device` says so."""
+    from seaweedfs_tpu.ec import encoder
+    from seaweedfs_tpu.stats import roofline
+    chunks, lost = 9, [3, 11]
+    buffers = encoder.REBUILD_BUFFERS
+    assert chunks > buffers <= pool.bound
+    base, shards = _sealed(tmp_path, chunks=chunks)
+    # the seal that made the shards has used `pool`: an empty one
+    pool = encoder._ChunkPool(pool.bound, pool.nbytes)
+    monkeypatch.setattr(encoder, "CHUNK_POOL", pool)
+
+    def rebuild(taken_before: int):
+        _lose(base, lost)
+        coder = _RebuildHandleCoder(
+            [], before_call=lambda k: k == 0 and
+            _all_buffers_read_ahead(pool, taken_before + buffers))
+        rebuild_ec_files(base, coder=coder, chunk_size=CHUNK)
+        _assert_rebuilt_like_reference(base, shards, lost)
+        return pool.counts()
+
+    first = rebuild(0)
+    assert first == {"reused": chunks - buffers, "allocated": buffers,
+                     "held_bytes": buffers * pool.nbytes}
+    second = rebuild(chunks)
+    assert second == {"reused": first["reused"] + chunks,
+                      "allocated": buffers,
+                      "held_bytes": buffers * pool.nbytes}
+    assert roofline.debug_doc("n:1", "volume")["seal_buffers"] == second
+
+
+def test_a_seal_and_a_rebuild_at_once_keep_the_pool_at_its_bound(
+        tmp_path, pool, monkeypatch):
+    """Both jobs at once, every buffer either may hold live at the same
+    time: more than the bound exist for a while, no more than the bound
+    stay, and both jobs' files are the reference's."""
+    import threading
+
+    from seaweedfs_tpu.ec import encoder
+    from seaweedfs_tpu.ops.coder_numpy import NumpyCoder
+    live = encoder.SEAL_BUFFERS + encoder.REBUILD_BUFFERS
+    assert pool.bound == max(encoder.SEAL_BUFFERS,
+                             encoder.REBUILD_BUFFERS) < live
+    lost = [3, 11]
+    rebuilt, shards = _sealed(tmp_path, chunks=9)
+    _lose(rebuilt, lost)
+    # the seal that made the shards has used `pool`: an empty one
+    pool = encoder._ChunkPool(pool.bound, pool.nbytes)
+    monkeypatch.setattr(encoder, "CHUNK_POOL", pool)
+    blob = random.Random(12).randbytes(9 * DATA_SHARDS * CHUNK)
+    sealed = _write_dat(tmp_path / "v", blob)
+
+    class BothReadersFirst(NumpyCoder):
+        def encode(self, data):
+            _all_buffers_read_ahead(pool, live)
+            return super().encode(data)
+
+    jobs = [
+        threading.Thread(target=_seal, daemon=True,
+                         args=(sealed, BothReadersFirst(10, 4))),
+        threading.Thread(target=rebuild_ec_files, daemon=True, args=(
+            rebuilt, _RebuildHandleCoder(
+                [], before_call=lambda k: _all_buffers_read_ahead(
+                    pool, live)), CHUNK))]
+    for th in jobs:
+        th.start()
+    for th in jobs:
+        th.join(timeout=30)
+        assert not th.is_alive()
+    c = pool.counts()
+    assert c["allocated"] >= live
+    assert c["held_bytes"] == pool.bound * pool.nbytes
+    _assert_sealed_like_reference(sealed, blob)
+    _assert_rebuilt_like_reference(rebuilt, shards, lost)

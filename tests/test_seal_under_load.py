@@ -272,7 +272,7 @@ def test_a_request_is_beside_a_job_that_runs_at_either_end(cluster):
 
     routes[at] = (method, prefix, held, stream)
 
-    def one_read(job_first: bool) -> None:
+    def one_read(job_first: bool, total: int) -> None:
         entered.clear()
         go.clear()
         got: list = []
@@ -290,13 +290,16 @@ def test_a_request_is_beside_a_job_that_runs_at_either_end(cluster):
         t.join(DEADLINE)
         assert not t.is_alive()
         if not job_first:
+            # the server books the row after the response is written:
+            # the job lasts until it has
+            rows_at(total)
             job.__exit__(None, None, None)
         assert got == [b"x" * 100]
 
-    one_read(job_first=False)      # starts alone, ends inside the job
+    one_read(job_first=False, total=2)   # starts alone, ends inside the job
     rows = rows_at(2)
     assert (rows[BESIDE]["count"], rows[ALONE]["count"]) == (1, 1)
-    one_read(job_first=True)       # starts inside, ends after it
+    one_read(job_first=True, total=3)    # starts inside, ends after it
     rows = rows_at(3)
     assert (rows[BESIDE]["count"], rows[ALONE]["count"]) == (2, 1)
     routes[at] = (method, prefix, handler, stream)

@@ -33,8 +33,10 @@ pytestmark = pytest.mark.roofline
 
 BLOCK = SMALL_BLOCK_SIZE
 COUNTED_ONLY = {"seal.stack", "seal.dispatch", "seal.drain",
-                "rebuild.dispatch", "rebuild.drain",
+                "rebuild.dispatch", "rebuild.drain", "beside.rebuild_read",
                 "req.beside_job", "req.alone"}
+# the read-ahead threads' rows: beside the main thread, in no sum
+BESIDE = {"seal.stack", "beside.rebuild_read"}
 # the drives answer their one upload before any job starts
 DRIVEN = set(STAGES) - {"req.beside_job"}
 
@@ -118,9 +120,8 @@ def test_main_thread_stages_sum_to_the_wall(tmp_path, monkeypatch):
     coder = PallasCoder()
     warm = np.zeros((10, BLOCK), np.uint8)
     np.asarray(coder.encode_with_crc(warm)[0])      # compile outside
-    np.asarray(coder.reconstruct(
-        {s: warm[0] for s in range(14) if s not in (3, 11)},
-        wanted=[3, 11])[3])
+    np.asarray(coder.reconstruct_unfenced(
+        [s for s in range(12) if s not in (3, 11)], warm, [3, 11]))
     roofline.LEDGER.reset()
 
     clock = StageClock("rs")
@@ -128,7 +129,7 @@ def test_main_thread_stages_sum_to_the_wall(tmp_path, monkeypatch):
     write_ec_files(base, coder=coder, chunk_size=BLOCK, clock=clock)
     wall = time.perf_counter() - t0
     got = clock.totals()
-    main = sum(v["seconds"] for k, v in got.items() if k != "seal.stack")
+    main = sum(v["seconds"] for k, v in got.items() if k not in BESIDE)
     assert 0.90 * wall <= main <= wall, (main, wall, got)
     for stage in ("seal.dispatch", "seal.write_data", "seal.drain",
                   "seal.write_parity"):
@@ -160,16 +161,20 @@ def test_main_thread_stages_sum_to_the_wall(tmp_path, monkeypatch):
     got = clock.totals()
     assert set(got) == {"rebuild.read", "rebuild.dispatch",
                         "rebuild.drain", "rebuild.write",
-                        "rebuild.finish"}
-    main = sum(v["seconds"] for v in got.values())
+                        "rebuild.finish", "beside.rebuild_read"}
+    main = sum(v["seconds"] for k, v in got.items() if k not in BESIDE)
     assert 0.90 * wall <= main <= wall, (main, wall, got)
-    for stage in ("rebuild.read", "rebuild.dispatch"):
+    # a chunk is dispatched, drained and written once, whole
+    for stage in ("rebuild.dispatch", "rebuild.drain", "rebuild.write",
+                  "beside.rebuild_read"):
         assert got[stage]["count"] == chunks, stage
-    # drained and written one rebuilt row at a time
-    for stage in ("rebuild.drain", "rebuild.write"):
-        assert got[stage]["count"] == 2 * chunks, stage
-    assert got["rebuild.read"]["bytes"] == 10 * n
+    # one more wait than chunks (the end of the stream)
+    assert got["rebuild.read"]["count"] == chunks + 1
+    assert got["rebuild.read"]["bytes"] == 0
+    assert got["beside.rebuild_read"]["bytes"] == 10 * n
+    assert got["rebuild.dispatch"]["bytes"] == 10 * n
     assert got["rebuild.drain"]["bytes"] == 2 * n
+    assert got["rebuild.write"]["bytes"] == 2 * n
     for sid in (3, 11):
         with open(base + to_ext(sid), "rb") as a, \
                 open(want + to_ext(sid), "rb") as b:
@@ -230,7 +235,8 @@ def test_stage_rows_ride_debug_device_events_and_the_span(
             ("ec.encode.finish", "seal.", "/admin/ec/generate"),
             ("ec.rebuild.finish", "rebuild.", "/admin/ec/rebuild")):
         stages = got["finish"][kind]["attrs"]["stages"]
-        assert stages and all(s.startswith(prefix) for s in stages)
+        assert stages and all(s.startswith(prefix) or s in BESIDE
+                              for s in stages)
         assert f"{prefix}drain" in stages
         assert all(set(v) == {"count", "seconds", "bytes"}
                    for v in stages.values())
@@ -255,6 +261,7 @@ def test_cluster_roofline_prints_stages_in_a_section_of_their_own(
     assert "seal.write_data" in tail and "encode_kernel" not in tail
     assert "seal.stack host buffers: " in tail and " MiB held" in tail
     assert "seal.drain: " in tail and " waited for" in tail
+    assert "rebuild.drain: " in tail
 
 
 def test_debug_device_serves_seal_inflight(tmp_path, monkeypatch):
@@ -269,6 +276,22 @@ def test_debug_device_serves_seal_inflight(tmp_path, monkeypatch):
     assert sum(got["seal_inflight"].values()) == drains["count"] >= 1
     assert set(got["seal_buffers"]) == {"reused", "allocated",
                                         "held_bytes"}
+
+
+def test_debug_device_serves_rebuild_inflight(tmp_path, monkeypatch):
+    """... and, beside `seal_inflight`, the same for the rebuild's
+    drains: one count per drained chunk of the driven rebuild."""
+    from seaweedfs_tpu.ec import encoder
+    monkeypatch.setattr(encoder, "REBUILD_INFLIGHT",
+                        encoder._InflightCount())
+    got = _stage_drive.drive(str(tmp_path))["device"]
+    drains = next(r for r in got["kernels"]
+                  if r["kernel"] == "rebuild.drain")
+    assert set(got["rebuild_inflight"]) == {"ready", "waited"}
+    assert sum(got["rebuild_inflight"].values()) == drains["count"] >= 1
+    beside = next(r for r in got["kernels"]
+                  if r["kernel"] == "beside.rebuild_read")
+    assert beside["count"] == drains["count"]
 
 
 # -- (c) the annotated stages on the profiler's clock ---------------------------
