@@ -408,7 +408,7 @@ def test_load_readers_on_facts_made_by_hand(name, want):
     assert read(facts(without)) is None
     assert read(facts({})) is None
     entry = next(m for m in MAN["per_layer"] if m["name"] == name)
-    assert entry["workloads"] == ["seal-under-load"]
+    assert "seal-under-load" in entry["workloads"]
     assert entry["moves"] == ("req_per_s" if "_req_" in name
                               else "seal_MBps")
     assert entry["layer"] == ("rpc plane + volume engine"
@@ -426,11 +426,11 @@ def test_load_req_beside_share_reads_zero_where_no_job_ran():
 def test_the_new_cell_is_entries_appended_and_files_added():
     """PR 27's entries come after everything PR 25 left, in this order,
     and the cell reports what the issue lists for it."""
-    assert [c["name"] for c in MAN["configs"]][2:] == [
+    assert [c["name"] for c in MAN["configs"]][2:3] == [
         "live-ec-maintenance"]
-    assert [w["name"] for w in MAN["workloads"]][3:] == ["seal-under-load"]
+    assert [w["name"] for w in MAN["workloads"]][3:4] == ["seal-under-load"]
     names = [m["name"] for m in MAN["per_layer"]]
-    assert names[27:] == list(WANT)
+    assert names[27:32] == list(WANT)
     cell = manifest.cell(MAN, "seal-under-load")
     assert cell["traffic"]["jobs"] == {
         "op": "ec.encode", "per_second": 0.3, "metric": "seal_MBps"}
