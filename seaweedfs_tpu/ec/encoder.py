@@ -16,7 +16,10 @@ keep outputs byte-identical:
 
 from __future__ import annotations
 
+import collections
+import functools
 import os
+import queue
 import threading
 from concurrent.futures import ThreadPoolExecutor
 
@@ -37,7 +40,16 @@ from ..storage.needle_map import MemDb
 DEFAULT_CHUNK = 4 * 1024 * 1024
 
 # Chunks `_pipelined_encode` keeps in flight between dispatch and drain.
-SEAL_DEPTH = 2
+# Since its shard writes left the main thread (the writer stage of
+# `_run_pipeline`) a turn of the loop is shorter than a chunk's device
+# round trip.  The chip's readings (PERF.md section 6, PR 33; MB/s;
+# `waited` of 39 drains; `seal.drain` ms a chunk): 2: 1427, 1427;
+# 17-20; 10.4-13.5 - 3: 1359-1671 over eight runs; 4-14; 6.7-8.9 - 4:
+# 1277-1373; 1-5; 5.3-8.6 - 5: 1184, 1378; 2-5; 3.7-3.9.  Past 3 the
+# wait only moves from the drain into the dispatch (the transfers'
+# layout passes on the host set the pace, not the round trip's length)
+# and every chunk more in flight is 40 MiB more of fresh memory.
+SEAL_DEPTH = 3
 
 # Chunks `rebuild_ec_files` keeps in flight: its main thread spends a
 # third of a chunk's device round trip per chunk, so the window has to
@@ -45,21 +57,38 @@ SEAL_DEPTH = 2
 # the chip's readings at 2, 3, 4 and 5).
 REBUILD_DEPTH = 4
 
-# Pooled buffers one job has live: its chunks in flight, the chunks
-# read ahead of them, one being filled.  The seal's main thread is the
-# slow side (it writes 40 MiB a chunk), so it is read ahead as deep as
-# its window; the rebuild's reader is the fast side.
-SEAL_BUFFERS = 2 * SEAL_DEPTH + 1
+# Threads that write the seal's fourteen shard files, shard `sid`
+# always thread `sid % SEAL_WRITERS`'s.  The chip's readings (PR 33;
+# MB/s; hand-overs of 39 that `waited` for the writers): 1: 1141,
+# 1300; 18-19 - 2: 1292, 1420; 0-11 - 3: 1359-1671 over eight runs;
+# 0-1 - 4: 1431-1602; 0-1 - 5: 1494-1722; 0.  From 3 on the main
+# thread never waits for them and the rates do not separate.
+SEAL_WRITERS = 3
+
+# Pooled buffers one job has live: its chunks in flight, one read
+# ahead of them, one being filled.  The seal's writers hold a chunk's
+# buffer until its data rows are in their files, and the main thread
+# does not hand them chunk k before chunk k - SEAL_DEPTH is written:
+# what they lag stays inside the window.  (A second chunk read ahead
+# gave the seal nothing: PR 33, `seal.stack_wait` 0.02-0.41 ms a chunk
+# with one, 0.16-0.52 with two.)
+SEAL_BUFFERS = SEAL_DEPTH + 2
 REBUILD_BUFFERS = REBUILD_DEPTH + 2
 
-# Threads the rebuild's reader spreads the survivors of one chunk over.
-# One thread copies the page cache into a pooled buffer at a third of
-# the pace the serial loop read into its one warm 4 MiB of heap, and
-# then sets the job's pace; the survivors are separate files, on
-# separate disks where a volume server has them, so their reads go out
-# side by side (PERF.md section 6, PR 31: one thread 17 ms a chunk,
-# five 7 ms beside the pipeline, ten no better).
+# Threads a job's reader spreads the reads of one chunk over: the
+# rebuild's ten survivors, the seal's stripe rows (four reads a 40 MiB
+# chunk of small blocks).  One thread copies the page cache into a
+# pooled buffer at a third of the pace the serial loop read into its
+# one warm 4 MiB of heap, and then sets the job's pace; the survivors
+# are separate files, on separate disks where a volume server has
+# them, so their reads go out side by side (PERF.md section 6, PR 31:
+# one thread 17 ms a chunk, five 7 ms beside the pipeline, ten no
+# better).  The seal's readings beside its writers and transfers
+# (PR 33; ms a chunk; the main thread's wait for the reader): one
+# thread 24.0-27.8; 9.2-10.0 - two 18.4-19.7; 1.1-3.0 - five (four at
+# work) 13.9-17.2; 0.2-0.5: one constant serves both jobs.
 REBUILD_READERS = 5
+SEAL_READERS = REBUILD_READERS
 
 # Host buffers of one default chunk (40 MiB) that stay with the process
 # between jobs: what the larger of the two jobs has live
@@ -110,13 +139,17 @@ CHUNK_POOL = _ChunkPool(CHUNK_POOL_BUFFERS, DATA_SHARDS * DEFAULT_CHUNK)
 
 
 class _InflightCount:
-    """How a job's drain found the oldest chunk in flight,
-    process-wide, one count for the seals and one for the rebuilds:
-    `ready` (the device was done with it: its round trip hid behind
-    the main thread's work) or `waited`.  All `waited` means the device
-    path, not the main thread, sets the pace.  A device array is ready
-    when it is computed; whether the copy back had landed as well is
-    what the drain stage's seconds say."""
+    """How a job's main thread found what it was about to wait for,
+    process-wide, one count per place it may wait: `ready` or `waited`.
+    The drains (one count for the seals, one for the rebuilds) note the
+    oldest chunk in flight: `ready` says the device was done with it,
+    its round trip hid behind the main thread's work, and all `waited`
+    that the device path sets the pace.  A device array is ready when
+    it is computed; whether the copy back had landed as well is what
+    the drain stage's seconds say.  The seal's hand-over notes the
+    writer stage: `ready` says the writers were inside the window when
+    the main thread had a chunk's data rows for them, and all `waited`
+    that the writers set the pace."""
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -131,7 +164,7 @@ class _InflightCount:
                 self._waited += 1
 
     def counts(self) -> dict:
-        """Chunks drained so far, by what the drain found
+        """Chunks so far, by what the main thread found
         (`/debug/device`)."""
         with self._lock:
             return {"ready": self._ready, "waited": self._waited}
@@ -139,6 +172,7 @@ class _InflightCount:
 
 SEAL_INFLIGHT = _InflightCount()
 REBUILD_INFLIGHT = _InflightCount()
+SEAL_WRITER = _InflightCount()
 
 
 def _request_copy_back(handle) -> None:
@@ -319,20 +353,27 @@ def _pread_into(fd: int, views: list, offset: int) -> None:
 
 
 def _read_chunk(fd: int, width: int, reads,
-                flat: np.ndarray | None = None) -> np.ndarray:
+                flat: np.ndarray | None = None, each=map) -> np.ndarray:
     """Build one chunk of `_chunk_spans` in place: no intermediate
     `bytes`, no copy.  In `flat` (a buffer of at least
     `DATA_SHARDS * width` bytes that the caller owns and may reuse) if
     given, else in a fresh array that belongs to whoever gets the
     chunk.  Either way the chunk is C-contiguous — a narrower last
     chunk is viewed out of the front of `flat`, not sliced out of wider
-    rows — so `jnp.asarray` does not copy it first."""
+    rows — so `jnp.asarray` does not copy it first.  The reads fill
+    disjoint views; `each` (`map`, or an executor's) says whether they
+    go out one after the other or side by side."""
     if flat is None:
         flat = np.empty(DATA_SHARDS * width, dtype=np.uint8)
     data = flat[:DATA_SHARDS * width].reshape(DATA_SHARDS, width)
-    for offset, row, nrows, col, n in reads:
+
+    def read(span) -> None:
+        offset, row, nrows, col, n = span
         _pread_into(fd, [data[row + j, col:col + n] for j in range(nrows)],
                     offset)
+
+    for _ in each(read, reads):
+        pass
     return data
 
 
@@ -346,13 +387,107 @@ def _chunk_reader(dat, dat_size: int, large: int, small: int,
         yield _read_chunk(fd, width, reads)
 
 
+class _Countdown:
+    """Calls `then()` on the `n`-th `done()`, from whichever thread
+    makes it: how a pooled buffer, or a slot of the writers' window,
+    goes back when the last of several parties is finished with it."""
+    __slots__ = ("_left", "_then", "_lock")
+
+    def __init__(self, n: int, then):
+        self._left = n
+        self._then = then
+        self._lock = threading.Lock()
+
+    def done(self) -> None:
+        with self._lock:
+            self._left -= 1
+            last = self._left == 0
+        if last:
+            self._then()
+
+
+class _Writers:
+    """The writer stage of `_run_pipeline`: `SEAL_WRITERS` threads that
+    call the job's `write(sid, row)` beside the main thread, each row a
+    closed `stage` of `clock`.  Shard `sid` is always thread
+    `sid % SEAL_WRITERS`'s and a thread writes in the order it was
+    handed, so every shard file is written by one thread, in chunk
+    order.  A `write` that raises ends the job: the error joins
+    `error`, `cancelled` is set, and every thread then lets what it is
+    still handed go unwritten (its buffers are released all the same:
+    nobody reads them any more)."""
+
+    def __init__(self, write, window: int, clock: StageClock, stage: str,
+                 cancelled: threading.Event, error: list):
+        self._write, self._clock, self._stage = write, clock, stage
+        self._cancelled, self._error = cancelled, error
+        self._slots = threading.Semaphore(window)
+        self._queues = [queue.SimpleQueue() for _ in range(SEAL_WRITERS)]
+        self._threads = [
+            threading.Thread(target=self._loop, args=(q,), daemon=True,
+                             name=f"ec-write-{i}")
+            for i, q in enumerate(self._queues)]
+        for t in self._threads:
+            t.start()
+
+    def _loop(self, q) -> None:
+        while True:
+            item = q.get()
+            if item is None:
+                return
+            sid, row, done = item
+            if not self._cancelled.is_set():
+                try:
+                    with self._clock(self._stage, row.nbytes):
+                        self._write(sid, row)
+                except BaseException as e:  # noqa: BLE001 — surfaced
+                    self._error.append(e)   # by _run_pipeline
+                    self._cancelled.set()
+            if done is not None:
+                done()
+
+    def hand(self, first_sid: int, rows, done=None) -> None:
+        """Queue `rows[i]` for shard `first_sid + i`; `done()` is
+        called once per row, when it is written."""
+        for sid, row in enumerate(rows, first_sid):
+            self._queues[sid % len(self._queues)].put((sid, row, done))
+
+    def hand_chunk(self, release, first_sid: int, rows) -> None:
+        """`hand` for rows that are views of a chunk's pooled buffer:
+        `release()` is called when the last of them is written.  Holds
+        the caller while the writers are a whole window behind — the
+        chunk handed `window` chunks ago is not written yet
+        (`SEAL_WRITER` counts how the call found them)."""
+        ready = self._slots.acquire(blocking=False)
+        SEAL_WRITER.note(ready)
+        while not ready:
+            if self._cancelled.is_set():
+                raise self._error[0]
+            ready = self._slots.acquire(timeout=0.2)
+
+        def written() -> None:
+            release()
+            self._slots.release()
+
+        self.hand(first_sid, rows, _Countdown(len(rows), written).done)
+
+    def join(self) -> None:
+        """Wait until everything handed is written (or, after
+        `cancelled`, let go), and for the threads to end."""
+        for q in self._queues:
+            q.put(None)
+        for t in self._threads:
+            t.join()
+
+
 def _run_pipeline(chunks, fill, dispatch, flush, *, depth: int,
                   buffers: int, clock: StageClock, wait_stage: str,
-                  fill_stage: str) -> None:
+                  fill_stage: str, write=None, write_stage: str = "",
+                  write_tail_stage: str = "") -> None:
     """The read-ahead pipeline of both EC file jobs (the seal's
     `_pipelined_encode`, `rebuild_ec_files`): the skeleton that owns
-    the two threads, the pooled buffers and the window of chunks in
-    flight; what a chunk IS comes in as three functions.
+    the threads, the pooled buffers and the window of chunks in
+    flight; what a chunk IS comes in as three functions, or four.
 
       reader thread:  wait for a free buffer (the job has `buffers`)
                       data = fill(what, buffer)         `fill_stage`
@@ -360,21 +495,33 @@ def _run_pipeline(chunks, fill, dispatch, flush, *, depth: int,
                       handles = dispatch(data)
                       with `depth` chunks in flight:
                         flush(oldest handles, release)
+      writer threads, for a job that hands `write`:
+                      write(sid, row) of a row the
+                        main thread handed them        `write_stage`
 
     `chunks` yields `(nbytes, what)` per chunk: the bytes of buffer it
     needs and what `fill` is to read into it.  `dispatch` and `flush`
     time their own stages.  `flush` collects the oldest chunk's
-    handles, THEN calls `release()`, then writes.  One ownership rule:
-    a chunk's buffer goes back to `CHUNK_POOL`, and so to the reader,
-    only in that `release()` — when the chunk's results are drained.
-    Until then the coder may still read it: a device coder transfers
-    asynchronously, and on the CPU platform `jnp.asarray` may alias the
-    host array.  An error on either thread ends the job: the reader is
-    cancelled and joined, what never reached the coder goes back to the
-    pool, and the first error is raised."""
-    import collections
-    import queue
+    handles, THEN calls `release()`, then writes.  A chunk's buffer
+    goes back to `CHUNK_POOL`, and so to the reader, only when its
+    results are drained (that `release()`): until then the coder may
+    still read it — a device coder transfers asynchronously, and on
+    the CPU platform `jnp.asarray` may alias the host array.
 
+    A job that hands `write` writes nothing on the main thread.  Its
+    functions are called as `dispatch(data, hand)` and
+    `flush(handles, release, hand)` and give rows to the writer threads
+    (`_Writers`) with `hand(first_sid, rows)`.  The rows `dispatch`
+    hands are views of the chunk's buffer, and it hands them for every
+    chunk: the buffer then goes back when they are written AND the
+    chunk is drained, whichever comes last, and `dispatch` is held in
+    `hand` while the writers are `depth` chunks behind.  The rows
+    `flush` hands own their bytes.  When the last chunk is flushed the
+    main thread waits for the writers (`write_tail_stage`).
+
+    An error on any thread ends the job: reader and writers are
+    cancelled and joined, what never reached the coder goes back to
+    the pool, and the first error is raised."""
     q: "queue.Queue" = queue.Queue()
     free = threading.Semaphore(buffers)
     cancelled = threading.Event()
@@ -390,6 +537,8 @@ def _run_pipeline(chunks, fill, dispatch, flush, *, depth: int,
                 while not free.acquire(timeout=0.2):
                     if cancelled.is_set():
                         return
+                if cancelled.is_set():      # a writer failed
+                    return
                 buf = CHUNK_POOL.take(nbytes)
                 with clock(fill_stage) as st:
                     data = fill(what, buf)
@@ -402,12 +551,22 @@ def _run_pipeline(chunks, fill, dispatch, flush, *, depth: int,
     t = threading.Thread(target=read_loop, daemon=True,
                          name="ec-read-ahead")
     t.start()
+    writers = None if write is None else \
+        _Writers(write, depth, clock, write_stage, cancelled, error)
+    # per chunk in flight: its handles, and the `release` its drain
+    # is to call
     inflight: "collections.deque" = collections.deque()
-    lent: "collections.deque" = collections.deque()  # their buffers
 
-    def release() -> None:
-        CHUNK_POOL.give(lent.popleft())
+    def give_back(buf) -> None:
+        CHUNK_POOL.give(buf)
         free.release()
+
+    def drain_oldest() -> None:
+        handles, release = inflight.popleft()
+        if writers is None:
+            flush(handles, release)
+        else:
+            flush(handles, release, writers.hand)
 
     try:
         while True:
@@ -416,15 +575,31 @@ def _run_pipeline(chunks, fill, dispatch, flush, *, depth: int,
             if item is None:
                 break
             data, buf = item
-            lent.append(buf)
-            inflight.append(dispatch(data))
+            if cancelled.is_set():          # a writer failed
+                CHUNK_POOL.give(buf)
+                break
+            release = functools.partial(give_back, buf)
+            if writers is None:
+                handles = dispatch(data)
+            else:
+                # two may still read the buffer: the coder until the
+                # drain, the writers until its data rows are written
+                release = _Countdown(2, release).done
+                handles = dispatch(
+                    data, functools.partial(writers.hand_chunk, release))
+            inflight.append((handles, release))
             if len(inflight) >= depth:
-                flush(inflight.popleft(), release)
-        while inflight:
-            flush(inflight.popleft(), release)
+                drain_oldest()
+        while inflight and not cancelled.is_set():
+            drain_oldest()
+        if writers is not None:
+            with clock(write_tail_stage):
+                writers.join()
     finally:
         cancelled.set()
         t.join()
+        if writers is not None:
+            writers.join()
         # After a failure: what never reached the coder goes back to
         # the pool; a chunk that was in flight is dropped with its
         # buffer, which the coder may still read.
@@ -438,37 +613,52 @@ def _run_pipeline(chunks, fill, dispatch, flush, *, depth: int,
 
 def _pipelined_encode(fd: int, spans, coder: ErasureCoder, outputs,
                       accs=None, clock: StageClock | None = None):
-    """Double-buffered encode pipeline (SURVEY §2.3 'double-buffered
+    """The seal's encode pipeline (SURVEY §2.3 'double-buffered
     host→HBM DMA + batched kernel launches') over the chunks `spans`
     (`_chunk_spans`) of the file `fd`, on `_run_pipeline`, each step a
-    stage of `clock` (stats/roofline.py STAGES):
+    stage of `clock` (stats/roofline.py STAGES).  The main thread
+    writes nothing:
 
       reader thread:  wait for a free buffer
-                      read chunk k+1 into it   seal.stack
-      main thread:    wait for chunk k         seal.stack_wait
+                      read chunk k+1 into it,
+                        SEAL_READERS reads at once   seal.stack
+      main thread:    wait for chunk k               seal.stack_wait
                       issue H2D of k, launch,
-                        request D2H            seal.dispatch
-                      write data shards of k   seal.write_data
-                      collect parity of k-1    seal.drain
-                      hand its buffer back
-                      write it                 seal.write_parity
+                        request D2H                  seal.dispatch
+                      hand the data rows of k to
+                        the writers                  seal.write_data
+                      collect parity of
+                        k-(SEAL_DEPTH-1)             seal.drain
+                      hand it to the writers         seal.write_parity
+      writer threads: write a row into its shard
+                        file, SEAL_WRITERS at once   beside.seal_write
 
     This is a caller of the coder that drains later, so it asks for the
     unfenced call (`encode_unfenced`, where the coder has one;
     `RooflineLedger.record` takes fenced walls only, so that call
     records no kernel row) and for the copy back (where the handle can
-    copy asynchronously).  The device round trip of chunk k —
-    host→device, kernel, device→host — then runs beside the data-shard
-    writes of k, and the drain of a chunk dispatched a whole iteration
-    earlier finds its bytes on the host (`SEAL_INFLIGHT` counts how
-    often).  A host coder computes inside dispatch and its arrays count
-    as ready.  A device error surfaces at the drain.
+    copy asynchronously).  With the writes beside it the main thread's
+    turn is shorter than the device round trip of a chunk —
+    host→device, kernel, device→host — so the parity is collected
+    `SEAL_DEPTH - 1` chunks later, when its bytes are on the host
+    (`SEAL_INFLIGHT` counts how often).  A host coder computes inside
+    dispatch and its arrays count as ready.  A device error surfaces at
+    the drain.
+
+    The rows go to the writers as views — of the pooled chunk, of the
+    collected parity — and a shard file is one writer thread's, in
+    chunk order, so `_shard_write` sees each shard's bytes in file
+    order (the byte accumulators of the non-fused path depend on it).
+    `seal.write_data` is the hand-over and, if the writers are a whole
+    window behind, the wait for them (`SEAL_WRITER` counts how often);
+    `seal.write_parity` the hand-over and, once, the wait for the last
+    rows.  When this function returns every row is in its file.
 
     The chunks live in `SEAL_BUFFERS` buffers of `CHUNK_POOL`
-    (`SEAL_DEPTH` in flight, as many read ahead, one being filled), and
-    that count is what bounds the read-ahead.  A buffer goes back to
-    the pool only when its chunk is finished — its data shards written
-    AND its parity drained.
+    (`SEAL_DEPTH` in flight or being written, one read ahead, one
+    being filled), and that count is what bounds the read-ahead.  A
+    buffer goes back to the pool only when its chunk is finished —
+    its data rows written AND its parity drained.
 
     When ``accs is None`` the coder must support fused CRC
     (`encode_with_crc`) and every chunk must span whole `.ecc` blocks:
@@ -478,18 +668,22 @@ def _pipelined_encode(fd: int, spans, coder: ErasureCoder, outputs,
     if clock is None:
         clock = StageClock()
     data_shards = coder.data_shards
-    parity_shards = coder.parity_shards
     fused = accs is None
     unfenced = getattr(coder, "encode_unfenced", None)
     crc_lists: list[list[int]] = \
-        [[] for _ in range(data_shards + parity_shards)]
+        [[] for _ in range(data_shards + coder.parity_shards)]
+    readers = ThreadPoolExecutor(SEAL_READERS,
+                                 thread_name_prefix="ec-seal-read")
 
     def fill(span, buf):
-        return _read_chunk(fd, *span, buf)
+        return _read_chunk(fd, *span, buf, readers.map)
 
-    def dispatch(data):
+    def write(sid, row) -> None:
+        _shard_write(outputs[sid], sid, row, accs)
+
+    def dispatch(data, hand):
         # Dispatch first: a device coder's transfer, kernel and copy
-        # back run while we write the data shards.
+        # back run beside the next chunks' turns.
         with clock("seal.dispatch", data.nbytes):
             if unfenced is not None:
                 handles = unfenced(data, crc=fused)
@@ -500,11 +694,10 @@ def _pipelined_encode(fd: int, spans, coder: ErasureCoder, outputs,
             for h in handles:
                 _request_copy_back(h)
         with clock("seal.write_data", data.nbytes):
-            for i in range(data_shards):
-                _shard_write(outputs[i], i, data[i].tobytes(), accs)
+            hand(0, data)
         return handles
 
-    def flush(handles, release) -> None:
+    def flush(handles, release, hand) -> None:
         with clock("seal.drain") as st:
             SEAL_INFLIGHT.note(all(_is_ready(h) for h in handles))
             parity = np.asarray(handles[0])
@@ -514,20 +707,22 @@ def _pipelined_encode(fd: int, spans, coder: ErasureCoder, outputs,
                 for sid, row in enumerate(crcs):
                     crc_lists[sid].extend(int(c) for c in row)
                 st.add_bytes(crcs.nbytes)
-        # The oldest chunk is finished (its data shards were written
-        # before this call): the reader may have its buffer.
+        # The coder is done with the oldest chunk: its buffer goes back
+        # as soon as the writers are done with its data rows too.
         release()
         with clock("seal.write_parity", parity.nbytes):
-            for p in range(parity_shards):
-                sid = data_shards + p
-                _shard_write(outputs[sid], sid, parity[p].tobytes(),
-                             accs)
+            hand(data_shards, parity)
 
-    _run_pipeline(((DATA_SHARDS * width, (width, reads))
-                   for width, reads in spans),
-                  fill, dispatch, flush, depth=SEAL_DEPTH,
-                  buffers=SEAL_BUFFERS, clock=clock,
-                  wait_stage="seal.stack_wait", fill_stage="seal.stack")
+    try:
+        _run_pipeline(((DATA_SHARDS * width, (width, reads))
+                       for width, reads in spans),
+                      fill, dispatch, flush, depth=SEAL_DEPTH,
+                      buffers=SEAL_BUFFERS, clock=clock,
+                      wait_stage="seal.stack_wait", fill_stage="seal.stack",
+                      write=write, write_stage="beside.seal_write",
+                      write_tail_stage="seal.write_parity")
+    finally:
+        readers.shutdown()
     return dict(enumerate(crc_lists)) if fused else None
 
 

@@ -452,6 +452,12 @@ class ClusterRoofline(Command):
                 lines.append(
                     f"{job}.drain: {inflight['ready']} chunks were ready "
                     f"on the device, {inflight['waited']} waited for")
+        writer = doc.get("seal_writer")
+        if writer:
+            lines.append(
+                f"seal.write_data: {writer['ready']} chunks found the "
+                f"writers inside the window, {writer['waited']} waited "
+                "for them")
         occ_lines = []
         if flags.get("node"):
             occ = (doc.get("occupancy") or {}).get("latest", {})

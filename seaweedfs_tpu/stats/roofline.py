@@ -100,9 +100,10 @@ PIPELINE_STAGES = ("stack", "dispatch", "device", "drain")
 # coder's fence.  Closed like KERNELS: StageClock raises on any other
 # name.  The main-thread stages of one job are contiguous and never
 # nest: their seconds sum to the job's wall (`seal.stack` and
-# `beside.rebuild_read` are the read-ahead threads', beside it; the
-# second is named so that no sum over `rebuild.` takes it for a
-# main-thread row).  The two `req.` rows are the request plane's,
+# `beside.rebuild_read` are the read-ahead threads', `beside.seal_write`
+# the seal's writer threads', beside it; the `beside.` rows are named so
+# that no sum over `seal.` or `rebuild.` takes them for a main-thread
+# row).  The two `req.` rows are the request plane's,
 # booked by `note_request` on the threads that answer needle requests:
 # what a job in the same process costs them.
 
@@ -113,20 +114,28 @@ STAGES = {
         "wait)",
     "seal.stack":
         "read-ahead thread, beside the main thread and in no sum: "
-        "preadv of the stripe rows, in place, into a pooled (10, n) "
-        "host buffer (its wait for a free buffer is outside)",
+        "preadv of the stripe rows, several at once, in place, into a "
+        "pooled (10, n) host buffer (its wait for a free buffer is "
+        "outside)",
     "seal.dispatch":
         "the coder's encode call as the pipeline makes it: H2D issue, "
         "kernel launch, request of the copy back; a device coder is "
         "waited for in seal.drain, a host coder computes here",
     "seal.write_data":
-        "tobytes + write of the data shards of one chunk",
+        "hand-over of one chunk's data rows to the writer threads, as "
+        "views of the pooled chunk, and the wait for them where they "
+        "are a whole window of chunks behind (the writes themselves "
+        "are beside.seal_write): what the shard writes cost the main "
+        "thread",
     "seal.drain":
         "np.asarray of the parity and CRC handles of the oldest chunk "
         "in flight: collects what dispatch asked back, waits only for "
         "what is not back yet; bytes = parity + CRC bytes collected",
     "seal.write_parity":
-        "write of the parity shards of one chunk",
+        "hand-over of one chunk's parity rows to the writer threads, "
+        "as views of the collected array, and at the end of the job "
+        "the wait until the last rows are in their files (one more "
+        "count than chunks)",
     "seal.finish":
         "close of the shard files, .vif, .ecc save, .ecx",
     "seal.mount":
@@ -161,6 +170,12 @@ STAGES = {
         "in no sum: preadv of one chunk of every planned survivor, "
         "several at once, in place, into a pooled (k, n) host buffer "
         "(its wait for a free buffer is outside)",
+    "beside.seal_write":
+        "the seal's writer threads, beside the main thread and in no "
+        "sum: one row of one chunk into its shard file, several "
+        "threads at once, a shard file always the same thread's (CRC "
+        "accumulator feed first on the non-fused path); seconds are "
+        "summed over the threads",
     "req.beside_job":
         "request plane, not a job's thread: a needle request (upload, "
         "read or delete on a fid path) the volume server answered "
@@ -178,13 +193,14 @@ STAGES = {
 # spans and take their place in a per-gap attribution; seal.stack runs
 # beside the main thread and would be credited with gaps it does not
 # cause (seal.stack_wait is what says the reader is the bound), and
-# so would beside.rebuild_read (rebuild.read is what says it); a
-# request row closes hundreds of times a second on threads that cause
-# no gap of the device.
+# so would beside.rebuild_read (rebuild.read is what says it) and
+# beside.seal_write (seal.write_data is what says the writers are the
+# bound); a request row closes hundreds of times a second on threads
+# that cause no gap of the device.
 ANNOTATED_STAGES = frozenset(STAGES) - {
     "seal.stack", "seal.dispatch", "seal.drain",
     "rebuild.dispatch", "rebuild.drain", "beside.rebuild_read",
-    "req.beside_job", "req.alone"}
+    "beside.seal_write", "req.beside_job", "req.alone"}
 
 kernel_seconds_total = Counter(
     "SeaweedFS_kernel_seconds_total",
@@ -574,7 +590,7 @@ class StageClock:
 
     def __init__(self, codec: str = ""):
         self.codec = codec
-        self._lock = threading.Lock()   # seal.stack closes on the reader
+        self._lock = threading.Lock()   # stages close on several threads
         self._totals: dict[str, list] = {}
 
     def __call__(self, name: str, nbytes: int = 0):
@@ -671,10 +687,11 @@ def debug_doc(node: str, role: str) -> dict:
     name, no dtype, geometry or work), recent invocations, recent
     pipeline gantts with bubble attribution, the conservation verdict,
     device memory stats, the counts of the EC file pipeline's host
-    buffer pool (ec/encoder.py CHUNK_POOL, under its first name) and
-    how the drains of the seals and of the rebuilds found the oldest
-    chunk in flight (SEAL_INFLIGHT, REBUILD_INFLIGHT: `ready` or
-    `waited`)."""
+    buffer pool (ec/encoder.py CHUNK_POOL, under its first name), how
+    the drains of the seals and of the rebuilds found the oldest chunk
+    in flight (SEAL_INFLIGHT, REBUILD_INFLIGHT: `ready` or `waited`)
+    and how the seals' hand-overs found the writer threads
+    (SEAL_WRITER)."""
     from ..ec import encoder
     return {"node": node, "role": role, "armed": ARMED,
             "kernels": LEDGER.kernel_table() + LEDGER.stage_table(),
@@ -685,4 +702,5 @@ def debug_doc(node: str, role: str) -> dict:
             "devices": _device_memory_stats(),
             "seal_buffers": encoder.CHUNK_POOL.counts(),
             "seal_inflight": encoder.SEAL_INFLIGHT.counts(),
+            "seal_writer": encoder.SEAL_WRITER.counts(),
             "rebuild_inflight": encoder.REBUILD_INFLIGHT.counts()}

@@ -518,7 +518,9 @@ def test_failure_while_the_reader_waits_for_a_buffer(tmp_path, pool):
 
     from seaweedfs_tpu.ops.coder_numpy import NumpyCoder
 
-    blob = random.Random(6).randbytes(9 * DATA_SHARDS * CHUNK)
+    from seaweedfs_tpu.ec import encoder
+    buffers = encoder.SEAL_BUFFERS
+    blob = random.Random(6).randbytes((buffers + 4) * DATA_SHARDS * CHUNK)
     base = _write_dat(tmp_path / "v", blob)
 
     class ExplodingCoder(NumpyCoder):
@@ -528,9 +530,10 @@ def test_failure_while_the_reader_waits_for_a_buffer(tmp_path, pool):
             type(self).calls += 1
             if type(self).calls >= 2:
                 deadline = _t.monotonic() + 10
-                while _taken(pool) < 5 and _t.monotonic() < deadline:
+                while (_taken(pool) < buffers
+                       and _t.monotonic() < deadline):
                     _t.sleep(0.001)
-                _t.sleep(0.05)    # ... and it is waiting for a sixth
+                _t.sleep(0.05)    # ... and it is waiting for one more
                 raise RuntimeError("device fell over")
             return super().encode(data)
 
@@ -548,10 +551,12 @@ def test_failure_while_the_reader_waits_for_a_buffer(tmp_path, pool):
     th.join(timeout=15)
     assert not th.is_alive(), "write_ec_files deadlocked on coder failure"
     assert result == ["device fell over"]
-    # Five were live: two in flight (dropped: a coder may still read
-    # them), three read ahead and handed back.
-    assert _taken(pool) == 5
-    assert pool.counts()["held_bytes"] == 3 * pool.nbytes
+    # All of the job's were live: two in flight (dropped: a coder may
+    # still read them), the others read ahead and handed back.
+    assert _taken(pool) == buffers
+    assert pool.counts()["held_bytes"] == (buffers - 2) * pool.nbytes
+    assert not [t for t in threading.enumerate()
+                if t.name.startswith("ec-")]
     _seal(base, NumpyCoder(10, 4))
     _assert_sealed_like_reference(base, blob)
     assert pool.counts()["held_bytes"] <= pool.bound * pool.nbytes
@@ -564,8 +569,10 @@ def test_second_seal_allocates_nothing_and_the_bound_holds(tmp_path, pool):
     import threading
     import time as _t
 
+    from seaweedfs_tpu.ec import encoder
     from seaweedfs_tpu.ops.coder_numpy import NumpyCoder
     from seaweedfs_tpu.stats import roofline
+    buffers, chunks = encoder.SEAL_BUFFERS, encoder.SEAL_BUFFERS + 4
 
     class ReaderFirstCoder(NumpyCoder):
         """The first call of each job waits until the read-ahead thread
@@ -583,22 +590,22 @@ def test_second_seal_allocates_nothing_and_the_bound_holds(tmp_path, pool):
                 _t.sleep(0.001)
             return super().encode(data)
 
-    blob = random.Random(7).randbytes(9 * DATA_SHARDS * CHUNK)
+    blob = random.Random(7).randbytes(chunks * DATA_SHARDS * CHUNK)
     base = _write_dat(tmp_path / "v", blob)
-    _seal(base, ReaderFirstCoder(5))
+    _seal(base, ReaderFirstCoder(buffers))
     first = pool.counts()
-    assert first == {"reused": 4, "allocated": 5,
-                     "held_bytes": 5 * pool.nbytes}
-    _seal(base, ReaderFirstCoder(9 + 5))
+    assert first == {"reused": chunks - buffers, "allocated": buffers,
+                     "held_bytes": buffers * pool.nbytes}
+    _seal(base, ReaderFirstCoder(chunks + buffers))
     second = pool.counts()
     assert second["allocated"] == first["allocated"]
-    assert second["reused"] == first["reused"] + 9
+    assert second["reused"] == first["reused"] + chunks
     assert roofline.debug_doc("n:1", "volume")["seal_buffers"] == second
     _assert_sealed_like_reference(base, blob)
 
-    # Two jobs at once, ten buffers live between them.
+    # Two jobs at once, twice a job's buffers live between them.
     bases = [_write_dat(tmp_path / f"w{i}", blob) for i in range(2)]
-    coder = ReaderFirstCoder(18 + 10)
+    coder = ReaderFirstCoder(2 * chunks + 2 * buffers)
     jobs = [threading.Thread(target=_seal, args=(b, coder), daemon=True)
             for b in bases]
     for th in jobs:
@@ -607,7 +614,7 @@ def test_second_seal_allocates_nothing_and_the_bound_holds(tmp_path, pool):
         th.join(timeout=30)
         assert not th.is_alive()
     third = pool.counts()
-    assert third["allocated"] >= second["allocated"] + 5
+    assert third["allocated"] >= second["allocated"] + buffers
     assert third["held_bytes"] == pool.bound * pool.nbytes
     for b in bases:
         _assert_sealed_like_reference(b, blob)
@@ -682,11 +689,13 @@ class _HandleCoder:
 
 def test_copy_back_is_requested_at_dispatch_and_collected_at_drain(
         tmp_path, pool, inflight, monkeypatch):
-    """One event log of the main thread, whole: per chunk the coder is
-    called, the copy back is requested once — before that chunk's
-    first data-shard write — and the handle is asked whether it is
-    ready and materialised only in the drain, one iteration later,
-    after the NEXT chunk's data shards were written."""
+    """One event log, the main thread's part of it whole: per chunk the
+    coder is called and the copy back is requested once; the handle is
+    asked whether it is ready and materialised only in the drain,
+    `SEAL_DEPTH - 1` dispatches later, and the tail is drained oldest
+    first.  The writes are the writer threads': a chunk's data rows
+    are written after its copy back was requested (they are handed over
+    after it), its parity rows after its handle was materialised."""
     from seaweedfs_tpu.ec import encoder
     log: list = []
     real_write = encoder._shard_write
@@ -696,23 +705,27 @@ def test_copy_back_is_requested_at_dispatch_and_collected_at_drain(
         real_write(f, sid, buf, accs)
 
     monkeypatch.setattr(encoder, "_shard_write", logged_write)
-    chunks = 4
+    chunks, later = 6, encoder.SEAL_DEPTH - 1
+    assert 0 < later < chunks
     blob = random.Random(9).randbytes(chunks * DATA_SHARDS * CHUNK)
     base = _write_dat(tmp_path / "v", blob)
     _seal(base, _HandleCoder(log))
 
-    def drained(k):
-        return [("is_ready", k), ("array", k)] + \
-            [("write", sid) for sid in range(DATA_SHARDS, TOTAL_SHARDS)]
-
     want: list = []
     for k in range(chunks):
         want += [("call", k), ("copy_back", k)]
-        want += [("write", sid) for sid in range(DATA_SHARDS)]
-        if k:
-            want += drained(k - 1)
-    want += drained(chunks - 1)
-    assert log == want
+        if k >= later:
+            want += [("is_ready", k - later), ("array", k - later)]
+    for k in range(chunks - later, chunks):
+        want += [("is_ready", k), ("array", k)]
+    assert [e for e in log if e[0] != "write"] == want
+    for sid in range(TOTAL_SHARDS):
+        # a shard's k-th write is chunk k's row
+        written = [i for i, e in enumerate(log) if e == ("write", sid)]
+        assert len(written) == chunks
+        before = "copy_back" if sid < DATA_SHARDS else "array"
+        for k, i in enumerate(written):
+            assert log.index((before, k)) < i, (sid, k)
     assert inflight.counts() == {"ready": chunks, "waited": 0}
     _assert_sealed_like_reference(base, blob)
 
@@ -742,30 +755,448 @@ def test_seal_inflight_counts_what_the_drain_found(
 def test_a_handle_that_fails_at_the_drain_fails_the_job(
         tmp_path, pool, inflight):
     """An unfenced device coder's error surfaces where the handle is
-    collected: the job raises it, the reader thread is joined, and the
-    buffers of the chunks in flight (the failed one and the one
+    collected: the job raises it, every thread is joined, and the
+    buffers of the chunks in flight (the failed one and those
     dispatched after it, which the coder may still read) are dropped,
     not handed back to the pool."""
     import threading
+
+    from seaweedfs_tpu.ec import encoder
+    depth = encoder.SEAL_DEPTH
     log: list = []
     coder = _HandleCoder(log, fail_at=1)
-    blob = random.Random(11).randbytes(9 * DATA_SHARDS * CHUNK)
+    blob = random.Random(11).randbytes(
+        (encoder.SEAL_BUFFERS + 4) * DATA_SHARDS * CHUNK)
     base = _write_dat(tmp_path / "v", blob)
     with pytest.raises(RuntimeError, match="device fell over"):
         _seal(base, coder)
     assert not [th for th in threading.enumerate()
-                if th.name == "ec-read-ahead"]
-    # chunk 1 failed in the drain that follows chunk 2's dispatch
+                if th.name.startswith("ec-")]
+    # chunk 1 failed in the drain that follows chunk `depth`'s dispatch
     assert [e for e in log if e[0] == "call"] == \
-        [("call", 0), ("call", 1), ("call", 2)]
+        [("call", k) for k in range(depth + 1)]
     assert log[-1] == ("array", 1)
     for data in coder.chunks[1:]:
         assert not any(np.shares_memory(data, buf) for buf in pool._free)
     c = pool.counts()
-    assert c["held_bytes"] <= (c["allocated"] - 2) * pool.nbytes
+    assert c["held_bytes"] <= (c["allocated"] - depth) * pool.nbytes
     assert inflight.counts() == {"ready": 2, "waited": 0}
     _seal(base, _HandleCoder([]))
     _assert_sealed_like_reference(base, blob)
+
+
+# -- the seal's shard writes run beside the main thread -----------------------
+
+@pytest.fixture
+def writer_count(monkeypatch):
+    """A hand-over count of this test's own (as `inflight`)."""
+    from seaweedfs_tpu.ec import encoder
+    c = encoder._InflightCount()
+    monkeypatch.setattr(encoder, "SEAL_WRITER", c)
+    return c
+
+
+class _KeepingCoder(_HandleCoder):
+    """`_HandleCoder` that keeps the parity array each handle gave the
+    drain, so that a test can tell whose rows a writer was handed."""
+
+    def __init__(self, log: list, **kw):
+        super().__init__(log, **kw)
+        self.parities: dict[int, np.ndarray] = {}
+
+    def encode(self, data):
+        coder, k, inner = self, len(self.chunks), super().encode(data)
+
+        class Handle:
+            copy_to_host_async = inner.copy_to_host_async
+            is_ready = inner.is_ready
+
+            def __array__(_self, dtype=None, copy=None):
+                coder.parities[k] = inner.__array__()
+                return coder.parities[k]
+        return Handle()
+
+
+def _chunk_of(coder, sid: int, buf) -> int | None:
+    """The chunk whose row of shard `sid` the object `buf` IS — the
+    same bytes at the same address, in the pooled chunk for a data
+    shard (the newest chunk read there: buffers are reused), in the
+    collected parity for a parity shard; None for a copy."""
+    def at(a) -> int:
+        return np.asarray(a).__array_interface__["data"][0]
+
+    if sid < DATA_SHARDS:
+        whose, row = reversed(list(enumerate(coder.chunks))), sid
+    else:
+        whose, row = coder.parities.items(), sid - DATA_SHARDS
+    return next((k for k, a in whose
+                 if at(buf) == at(a[row]) and len(buf) == a.shape[1]),
+                None)
+
+
+def _record_writes(monkeypatch, log: list, coder, gate=None):
+    """`_shard_write` logs `("write", sid, chunk, thread)` before it
+    writes, `chunk` by `_chunk_of`; with a `gate` (an Event) no row is
+    written before it is set."""
+    import threading
+
+    from seaweedfs_tpu.ec import encoder
+    real_write = encoder._shard_write
+
+    def logged_write(f, sid, buf, accs):
+        if gate is not None:
+            assert gate.wait(10)
+        log.append(("write", sid, _chunk_of(coder, sid, buf),
+                    threading.current_thread().name))
+        real_write(f, sid, buf, accs)
+
+    monkeypatch.setattr(encoder, "_shard_write", logged_write)
+
+
+def _record_gives(monkeypatch, log: list, pool, coder) -> None:
+    """`pool.give` logs `("give", chunk)`: the first chunk read into
+    that buffer which has not been handed back yet."""
+    real_give, given = pool.give, set()
+
+    def logged_give(buf):
+        k = next(k for k, data in enumerate(coder.chunks)
+                 if k not in given and np.shares_memory(buf, data))
+        given.add(k)
+        log.append(("give", k))
+        real_give(buf)
+
+    monkeypatch.setattr(pool, "give", logged_give)
+
+
+def test_rows_reach_the_writers_as_views_one_thread_a_shard(
+        tmp_path, pool, monkeypatch):
+    """No `tobytes` on the seal's write path: every object
+    `_shard_write` receives shares memory with the pooled chunk (a data
+    row) or with the collected parity array (a parity row).  And a
+    shard file is one writer thread's, in chunk order: `SEAL_WRITERS`
+    threads, none of them the job's, and none left afterwards."""
+    import threading
+
+    from seaweedfs_tpu.ec import encoder
+    log: list = []
+    coder = _KeepingCoder([])
+    _record_writes(monkeypatch, log, coder)
+    chunks = encoder.SEAL_BUFFERS + 3          # buffers are reused
+    blob = random.Random(13).randbytes(chunks * DATA_SHARDS * CHUNK - 777)
+    base = _write_dat(tmp_path / "v", blob)
+    _seal(base, coder)
+    monkeypatch.undo()
+    threads = set()
+    for sid in range(TOTAL_SHARDS):
+        mine = [e for e in log if e[1] == sid]
+        assert [e[2] for e in mine] == list(range(chunks)), sid
+        assert len({e[3] for e in mine}) == 1, sid
+        threads.add(mine[0][3])
+    assert len(threads) == encoder.SEAL_WRITERS
+    assert all(name.startswith("ec-write-") for name in threads)
+    assert not [t for t in threading.enumerate()
+                if t.name.startswith("ec-")]
+    _assert_sealed_like_reference(base, blob)
+
+
+@pytest.mark.parametrize("last", ["the_drain", "the_writes"])
+def test_buffer_goes_back_when_rows_are_written_and_parity_drained(
+        tmp_path, pool, monkeypatch, last):
+    """A chunk's buffer is the coder's until its parity is drained AND
+    the writers' until its data rows are in their files: whichever
+    comes last hands it back, and nothing before."""
+    import threading
+    import time as _t
+
+    from seaweedfs_tpu.ec import encoder
+    depth = encoder.SEAL_DEPTH
+    log: list = []
+    gate = threading.Event()
+    if last == "the_drain":
+        gate.set()
+
+    def data_rows_of_chunk_0() -> int:
+        return len([e for e in log
+                    if e[0] == "write" and e[1] < DATA_SHARDS
+                    and e[2] == 0])
+
+    class Coder(_KeepingCoder):
+        def encode(self, data):
+            k = len(self.chunks)
+            if last == "the_drain" and k == depth - 1:
+                # chunk 0 is drained after this dispatch: its rows
+                # are in their files before that
+                deadline = _t.monotonic() + 10
+                while (data_rows_of_chunk_0() < DATA_SHARDS
+                       and _t.monotonic() < deadline):
+                    _t.sleep(0.001)
+                _t.sleep(0.02)      # ... and the last row's `done` ran
+                log.append(("rows_written", 0))
+            if last == "the_writes" and k == depth:
+                # chunk 0 was drained before this dispatch, and no row
+                # of any chunk is written yet
+                assert ("array", 0) in log
+                log.append(("drained", 0))
+                gate.set()
+            return super().encode(data)
+
+    coder = Coder(log)
+    _record_writes(monkeypatch, log, coder, gate)
+    _record_gives(monkeypatch, log, pool, coder)
+    blob = random.Random(14).randbytes((depth + 2) * DATA_SHARDS * CHUNK)
+    base = _write_dat(tmp_path / "v", blob)
+    _seal(base, coder)
+    monkeypatch.undo()
+    given = log.index(("give", 0))
+    rows = [i for i, e in enumerate(log)
+            if e[0] == "write" and e[1] < DATA_SHARDS and e[2] == 0]
+    assert len(rows) == DATA_SHARDS
+    assert given > max(rows) and given > log.index(("array", 0))
+    if last == "the_drain":
+        assert max(rows) < log.index(("rows_written", 0)) \
+            < log.index(("array", 0)) < given
+    else:
+        assert log.index(("array", 0)) < log.index(("drained", 0)) \
+            < min(rows)
+    # every chunk's buffer came back, once
+    assert sorted(e[1] for e in log if e[0] == "give") == \
+        list(range(depth + 2))
+    _assert_sealed_like_reference(base, blob)
+
+
+@pytest.mark.parametrize("rows", ["data", "parity"])
+def test_a_full_disk_on_a_writer_ends_the_seal_promptly(
+        tmp_path, pool, monkeypatch, rows):
+    """`OSError(28)` out of a shard write on a writer thread — of a
+    data row, of a parity row — is raised out of `write_ec_files`
+    within seconds; no pipeline thread is left; every buffer no coder
+    may still read is back in the pool (the chunks that were dispatched
+    and not drained keep theirs) and the pool stays at its bound; the
+    next seal writes the reference's bytes."""
+    import threading
+    import time as _t
+
+    from seaweedfs_tpu.ec import encoder
+    real_write = encoder._shard_write
+    sid_at_fault = 7 if rows == "data" else 12
+    seen: list = []
+
+    def full_disk(f, sid, buf, accs):
+        if sid == sid_at_fault:
+            seen.append(sid)
+            if len(seen) == 3:               # its third chunk
+                raise OSError(28, "No space left on device")
+        real_write(f, sid, buf, accs)
+
+    monkeypatch.setattr(encoder, "_shard_write", full_disk)
+    log: list = []
+    coder = _HandleCoder(log)
+    blob = random.Random(15).randbytes(24 * DATA_SHARDS * CHUNK)
+    base = _write_dat(tmp_path / "v", blob)
+    result: list = []
+
+    def run():
+        try:
+            _seal(base, coder)
+            result.append("no-error")
+        except OSError as e:
+            result.append(e)
+
+    th = threading.Thread(target=run, daemon=True)
+    t0 = _t.monotonic()
+    th.start()
+    th.join(timeout=15)
+    assert not th.is_alive(), "write_ec_files hung on the full disk"
+    assert _t.monotonic() - t0 < 5
+    assert isinstance(result[0], OSError) and result[0].errno == 28
+    assert not [t for t in threading.enumerate()
+                if t.name.startswith("ec-")]
+    assert len(coder.chunks) < 24            # it did not run to the end
+    undrained = [k for k in range(len(coder.chunks))
+                 if ("array", k) not in log]
+    for k in undrained:
+        assert not any(np.shares_memory(coder.chunks[k], buf)
+                       for buf in pool._free)
+    c = pool.counts()
+    assert c["allocated"] <= encoder.SEAL_BUFFERS <= pool.bound
+    assert c["held_bytes"] == \
+        (c["allocated"] - len(undrained)) * pool.nbytes
+    monkeypatch.undo()
+    _seal(base, _HandleCoder([]))
+    _assert_sealed_like_reference(base, blob)
+    assert pool.counts()["held_bytes"] <= pool.bound * pool.nbytes
+
+
+def test_the_reads_of_a_chunk_go_out_side_by_side(
+        tmp_path, pool, monkeypatch):
+    """The seal's reader spreads a chunk's `preadv` calls (four a chunk
+    of four small-block rows, as on the chip) over its pool: all four
+    are inside `preadv` at once (a barrier only all of them together
+    pass), none on the job's thread or the read-ahead thread, and no
+    thread is left when the job ends."""
+    import threading
+
+    from seaweedfs_tpu.ec import encoder
+    from seaweedfs_tpu.ops.coder_numpy import NumpyCoder
+    reads = CHUNK // SMALL
+    assert 1 < reads <= encoder.SEAL_READERS
+    barrier = threading.Barrier(reads, timeout=10)
+    real_preadv, names = os.preadv, set()
+
+    def together(fd, views, offset):
+        names.add(threading.current_thread().name)
+        barrier.wait()
+        return real_preadv(fd, views, offset)
+
+    monkeypatch.setattr(encoder.os, "preadv", together)
+    blob = random.Random(16).randbytes(3 * DATA_SHARDS * CHUNK)
+    base = _write_dat(tmp_path / "v", blob)
+    _seal(base, NumpyCoder(10, 4))
+    monkeypatch.undo()
+    assert len(names) >= reads
+    assert all(n.startswith("ec-seal-read") for n in names)
+    assert not [t for t in threading.enumerate()
+                if t.name.startswith("ec-")]
+    _assert_sealed_like_reference(base, blob)
+
+
+def test_seal_writer_and_the_writers_row_count_what_happened(
+        tmp_path, pool, writer_count, monkeypatch):
+    """`seal_writer` of `/debug/device` notes every chunk's hand-over:
+    `ready` while the writers are inside the window, `waited` once they
+    are `SEAL_DEPTH` chunks behind (here: held at a gate until the main
+    thread waits for them).  `beside.seal_write` has a count a row and
+    the rows' bytes; the main thread's `seal.write_data` and
+    `seal.write_parity` keep a count a chunk (and one: the last wait)
+    and the bytes handed over."""
+    import threading
+    import time as _t
+
+    from seaweedfs_tpu.ec import encoder
+    from seaweedfs_tpu.stats import roofline
+    from seaweedfs_tpu.stats.roofline import StageClock
+    depth, chunks = encoder.SEAL_DEPTH, encoder.SEAL_DEPTH + 3
+    gate = threading.Event()
+    coder = _KeepingCoder([])
+    _record_writes(monkeypatch, [], coder, gate)
+
+    def open_when_the_main_thread_waits():
+        deadline = _t.monotonic() + 10
+        while (not writer_count.counts()["waited"]
+               and _t.monotonic() < deadline):
+            _t.sleep(0.001)
+        gate.set()
+
+    opener = threading.Thread(target=open_when_the_main_thread_waits,
+                              daemon=True)
+    opener.start()
+    blob = random.Random(17).randbytes(chunks * DATA_SHARDS * CHUNK)
+    base = _write_dat(tmp_path / "v", blob)
+    clock = StageClock("rs")
+    write_ec_files(base, coder=coder, large_block_size=LARGE,
+                   small_block_size=SMALL, chunk_size=CHUNK, clock=clock)
+    opener.join(timeout=10)
+    assert not opener.is_alive()
+    got = writer_count.counts()
+    assert got["ready"] + got["waited"] == chunks
+    assert got["ready"] >= depth and got["waited"] >= 1
+    doc = roofline.debug_doc("n:1", "volume")
+    assert doc["seal_writer"] == got
+    rows = clock.totals()
+    assert rows["beside.seal_write"]["count"] == TOTAL_SHARDS * chunks
+    assert rows["beside.seal_write"]["bytes"] == TOTAL_SHARDS * chunks * CHUNK
+    assert rows["seal.write_data"]["count"] == chunks
+    assert rows["seal.write_data"]["bytes"] == DATA_SHARDS * chunks * CHUNK
+    assert rows["seal.write_parity"]["count"] == chunks + 1
+    assert rows["seal.write_parity"]["bytes"] == 4 * chunks * CHUNK
+    served = {r["kernel"]: r for r in doc["kernels"]}
+    assert served["beside.seal_write"]["count"] >= TOTAL_SHARDS * chunks
+    _assert_sealed_like_reference(base, blob)
+
+
+class _UnfencedCoder(_HandleCoder):
+    """`_HandleCoder` with the device coder's unfenced entry point."""
+
+    def encode_unfenced(self, data, crc: bool = False):
+        assert not crc
+        return (self.encode(data),)
+
+
+@pytest.mark.parametrize("kind", ["host", "unfenced", "pallas_fused",
+                                  "pallas_accs"])
+def test_every_coder_runs_the_same_loop_byte_identically(
+        tmp_path, monkeypatch, kind):
+    """A host coder (no `encode_unfenced`: it computes inside
+    dispatch), a coder that is called unfenced, the device coder with
+    the fused CRC and the same with the byte accumulators (each
+    shard's fed by its one writer thread, in file order): one loop,
+    and every shard and `.ecc` entry is the reference's."""
+    from seaweedfs_tpu.ec import SMALL_BLOCK_SIZE
+    from seaweedfs_tpu.ec.integrity import (BlockCrcAccumulator,
+                                            ShardChecksums)
+    from seaweedfs_tpu.ops.coder_numpy import NumpyCoder
+    if kind in ("host", "unfenced"):
+        from seaweedfs_tpu.ec import encoder
+        monkeypatch.setattr(
+            encoder, "CHUNK_POOL",
+            encoder._ChunkPool(encoder.CHUNK_POOL_BUFFERS,
+                               DATA_SHARDS * CHUNK))
+        blob = random.Random(18).randbytes(7 * DATA_SHARDS * CHUNK + 99)
+        base = _write_dat(tmp_path / "v", blob)
+        _seal(base, NumpyCoder(10, 4) if kind == "host"
+              else _UnfencedCoder([]))
+        _assert_sealed_like_reference(base, blob)
+        return
+    from seaweedfs_tpu.ops.coder_pallas import PallasCoder
+    monkeypatch.setenv("SEAWEEDFS_TPU_EC_FUSED_CRC",
+                       "1" if kind == "pallas_fused" else "0")
+    block = SMALL_BLOCK_SIZE
+    blob = random.Random(19).randbytes(4 * DATA_SHARDS * block - 4321)
+    base, want = (_write_dat(tmp_path / n, blob) for n in ("v", "w"))
+    write_ec_files(base, coder=PallasCoder(), chunk_size=block)
+    write_ec_files(want, coder=NumpyCoder(), chunk_size=block)
+    ecc, ecc_want = ShardChecksums.load(base), ShardChecksums.load(want)
+    for sid in range(TOTAL_SHARDS):
+        with open(base + to_ext(sid), "rb") as a, \
+                open(want + to_ext(sid), "rb") as b:
+            raw = a.read()
+            assert raw == b.read(), sid
+        acc = BlockCrcAccumulator()
+        acc.feed(raw)
+        assert ecc.get(sid) == ecc_want.get(sid) == acc.finalize(), sid
+
+
+def test_seals_at_once_under_a_short_switch_interval(tmp_path, pool):
+    """More jobs than a job has writers, the interpreter switching
+    threads every few microseconds: every countdown is met exactly
+    once — each job's files are the reference's, every buffer is back
+    (the pool is at its bound) and no thread is left."""
+    import sys
+    import threading
+
+    from seaweedfs_tpu.ec import encoder
+    from seaweedfs_tpu.ops.coder_numpy import NumpyCoder
+    blob = random.Random(20).randbytes(12 * DATA_SHARDS * CHUNK + 5)
+    bases = [_write_dat(tmp_path / f"v{i}", blob)
+             for i in range(encoder.SEAL_WRITERS + 2)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        jobs = [threading.Thread(target=_seal, daemon=True,
+                                 args=(b, NumpyCoder(10, 4)))
+                for b in bases]
+        for th in jobs:
+            th.start()
+        for th in jobs:
+            th.join(timeout=60)
+            assert not th.is_alive()
+    finally:
+        sys.setswitchinterval(interval)
+    assert not [t for t in threading.enumerate()
+                if t.name.startswith("ec-")]
+    assert pool.counts()["held_bytes"] == pool.bound * pool.nbytes
+    for b in bases:
+        _assert_sealed_like_reference(b, blob)
 
 
 # -- the rebuild runs on the same pipeline, a deeper window in flight ----------
@@ -1048,12 +1479,13 @@ def test_a_failing_rebuild_ends_promptly_and_hands_its_buffers_back(
         before_call=lambda k: k == depth - 1 and
         _all_buffers_read_ahead(pool, buffers))
     if fault == "short_read":
-        real_preadv, calls = os.preadv, []
+        real_preadv = os.preadv
 
         def short(fd, views, offset):
-            calls.append(offset)
+            # the third chunk of every survivor, whichever of the five
+            # reader threads makes the call and in whatever order
             n = real_preadv(fd, views, offset)
-            return n - 1 if len(calls) == 2 * DATA_SHARDS + 4 else n
+            return n - 1 if offset == 2 * CHUNK else n
         monkeypatch.setattr(encoder.os, "preadv", short)
         error, dropped = ValueError, 1        # the one being filled
     elif fault == "drain":

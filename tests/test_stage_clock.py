@@ -34,9 +34,10 @@ pytestmark = pytest.mark.roofline
 BLOCK = SMALL_BLOCK_SIZE
 COUNTED_ONLY = {"seal.stack", "seal.dispatch", "seal.drain",
                 "rebuild.dispatch", "rebuild.drain", "beside.rebuild_read",
-                "req.beside_job", "req.alone"}
-# the read-ahead threads' rows: beside the main thread, in no sum
-BESIDE = {"seal.stack", "beside.rebuild_read"}
+                "beside.seal_write", "req.beside_job", "req.alone"}
+# the read-ahead and writer threads' rows: beside the main thread, in
+# no sum
+BESIDE = {"seal.stack", "beside.rebuild_read", "beside.seal_write"}
 # the drives answer their one upload before any job starts
 DRIVEN = set(STAGES) - {"req.beside_job"}
 
@@ -131,11 +132,14 @@ def test_main_thread_stages_sum_to_the_wall(tmp_path, monkeypatch):
     got = clock.totals()
     main = sum(v["seconds"] for k, v in got.items() if k not in BESIDE)
     assert 0.90 * wall <= main <= wall, (main, wall, got)
-    for stage in ("seal.dispatch", "seal.write_data", "seal.drain",
-                  "seal.write_parity"):
+    for stage in ("seal.dispatch", "seal.write_data", "seal.drain"):
         assert got[stage]["count"] == chunks, stage
-    # one more wait than chunks (the end of the stream), a read a chunk
+    # one more wait than chunks (the end of the stream; the writers'
+    # last rows), a read a chunk, a write a row
     assert got["seal.stack_wait"]["count"] == chunks + 1
+    assert got["seal.write_parity"]["count"] == chunks + 1
+    assert got["beside.seal_write"]["count"] == 14 * chunks
+    assert got["beside.seal_write"]["bytes"] == 14 * n
     assert got["seal.stack"]["count"] == chunks
     assert got["seal.stack"]["bytes"] == 10 * n
     assert got["seal.dispatch"]["bytes"] == 10 * n
@@ -261,6 +265,7 @@ def test_cluster_roofline_prints_stages_in_a_section_of_their_own(
     assert "seal.write_data" in tail and "encode_kernel" not in tail
     assert "seal.stack host buffers: " in tail and " MiB held" in tail
     assert "seal.drain: " in tail and " waited for" in tail
+    assert "seal.write_data: " in tail and " waited for them" in tail
     assert "rebuild.drain: " in tail
 
 
@@ -276,6 +281,13 @@ def test_debug_device_serves_seal_inflight(tmp_path, monkeypatch):
     assert sum(got["seal_inflight"].values()) == drains["count"] >= 1
     assert set(got["seal_buffers"]) == {"reused", "allocated",
                                         "held_bytes"}
+    # ... and how the hand-overs found the writer threads, whose rows
+    # are one row of the same list
+    assert set(got["seal_writer"]) == {"ready", "waited"}
+    assert sum(got["seal_writer"].values()) >= drains["count"]
+    writes = next(r for r in got["kernels"]
+                  if r["kernel"] == "beside.seal_write")
+    assert writes["count"] == 14 * drains["count"]
 
 
 def test_debug_device_serves_rebuild_inflight(tmp_path, monkeypatch):
