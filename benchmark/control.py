@@ -17,6 +17,17 @@ the configuration states, with the reference put in the program's place:
       (256 bytes of its payload zeroed in the volume file), as a store
       that acknowledges before its bytes are safe leaves them: before the
       window for a read mix, after it for a write mix.
+  reads of the pool's needles (`keys_from: "pool"`)   before the window,
+      in the pool's last volume, one surviving shard that the decode of
+      the first lost data shard reads (the last of the first ten
+      survivors: a parity shard, so that no healthy read passes over it)
+      is written anew, never through the hard link, with 256 bytes
+      zeroed every 4 KiB — the smallest needle, so that in each 1 MiB
+      block every lost interval of a needle's length decodes from some —
+      and `.ecc` left as it was; the volume is taken off the server and
+      mounted again through its own admin routes, since the server holds
+      the old file open.  Any 10 shards no longer give back every needle:
+      whichever of the server's checks meets it, a read fails or differs.
 
 Exit code 0 where `correct` came out false, 1 where the comparison let
 the control through.  The benchmark's own runs never run this.
@@ -40,9 +51,11 @@ import numpy as np  # noqa: E402
 from benchmark import ecref, loadgen, run  # noqa: E402
 from benchmark.data import payload_block, request_payload  # noqa: E402
 from benchmark.machine import MIB, check  # noqa: E402
+from benchmark.served import call  # noqa: E402
 
 TORN_ONE_IN = 16
 TORN_BYTES = 256
+TORN_EVERY = 4096
 
 
 def xor_parity_shard(base: str, sid: int) -> None:
@@ -70,6 +83,22 @@ def xor_parity_shard(base: str, sid: int) -> None:
     doc["shards"][str(sid)] = [f"{c:08x}" for c in crcs]
     with open(base + ".ecc", "w") as f:
         json.dump(doc, f)
+
+
+def tear_shard(base: str, sid: int) -> None:
+    """Shard `sid` of a sealed volume as a disk that lost part of every
+    sector run leaves it: a new file (never through the hard link) with
+    TORN_BYTES zeroed every TORN_EVERY bytes; `.ecc` is not touched."""
+    path = base + ecref.ext(sid)
+    with open(path, "rb") as f:
+        raw = bytearray(f.read())
+    check(raw and len(raw) % TORN_EVERY == 0,
+          f"{path}: {len(raw)} bytes are no whole blocks")
+    np.frombuffer(raw, np.uint8).reshape(-1, TORN_EVERY)[
+        :, 16:16 + TORN_BYTES] = 0
+    os.unlink(path)
+    with open(path, "wb") as f:
+        f.write(raw)
 
 
 def tear_writes(data_dir: str, block: bytes, idents, size: int,
@@ -104,7 +133,22 @@ def tear_writes(data_dir: str, block: bytes, idents, size: int,
 class ControlHooks(run.Hooks):
     def before_window(self, ctx: dict) -> None:
         req = ctx.get("requests")
-        if req and req["op"] == "read":
+        if ctx["pool_keys"]:
+            srv, tpl = ctx["srv"], ctx["template"]
+            lost = run.pool_lost_shards(ctx)
+            check(any(s < ecref.DATA_SHARDS for s in lost),
+                  f"no data shard is lost ({lost}): no read decodes")
+            sid = [s for s in range(ecref.TOTAL_SHARDS)
+                   if s not in lost][ecref.DATA_SHARDS - 1]
+            vid = ctx["vids"][-1]
+            call(f"{srv.volume}/admin/ec/unmount", {"volume": vid})
+            tear_shard(os.path.join(srv.data_dir,
+                                    f"{tpl.collection}_{vid}"), sid)
+            call(f"{srv.volume}/admin/ec/mount", {"volume": vid})
+            print(f"control: shard {sid} of volume {vid} written anew "
+                  f"with {TORN_BYTES} bytes in every {TORN_EVERY} zeroed",
+                  flush=True)
+        elif req and req["op"] == "read":
             with np.load(ctx["gen"]["keys"]) as z:
                 idents = z["ids"]
             torn = tear_writes(ctx["srv"].data_dir,

@@ -42,6 +42,7 @@ class Volume:
     base: str                    # <dir>/<collection>_<vid>
     dat_bytes: int
     kept_dat: str                # a link to the bytes, kept past the seal
+    kept_idx: str                # a copy of its index, kept past the seal
     needles: list = field(default_factory=list)   # (key+cookie, i, size)
     shard_dir: str = ""          # where a sealed template's shards are kept
 
@@ -69,8 +70,44 @@ def fill_volume(srv: Server, seed: int, stream: int, collection: str,
     os.makedirs(keep, exist_ok=True)
     kept = os.path.join(keep, f"{collection}.dat")
     os.link(base + ".dat", kept)
-    return Volume(vid, collection, stream, base, dat_bytes, kept,
+    kept_idx = os.path.join(keep, f"{collection}.idx")
+    shutil.copyfile(base + ".idx", kept_idx)
+    return Volume(vid, collection, stream, base, dat_bytes, kept, kept_idx,
                   [(fid.split(",")[1], i, s) for fid, _u, i, s in put])
+
+
+def needle_records(tpl: Volume) -> list[tuple[int, int]]:
+    """(offset, bytes) in the volume of every needle's record, in the
+    order of `tpl.needles`: from the index kept when the volume was
+    filled and the format's own arithmetic (ecref.py), each held
+    against the header the kept bytes have there."""
+    with open(tpl.kept_dat, "rb") as dat:
+        version = dat.read(1)[0]
+        check(version == ecref.RECORD_VERSION,
+              f"{tpl.collection}: a version {version} volume")
+        index = ecref.index_entries(tpl.kept_idx)
+        out = []
+        for key_cookie, _i, size in tpl.needles:
+            key, cookie = int(key_cookie[:-8], 16), int(key_cookie[-8:], 16)
+            check(key in index, f"{tpl.collection}: needle {key:x} is "
+                                f"not in the index")
+            offset, body = index[key]
+            dat.seek(offset)
+            head = dat.read(ecref.RECORD_HEADER.size + 4)
+            check(ecref.RECORD_HEADER.unpack_from(head) == (cookie, key,
+                                                            body)
+                  and int.from_bytes(head[-4:], "big") == size,
+                  f"{tpl.collection}: no record of needle {key:x} with "
+                  f"{size} bytes at {offset}")
+            out.append((offset, ecref.record_bytes(body)))
+    # The records lie end to end and the last one ends the volume: the
+    # arithmetic of a record's length, held against the file.
+    ends = sorted((o + n, o) for o, n in out)
+    check(all(a[0] == b[1] for a, b in zip(ends, ends[1:]))
+          and ends[-1][0] == tpl.dat_bytes,
+          f"{tpl.collection}: the records do not lie end to end up to "
+          f"the volume's {tpl.dat_bytes} bytes")
+    return out
 
 
 def clone_unsealed(srv: Server, tpl: Volume, vids: list[int]) -> None:

@@ -9,11 +9,21 @@ volume's `.dat` cut into rows of ten 1 MiB blocks, block i of every row
 going to shard i, the last row zero-filled (volumes under 10 GB have no
 1 GB large-block rows).  `.ecc` holds one crc32c per 1 MiB block of each
 shard, as JSON hex strings.
+
+Where a needle lies is the format's too (SeaweedFS `weed/storage/types`
+and `needle`): an index (`.idx`, or a sealed volume's sorted `.ecx`) is
+16-byte entries, big-endian: the key (8), the record's offset in units of
+8 bytes (4), the needle's Size (4, signed); a version 3 record is a
+16-byte header, Size bytes, a 4-byte checksum, an 8-byte timestamp and 1
+to 8 bytes of padding to the next multiple of 8.  `lost_bytes` says how
+much of a record lies on given data shards: what any implementation has
+to reconstruct to give the needle back once those shards are gone.
 """
 
 from __future__ import annotations
 
 import json
+import struct
 
 import numpy as np
 
@@ -156,6 +166,41 @@ def crc32c(buf) -> int:
 def shard_size(dat_bytes: int) -> int:
     rows = -(-dat_bytes // (DATA_SHARDS * BLOCK))
     return rows * BLOCK
+
+
+INDEX_ENTRY = struct.Struct(">QIi")       # key, offset / 8, Size
+RECORD_HEADER = struct.Struct(">IQi")      # cookie, key, Size
+RECORD_VERSION = 3
+_RECORD_AROUND = RECORD_HEADER.size + 4 + 8   # header, checksum, timestamp
+
+
+def index_entries(path: str) -> dict[int, tuple[int, int]]:
+    """{key: (the record's offset in the volume, its Size)} of an
+    `.idx` or `.ecx`; a later entry of a key replaces an earlier one."""
+    with open(path, "rb") as f:
+        raw = f.read()
+    return {key: (units * 8, size)
+            for key, units, size in INDEX_ENTRY.iter_unpack(raw)}
+
+
+def record_bytes(size: int) -> int:
+    """The bytes a version 3 record of Size `size` takes in the volume."""
+    return _RECORD_AROUND + size + 8 - (_RECORD_AROUND + size) % 8
+
+
+def lost_bytes(offset: int, length: int, lost: list[int]) -> int:
+    """Of the volume's bytes [offset, offset + length), those that lie
+    in a block of a data shard in `lost`: block b of the volume is
+    block b // 10 of shard b % 10."""
+    gone = {s for s in lost if s < DATA_SHARDS}
+    n, end = 0, offset + length
+    while offset < end:
+        block = offset // BLOCK
+        upto = min(end, (block + 1) * BLOCK)
+        if block % DATA_SHARDS in gone:
+            n += upto - offset
+        offset = upto
+    return n
 
 
 def ext(sid: int) -> str:

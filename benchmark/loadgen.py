@@ -12,6 +12,20 @@ the loop allocates the payload and two clock readings, and writes into
 arrays that were there before.  The parent runs nothing else during the
 window.
 
+A read mix has one of two key sets.  Its own, written in set-up with
+this generator (`keys`: ids, fids, the server), every key `size` bytes
+and checked against `request_payload`.  Or, with `keys_from: "pool"`,
+every needle of every volume of the jobs' pool (`keys`: the pool's
+volume ids and, once for them all, each needle's key+cookie, its number
+in the seed's stream and its size; key `v * needles + j` is needle j of
+volume v): the answer is held against `needle_payload` of the seed, the
+bytes that were put.  A worker makes those bytes once, before it says
+`ready`, and keeps a 128-bit BLAKE2b digest and the length of each: 16
+bytes a needle where the payloads themselves are the whole volume (520
+MiB, in each of four workers), for about a second of each worker's CPU
+in set-up (all at once) and, per answer, one hash of it, outside the
+interpreter lock, where the payloads would cost a compare.
+
 A worker is `python loadgen.py <spec.json>`; it writes `<out>.npz`.
 It says `ready` once it has imported, read its keys and made its
 arrays, and starts its clients when the parent, having heard every
@@ -21,6 +35,7 @@ while the window runs.  All read one clock, CLOCK_MONOTONIC.
 
 from __future__ import annotations
 
+import hashlib
 import json
 import os
 import subprocess
@@ -34,7 +49,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 if __name__ == "__main__":
     sys.path.insert(0, os.path.dirname(HERE))
 
-from benchmark.data import Http, payload_block, request_payload  # noqa: E402
+from benchmark.data import (Http, needle_payload, payload_block,  # noqa: E402
+                            request_payload)
 from benchmark.machine import check  # noqa: E402
 
 FID_BYTES = 32
@@ -54,11 +70,10 @@ def _client(spec: dict, thread: int, block: bytes, keys, arrays: dict,
     count = spec.get("count") or len(done)
     url = ""
     if op == "read":
+        key_fids, key_ids, same = keys["fids"], keys["ids"], keys["same"]
         order = np.random.default_rng(
-            [spec["seed"], 3, client_no]).integers(0, len(keys["ids"]),
+            [spec["seed"], 3, client_no]).integers(0, len(key_ids),
                                                    len(done))
-        key_fids = [f.decode() for f in keys["fids"]]
-        key_ids = [int(i) for i in keys["ids"]]
         url = keys["url"]
     n = 0
     try:
@@ -78,9 +93,8 @@ def _client(spec: dict, thread: int, block: bytes, keys, arrays: dict,
                 else:
                     k = order[n]
                     ident = key_ids[k]
-                    same = http.read(url, key_fids[k]) == \
-                        request_payload(block, ident, size)
-                    state = GOOD if same else DIFFERS
+                    state = GOOD if same(ident, http.read(
+                        url, key_fids[k])) else DIFFERS
             except Exception:  # noqa: BLE001 - counted, the loop goes on
                 state, ident = FAILED, -1
             t1 = time.monotonic()
@@ -90,6 +104,34 @@ def _client(spec: dict, thread: int, block: bytes, keys, arrays: dict,
         arrays["n"][thread] = n
         arrays["url"][thread] = url if op == "write" else ""
         http.close()
+
+
+def digest(payload: bytes) -> bytes:
+    return hashlib.blake2b(payload, digest_size=16).digest()
+
+
+def read_keys(spec: dict, block: bytes) -> dict:
+    """A read mix's key set as a client uses it: `fids` and `ids` by
+    key, the server's `url`, and `same(ident, answer)`."""
+    with np.load(spec["keys"]) as z:
+        keys = {k: z[k] for k in z.files}
+    if spec.get("keys_from") != "pool":
+        size = spec["size"]
+        return {"fids": [f.decode() for f in keys["fids"]],
+                "ids": [int(i) for i in keys["ids"]],
+                "url": str(keys["url"]),
+                "same": lambda ident, got: got == request_payload(
+                    block, ident, size)}
+    fids = [f.decode() for f in keys["fids"]]
+    want = [(int(n), digest(needle_payload(spec["seed"], spec["stream"],
+                                           int(i), int(n))))
+            for i, n in zip(keys["idx"], keys["sizes"])]
+    return {"fids": [f"{int(vid)},{f}" for vid in keys["vids"]
+                     for f in fids],
+            "ids": list(range(len(keys["vids"]) * len(fids))),
+            "url": str(keys["url"]),
+            "same": lambda ident, got: (len(got), digest(got))
+            == want[ident % len(want)]}
 
 
 def worker(spec: dict) -> None:
@@ -103,11 +145,7 @@ def worker(spec: dict) -> None:
               "fids": np.zeros((threads, cap), f"S{FID_BYTES}"),
               "n": np.zeros(threads, np.int64), "url": [""] * threads}
     block = payload_block(spec["seed"])
-    keys = None
-    if spec["op"] == "read":
-        with np.load(spec["keys"]) as z:
-            keys = {"ids": z["ids"], "fids": z["fids"],
-                    "url": str(z["url"])}
+    keys = read_keys(spec, block) if spec["op"] == "read" else None
     print("ready", flush=True)
     t_open = float(sys.stdin.readline())
     t_close = float("inf") if spec.get("count") \

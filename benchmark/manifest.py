@@ -2,7 +2,16 @@
 data: a configuration is `configs/<config>.json` (the manifest's `file`),
 a traffic mix `traffic/<traffic>.json`, a per-layer metric the reader
 `metrics/<name>.py` with one function `read(facts)`.  A later PR adds
-entries and files; nothing here names a cell, a mix or a metric.
+entries and files; nothing here names a cell, a mix or a metric.  A
+configuration's `server_env` may set glibc's `MALLOC_*` variables for
+its server and nothing else (served.py `server_environment`).
+
+A traffic file holds `why`, `sent_by` and `jobs` and/or `requests`
+(run.py's docstring has every key): jobs of one kind over a pool of
+`jobs.volumes` volumes, `jobs.repeat` of them in the window (0 with
+requests: the pool is only made and damaged); requests that write, read
+a key set of their own, or with `keys_from: "pool"` read every needle of
+the pool's volumes as the jobs' set-up left them.
 """
 
 from __future__ import annotations

@@ -17,7 +17,33 @@ Pallas one on a TPU: another exit code than 0 and no result line.
 What a cell does comes from data: its configuration, its traffic mix
 (`jobs`: admin jobs on erasure-coded volumes, one after another;
 `requests`: closed-loop clients; a mix may have both) and the readers of
-its per-layer metrics.  See manifest.py.
+its per-layer metrics.  See manifest.py.  A configuration may say
+`server_env`: glibc's `MALLOC_*` variables for the server's process and
+nothing else (served.py).  A traffic file may hold:
+
+  jobs.op           `ec.encode` | `ec.rebuild`: the shell command, and the
+                    state of the pool when the window opens (full quiet
+                    volumes; sealed volumes without the configuration's
+                    `lost_shards`)
+  jobs.repeat       how many jobs the window runs, one after another,
+                    over the pool's first volumes; or `jobs.per_second`,
+                    as many for each second the run is given.  `repeat`
+                    0 (with `requests`): the pool is made, damaged and
+                    warmed, and no job runs in the window
+  jobs.volumes      the volumes of the pool (default: one for each job)
+  jobs.metric       the end-to-end metric the jobs' rate goes under
+  jobs.volume_bytes, ec   sizes of the pool's volumes where they are not
+                    the configuration's
+  requests.op       `write` | `read`; requests.warm_seconds: the same
+                    traffic for so long before the window opens
+  requests.keys     a read mix's own key set, written in set-up into
+                    unsealed volumes of collection `bench`; or
+  requests.keys_from "pool"   reads only: the key set is every needle of
+                    every volume of the jobs' pool, read from the volume
+                    server and held against the seed's bytes
+                    (loadgen.py); nothing is written or grown for it,
+                    and of the configuration it asks `clients` and
+                    `procs` beside what the jobs ask
 """
 
 from __future__ import annotations
@@ -103,17 +129,49 @@ def setup_jobs(ctx: dict) -> None:
     ctx.update(template=tpl, vids=vids)
 
 
+def pool_lost_shards(ctx: dict) -> list[int]:
+    """The shards every volume of the pool is without when the window
+    opens."""
+    return ctx["ec"]["lost_shards"] if ctx["jobs"]["op"] == "ec.rebuild" \
+        else []
+
+
+def pool_keys(ctx: dict) -> str:
+    """The key set of a `keys_from: "pool"` mix, as a file: every needle
+    of every volume of the pool.  What the harness knows of each key
+    stays here as `ctx["key_facts"]`: its payload's bytes, and the bytes
+    of its record on a lost data shard, by key number."""
+    srv, tpl, vids = ctx["srv"], ctx["template"], ctx["vids"]
+    lost = pool_lost_shards(ctx)
+    sizes = np.array([size for _f, _i, size in tpl.needles])
+    gone = np.array([ecref.lost_bytes(offset, n, lost)
+                     for offset, n in ecjobs.needle_records(tpl)])
+    ctx["key_facts"] = {"bytes": np.tile(sizes, len(vids)),
+                        "lost_bytes": np.tile(gone, len(vids))}
+    path = os.path.join(srv.work, "keys.npz")
+    np.savez(path, vids=np.array(vids), sizes=sizes,
+             fids=np.array([f.encode() for f, _i, _s in tpl.needles]),
+             idx=np.array([i for _f, i, _s in tpl.needles]),
+             url=np.array(f"127.0.0.1:{srv.vport}"))
+    return path
+
+
 def setup_requests(ctx: dict) -> None:
-    """Volumes grown before the window, and for reads the key set,
-    written with the generator itself."""
+    """Volumes grown before the window, and for reads the key set:
+    written with the generator itself, or the pool's needles."""
     srv, req, cfg = ctx["srv"], ctx["requests"], ctx["store"]
-    call(f"{srv.master}/vol/grow?count={cfg['volumes']}"
-         f"&collection=bench&replication={cfg['replication']}", {})
     ctx["gen"] = {"master": srv.master, "op": req["op"],
-                  "size": cfg["size"], "collection": "bench",
                   "procs": cfg["procs"],
                   "threads": cfg["clients"] // cfg["procs"],
                   "seed": ctx["seed"], "cap_per_s": 4000}
+    if ctx["pool_keys"]:
+        ctx["gen"].update(size=0, collection=ctx["template"].collection,
+                          keys_from="pool", keys=pool_keys(ctx),
+                          stream=ctx["template"].stream)
+        return
+    call(f"{srv.master}/vol/grow?count={cfg['volumes']}"
+         f"&collection=bench&replication={cfg['replication']}", {})
+    ctx["gen"].update(size=cfg["size"], collection="bench")
     if req["op"] == "read":
         keys = ctx["keys"]
         per = -(-keys // cfg["clients"])
@@ -135,8 +193,15 @@ def setup_requests(ctx: dict) -> None:
 def window(ctx: dict) -> None:
     srv, seconds = ctx["srv"], ctx["seconds"]
     jobs, req = ctx.get("jobs"), ctx.get("requests")
-    marks = {"cpu": srv.cpu_seconds(), "log": srv.log_mark(),
-             "rows": srv.coder_rows()}
+    vids = ctx["vids"][:ctx["window_jobs"]] if jobs else []
+    marks: dict = {}
+
+    def mark() -> None:
+        # Every mark is taken as the window opens, the warm traffic
+        # behind: the server's clock, its log and its rows (one GET of
+        # `/debug/device`, which compiles and transfers nothing).
+        marks.update(cpu=srv.cpu_seconds(), log=srv.log_mark(),
+                     rows=srv.coder_rows())
     if ctx["trace"]:
         ctx["trace_dir"] = os.path.join(srv.work, "trace")
         srv.control(f"trace-start {ctx['trace_dir']}")
@@ -146,13 +211,10 @@ def window(ctx: dict) -> None:
         done = {}
 
         def at_open() -> None:
-            # The window is open (the warm traffic is behind): read the
-            # server's clock and log anew, and touch the device once,
-            # with one small seal among the requests.
-            marks.update(cpu=srv.cpu_seconds(), log=srv.log_mark())
-            if jobs:
+            mark()
+            if vids:        # the jobs run among the requests
                 done.update(ecjobs.jobs_window(
-                    srv, jobs["op"], ctx["vids"], ctx["volume_bytes"]))
+                    srv, jobs["op"], vids, ctx["volume_bytes"]))
         res = loadgen.run(spec, srv.work, "window", at_open)
         t_open = res["t_open"]
         ctx["setup_s"] = t_open - T_START
@@ -161,11 +223,13 @@ def window(ctx: dict) -> None:
         ctx["req"] = loadgen.in_window(res, t_open, t_open + seconds,
                                        os.cpu_count() or 1)
         ctx["req"]["op"] = req["op"]
-        if jobs:
-            ctx["job"] = done
+        if ctx["pool_keys"]:
+            ctx["req"].update(pool_facts(ctx))
+        ctx["job"] = done
     else:
+        mark()
         ctx["setup_s"] = time.monotonic() - T_START
-        ctx["job"] = ecjobs.jobs_window(srv, jobs["op"], ctx["vids"],
+        ctx["job"] = ecjobs.jobs_window(srv, jobs["op"], vids,
                                         ctx["volume_bytes"])
         ctx["window_s"] = ctx["job"]["window_s"]
     if ctx["trace"]:
@@ -177,6 +241,21 @@ def window(ctx: dict) -> None:
         for k, v in rows.items()}
     ctx["server_cpu_s"] = srv.cpu_seconds() - marks["cpu"]
     ctx["compiles"] = srv.compiles(marks["log"], srv.log_mark())
+
+
+def pool_facts(ctx: dict) -> dict:
+    """Of the window's answered reads of pool keys: how many, their
+    payload bytes, the bytes of their records that lie on a lost data
+    shard (what any implementation HAS to reconstruct to answer them:
+    the harness's own layout, never a counter of the program's), and
+    how many had any."""
+    res, facts = ctx["res"], ctx["key_facts"]
+    keys = res["ids"][ctx["req"]["inside"] & (res["ok"] != loadgen.FAILED)]
+    gone = facts["lost_bytes"][keys]
+    return {"pool_reads": len(keys),
+            "pool_read_bytes": int(facts["bytes"][keys].sum()),
+            "pool_lost_bytes": int(gone.sum()),
+            "pool_reads_on_lost_shards": int((gone > 0).sum())}
 
 
 # ---------------------------------------------------------------------------
@@ -231,6 +310,20 @@ def compare(ctx: dict) -> dict:
             (res["ok"][inside] == loadgen.FAILED).sum())
         out["answers_differ"] = int(
             (res["ok"][inside] == loadgen.DIFFERS).sum())
+        if ctx["pool_keys"]:
+            # A run whose draw never touched a lost shard says so; and
+            # a read repairs nothing on disk: no shard the configuration
+            # lost is back in a volume that no job of the window rebuilt.
+            out["pool_reads_on_lost_shards"] = \
+                ctx["req"]["pool_reads_on_lost_shards"]
+            tpl = ctx["template"]
+            rebuilt = {vid for vid, _t0, _t1 in
+                       (ctx.get("job") or {}).get("jobs", ())}
+            out["lost_shards_back"] = sum(
+                os.path.exists(os.path.join(
+                    srv.data_dir, f"{tpl.collection}_{vid}") + ecref.ext(sid))
+                for vid in ctx["vids"] if vid not in rebuilt
+                for sid in pool_lost_shards(ctx))
         if ctx["req"]["op"] == "write":
             # Every acknowledged write reads back byte for byte: a
             # sample of the window's, drawn from the seed.
@@ -251,7 +344,8 @@ def compare(ctx: dict) -> dict:
                 http.close()
             out["writes_read_back"] = len(pick)
             out["writes_lost_or_differ"] = bad
-    counted = ("needles_read", "writes_read_back", "volumes_compared")
+    counted = ("needles_read", "writes_read_back", "volumes_compared",
+               "pool_reads_on_lost_shards")
     return {k: [v, None if k in counted else 0] for k, v in out.items()}
 
 
@@ -277,13 +371,15 @@ def measures(ctx: dict) -> dict:
     """Every end-to-end quantity this run can report, by metric name."""
     out = {"setup_s": ctx["setup_s"]}
     jobs = ctx.get("jobs")
-    if jobs and jobs.get("metric"):
+    if ctx.get("job") and jobs.get("metric"):
         out[jobs["metric"]] = ctx["job"]["MBps"]
     if ctx.get("req"):
         out["req_per_s"] = ctx["req"]["req_per_s"]
         out["req_p95_ms"] = ctx["req"]["p95_ms"]
-        for extra in ("clients_active", "least_client_share"):
-            out[extra] = ctx["req"][extra]
+        for extra in ("clients_active", "least_client_share", "pool_reads",
+                      "pool_read_bytes", "pool_lost_bytes"):
+            if extra in ctx["req"]:
+                out[extra] = ctx["req"][extra]
     return out
 
 
@@ -319,6 +415,11 @@ def run(args, hooks: Hooks) -> dict:
                  "requests": traffic.get("requests")}
     check(ctx["jobs"] or ctx["requests"],
           f"traffic {cell['traffic_name']!r} has neither jobs nor requests")
+    ctx["pool_keys"] = (ctx["requests"] or {}).get("keys_from") == "pool"
+    check(not ctx["pool_keys"]
+          or ctx["jobs"] and ctx["requests"]["op"] == "read",
+          f"traffic {cell['traffic_name']!r}: `keys_from: \"pool\"` is "
+          f"for reads, of a pool that `jobs` make")
     ctx["ec"] = traffic.get("ec") or cfg      # sizes of the jobs' volumes
     ctx["store"] = cfg                        # shape of the requests
     volume_max = 16
@@ -334,10 +435,16 @@ def run(args, hooks: Hooks) -> dict:
               f"a file here may hold {machine['file_cap']} bytes: too "
               f"small for a volume ({machine})")
         # A fixed amount of work: so many jobs, or so many for each
-        # second the run was given.
-        ctx["pool"] = jobs["repeat"] if "repeat" in jobs else (
+        # second the run was given, over a pool of as many volumes or
+        # of as many as the mix says.
+        ctx["window_jobs"] = jobs["repeat"] if "repeat" in jobs else (
             2 if rehearse else
             max(1, round(args.seconds * jobs["per_second"])))
+        ctx["pool"] = jobs.get("volumes", ctx["window_jobs"])
+        check(ctx["pool"] >= max(1, ctx["window_jobs"])
+              and (ctx["window_jobs"] or ctx["requests"]),
+              f"traffic {cell['traffic_name']!r}: {ctx['window_jobs']} "
+              f"jobs over {ctx['pool']} volumes")
         # What a run writes: the filled volume, and per job 14 shards
         # of a tenth of the volume each, or the lost ones again.
         written = 1.4 if jobs["op"] == "ec.encode" else \
@@ -351,13 +458,13 @@ def run(args, hooks: Hooks) -> dict:
         ctx["machine"] = machine
     else:
         work, ctx["machine"] = place_work_dir(FILE_SLACK)
-    if ctx["requests"]:
+    if ctx["requests"] and not ctx["pool_keys"]:
         volume_max += ctx["store"]["volumes"]
         ctx["keys"] = 256 if rehearse else ctx["requests"].get("keys", 0)
     say(f"bench: {cell['name']} seed={args.seed} seconds={args.seconds} "
         f"trace={args.trace} machine={json.dumps(ctx['machine'])}")
 
-    srv = Server(work, rehearse, volume_max)
+    srv = Server(work, rehearse, volume_max, cfg.get("server_env"))
     ctx["srv"] = srv
     try:
         resolved = srv.wait_ready()
@@ -368,7 +475,7 @@ def run(args, hooks: Hooks) -> dict:
             f"start, resolved {resolved}")
         check(resolved["coder"] == "pallas"
               and resolved["platform"] == ("cpu" if rehearse else "tpu")
-              and resolved["count"] >= cell["chips"],
+              and resolved["count"] >= (1 if rehearse else cell["chips"]),
               f"the server resolved {resolved}; the cell wants the pallas "
               f"coder on {cell['chips']} TPU chip(s)")
         if ctx["jobs"]:

@@ -53,13 +53,48 @@ def log_tail(path: str, n: int = 40) -> str:
         return "".join(f.readlines()[-n:])
 
 
+ALLOCATOR_ENV = "MALLOC_"
+
+
+def server_environment(rehearse: bool, allocator: dict | None,
+                       base: dict | None = None) -> dict:
+    """The environment the server starts in: the harness's own less
+    every SEAWEEDFS_TPU_* variable (defaults are what is under test),
+    every program into the persistent cache, and what the
+    configuration's `server_env` says of the C library's allocator —
+    glibc's `MALLOC_*` variables and nothing else: a configuration
+    cannot reach a switch of the program or of JAX this way.  glibc
+    adjusts its `mmap` and trim thresholds to the sizes a process has
+    freed, so whether a later block comes from warm heap or from fresh
+    pages depends on the set-up's history: the same program then runs
+    in one of two modes from its first job to its last (PERF.md, PR 34),
+    and a deployment that fixes them runs in one."""
+    env = {k: v for k, v in (os.environ if base is None else base).items()
+           if not k.startswith("SEAWEEDFS_TPU_")}
+    # Every program goes to the persistent cache, the sub-second
+    # kernels too: only a checkout's first run compiles.
+    env.update(PYTHONPATH=ROOT, JAX_LOG_COMPILES="1",
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="-1")
+    if rehearse:
+        env.update(JAX_PLATFORMS="cpu", SEAWEEDFS_TPU_CODER="pallas",
+                   SEAWEEDFS_TPU_EC_FUSED_CRC="1")
+    for name, value in (allocator or {}).items():
+        check(name.startswith(ALLOCATOR_ENV) and isinstance(value, str),
+              f"server_env: {name!r}: only {ALLOCATOR_ENV}* variables, "
+              f"as strings")
+        env[name] = value
+    return env
+
+
 class Server:
     """`server_launcher.py server ...` in `work`, volumes under
     `work/data`.  `rehearse` asks for the chip's code path on the CPU
-    platform (Pallas in interpret mode); otherwise no SEAWEEDFS_TPU_*
-    variable reaches the process: defaults are what is under test."""
+    platform (Pallas in interpret mode); `allocator` is the
+    configuration's `server_env` (server_environment)."""
 
-    def __init__(self, work: str, rehearse: bool, volume_max: int):
+    def __init__(self, work: str, rehearse: bool, volume_max: int,
+                 allocator: dict | None = None):
         self.work = work
         self.data_dir = os.path.join(work, "data")
         os.makedirs(self.data_dir, exist_ok=True)
@@ -67,16 +102,7 @@ class Server:
         self.mport, self.vport = free_port(), free_port()
         self.master = f"http://127.0.0.1:{self.mport}"
         self.volume = f"http://127.0.0.1:{self.vport}"
-        env = {k: v for k, v in os.environ.items()
-               if not k.startswith("SEAWEEDFS_TPU_")}
-        # Every program goes to the persistent cache, the sub-second
-        # kernels too: only a checkout's first run compiles.
-        env.update(PYTHONPATH=ROOT, JAX_LOG_COMPILES="1",
-                   JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
-                   JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="-1")
-        if rehearse:
-            env.update(JAX_PLATFORMS="cpu", SEAWEEDFS_TPU_CODER="pallas",
-                       SEAWEEDFS_TPU_EC_FUSED_CRC="1")
+        env = server_environment(rehearse, allocator)
         to_child, self._cmd_w = os.pipe()
         self._reply_r, from_child = os.pipe()
         with open(self.log_path, "wb") as log:
@@ -183,8 +209,11 @@ class Server:
                 "seconds": sum(float(s) for _n, s in found)}
 
     def coder_rows(self) -> dict[str, dict]:
-        """`/debug/device` summed by kernel: calls, fenced call seconds
-        (H2D + kernel + D2H), bytes."""
+        """`/debug/device` summed by row name: count, seconds, bytes.
+        A kernel's row holds its fenced calls (the tail of the transfer
+        in and the kernel; the copy back is outside the fence); a
+        pipeline's unfenced calls leave none, and its stages' rows
+        follow in the same list."""
         out: dict[str, dict] = {}
         for r in call(f"{self.volume}/debug/device")["kernels"]:
             row = out.setdefault(r["kernel"],

@@ -5,7 +5,12 @@ For `(k, n)` bytes in and `r` rows of `n` bytes out, a GF(2^8) code
 moves `(k + r) * n` bytes and, as the bit-matrix product it is on a
 matrix unit, makes `2 * 8r * 8k * n` integer operations.  A fused CRC, a
 wider tile or another dtype changes the kernel's time and not this
-count.  Peaks come from one table, peaks.json, keyed by `device_kind`;
+count.  A read that meets lost bytes is the same code with one row out:
+an interval of `n` lost bytes moves `(k + 1) * n` bytes and makes
+`2 * 8 * 8k * n` operations, whatever launches it and however many
+intervals a launch holds (`facts["requests"]["pool_lost_bytes"]` is that
+`n` summed over a window's reads).  Peaks come from one table,
+peaks.json, keyed by `device_kind`;
 a device that is not in it is an error, never a default.
 """
 
@@ -33,6 +38,14 @@ def coder_bytes(k: int, r: int, n: int) -> int:
 
 def coder_ops(k: int, r: int, n: int) -> int:
     return 2 * (8 * r) * (8 * k) * n
+
+
+def lost_read_bytes(k: int, n: int) -> int:
+    return coder_bytes(k, 1, n)
+
+
+def lost_read_ops(k: int, n: int) -> int:
+    return coder_ops(k, 1, n)
 
 
 def least_seconds(k: int, r: int, n: int, device_kind: str) -> dict:

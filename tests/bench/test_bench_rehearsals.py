@@ -14,7 +14,9 @@ window, the comparison with the reference, the metrics.
   flipped byte, a job that does nothing; the request mixes' altered
   answer is the control's torn write);
 - a later PR's cell arrives as new files and manifest entries only, and
-  the read mix, whose files wait under `benchmark/`, as entries only.
+  the read mix, whose files wait under `benchmark/`, as entries only;
+  so does a cell whose clients read the needles of sealed volumes that
+  lost shards (`keys_from: "pool"`), with its control.
 """
 
 import filecmp
@@ -162,8 +164,29 @@ NEW_METRIC = '''"""EC file pipeline: coder calls per rebuilt volume."""
 
 def read(facts):
     jobs = facts["jobs"]
-    calls = facts["coder_rows"].get("reconstruct_kernel", {}).get("count")
+    calls = facts["coder_rows"].get("rebuild.dispatch", {}).get("count")
     return calls / jobs["count"] if jobs and calls else None
+'''
+POOL_CONFIG = {
+    "name": "warm-ec-read-small", "source": "a test's own deployment",
+    "volume_bytes": 41943040, "needle_bytes": [4096, 1048576],
+    "lost_shards": [3, 11], "clients": 16, "procs": 4,
+    "guarantees": ["as warm-ec-rs10-4", "any 10 shards give back every "
+                   "needle, to a client that asks while shards are gone"],
+    "reduced": ["volume_bytes"], "assumed": {"lost_shards": "3 and 11"}}
+POOL_TRAFFIC = {
+    "why": "16 clients read the needles of two small sealed volumes "
+           "that lost a data and a parity shard",
+    "sent_by": "a test",
+    "requests": {"op": "read", "warm_seconds": 1, "keys_from": "pool"},
+    "jobs": {"op": "ec.rebuild", "volumes": 2, "repeat": 0,
+             "metric": "rebuild_MBps"}}
+POOL_METRIC = '''"""EC file pipeline: bytes the window's reads had to have reconstructed."""
+
+
+def read(facts):
+    req = facts["requests"]
+    return req.get("pool_lost_bytes") if req else None
 '''
 
 
@@ -255,9 +278,74 @@ def test_a_new_cell_is_files_and_entries_only(tmp_path):
     assert rc == 0, err[-3000:]
     res = rehearsal_result(out)
     assert res["correct"] is True and res["attempted"] == 2
-    # one (10, 4 MiB) reconstruct call per 40 MiB volume
+    # one (10, 4 MiB) dispatch per 40 MiB volume
     assert res["metrics"] == {"rebuild_calls_per_volume":
                               {"value": 1.0, "unit": "count"}}
     assert res["compared"]["rebuilt_files_differ"] == [0, 0]
     assert set(res["seen"]) >= {"rebuild_MBps", "setup_s"}
+    _nothing_edited(root, before)
+
+
+@pytest.mark.parametrize("repeat", [0, 1])
+def test_a_pool_read_cell_is_files_and_entries_only(tmp_path, repeat):
+    """In a temporary copy of the benchmark: a configuration, a traffic
+    mix whose clients read the needles of sealed, damaged volumes, and
+    one reader of a fact the harness computes for such a mix.  With
+    `repeat` 0 no job runs in the window and the control comes out not
+    correct; with 1 of 2 the first volume is rebuilt beside the reads."""
+    root, man, before = _copy_of_the_benchmark(tmp_path)
+    bench = root / "benchmark"
+    traffic = json.loads(json.dumps(POOL_TRAFFIC))
+    traffic["jobs"]["repeat"] = repeat
+    (bench / "configs" / "warm-ec-read-small.json").write_text(
+        json.dumps(POOL_CONFIG))
+    (bench / "traffic" / "read-pool.json").write_text(json.dumps(traffic))
+    (bench / "metrics" / "pool_lost_bytes.py").write_text(POOL_METRIC)
+    man["configs"].append({
+        "name": "warm-ec-read-small", "source": POOL_CONFIG["source"],
+        "file": "benchmark/configs/warm-ec-read-small.json",
+        "reduced": ["volume_bytes"], "why": "a test"})
+    man["workloads"].append({
+        "name": "read-pool-small", "config": "warm-ec-read-small",
+        "traffic": "read-pool", "chips": 1, "why": "a test"})
+    for m in man["end_to_end"]:
+        if m["name"] == "req_per_s":
+            m["workloads"].append("read-pool-small")
+    man["per_layer"].append({
+        "name": "pool_lost_bytes", "unit": "bytes", "better": "lower",
+        "source": "program_counter", "layer": "EC file pipeline",
+        "moves": "req_per_s", "workloads": ["read-pool-small"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(man))
+
+    rc, out, err = rehearse("run.py", "read-pool-small", 2**31 + 41,
+                            trace=1, root=str(root), seconds=6)
+    assert rc == 0, err[-3000:]
+    res = rehearsal_result(out)
+    c = res["compared"]
+    assert res["correct"] is True and res["failed"] == 0
+    # Sixteen clients send.  Here a read of a damaged needle compiles
+    # for seconds (interpret mode, a program a width), so on a loaded
+    # machine a client that draws two in a row can spend a short window
+    # in them and count as idle: most of the sixteen answer inside it.
+    assert 8 <= res["seen"]["clients_active"] <= 16
+    assert c["requests_failed"] == [0, 0] and c["answers_differ"] == [0, 0]
+    assert c["pool_reads_on_lost_shards"][0] > 0
+    assert c["pool_reads_on_lost_shards"][1] is None
+    assert c["lost_shards_back"] == [0, 0]
+    lost = res["metrics"]["pool_lost_bytes"]["value"]
+    assert 0 < lost < res["attempted"] * 2**20
+    if repeat:
+        assert c["rebuilt_files_differ"] == [0, 0]
+        assert c["volumes_compared"] == [1, None]
+        assert res["seen"]["rebuild_MBps"] > 0
+    else:
+        assert res["attempted"] > 16
+        assert "files_missing" not in c and "rebuild_MBps" not in res["seen"]
+        rc, out, err = rehearse("control.py", "read-pool-small", 2**31 + 42,
+                                root=str(root), seconds=6)
+        assert rc == 0, (out[-2000:], err[-3000:])
+        assert "control: shard 10 of volume 102" in out
+        c = rehearsal_result(out)["compared"]
+        assert c["requests_failed"][0] + c["answers_differ"][0] > 0
+        assert c["lost_shards_back"] == [0, 0]
     _nothing_edited(root, before)

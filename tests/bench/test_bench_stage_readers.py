@@ -59,12 +59,14 @@ def test_stage_share_readers_on_facts_made_by_hand(name, want):
     assert read(_facts(op, {})) is None
     # no jobs at all (a request cell)
     assert read(dict(_facts(op, rows), jobs=None)) is None
-    entry = next(m for m in MAN["per_layer"] if m["name"] == name)
+    entry = dict(next(m for m in MAN["per_layer"] if m["name"] == name))
+    # its own cell first; a later PR may append cells that serve the rows
+    assert entry.pop("workloads")[0] == \
+        ("seal" if name in SEAL else "rebuild")
     assert entry == {
         "name": name, "unit": "%", "better": "lower",
         "source": "program_span", "layer": "EC file pipeline",
-        "moves": "seal_MBps" if name in SEAL else "rebuild_MBps",
-        "workloads": ["seal" if name in SEAL else "rebuild"]}
+        "moves": "seal_MBps" if name in SEAL else "rebuild_MBps"}
 
 
 @pytest.mark.parametrize("shares", [SEAL, REBUILD])
@@ -77,10 +79,35 @@ def test_main_thread_shares_and_the_unspanned_are_the_window(shares):
     assert total == pytest.approx(100.0)
 
 
+PR_24 = {"server_cpu_us_per_req": ["bench-write-1k", "seal-under-load",
+                                   "rebuild-under-load"],
+         "write_p99_ms": ["bench-write-1k", "seal-under-load",
+                          "rebuild-under-load"],
+         "longest_stall_ms": ["bench-write-1k", "seal-under-load",
+                              "rebuild-under-load"],
+         "client_cpu_share": ["bench-write-1k", "seal-under-load",
+                              "rebuild-under-load"],
+         "encode_kernel_roofline": ["seal"],
+         "reconstruct_kernel_roofline": ["rebuild"],
+         "seal_compiles_in_window": ["seal"],
+         "seal_device_idle_share": ["seal"],
+         "rebuild_compiles_in_window": ["rebuild"],
+         "rebuild_device_idle_share": ["rebuild"],
+         "req_compiles_in_window": ["bench-write-1k"],
+         "req_device_idle_share": ["bench-write-1k"]}
+
+
 def test_stage_metrics_are_appended_and_the_old_ones_untouched():
-    names = [m["name"] for m in MAN["per_layer"]]
-    assert names[13] == "req_device_idle_share"     # the last of PR 24's
-    assert sorted(names[14:]) == sorted((*SEAL, *REBUILD))
-    assert not any("bench-write-1k" in m["workloads"]
-                   for m in MAN["per_layer"] if m["name"] in
-                   (*SEAL, *REBUILD))
+    """PR 24's twelve that remain (its two `*_coder_call_share` fell
+    silent and went with PR 34), then PR 25's thirteen, each still there
+    with its cells; what later PRs appended is free."""
+    listed = {m["name"]: m["workloads"] for m in MAN["per_layer"]}
+    names = list(listed)
+    assert names[:12] == list(PR_24)
+    assert sorted(names[12:25]) == sorted((*SEAL, *REBUILD))
+    for name, cells in PR_24.items():
+        assert set(cells) <= set(listed[name]), name
+    assert "seal_coder_call_share" not in listed
+    assert "rebuild_coder_call_share" not in listed
+    assert not any("bench-write-1k" in listed[name]
+                   for name in (*SEAL, *REBUILD))

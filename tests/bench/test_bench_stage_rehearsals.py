@@ -36,6 +36,3 @@ def test_traced_rehearsal_reports_every_stage_share(cell):
     assert all(0.0 <= v <= 100.0 for v in got.values()), got
     main = sum(v for k, v in got.items() if k != "seal_stack_busy_share")
     assert main == pytest.approx(100.0, abs=1e-6), got
-    # the coder's fenced calls sit inside the pipeline's dispatch stage
-    assert got[f"{cell}_dispatch_share"] >= \
-        res["metrics"][f"{cell}_coder_call_share"]["value"]

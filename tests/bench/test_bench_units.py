@@ -102,6 +102,50 @@ def test_importing_the_harness_loads_no_jax():
     subprocess.run([sys.executable, "-c", code], check=True, timeout=120)
 
 
+def test_four_chip_cells_are_few_and_say_why():
+    """Nothing of this store exists only across chips: a cell that
+    holds the whole host does so for steadiness, and says so."""
+    four = [w for w in MAN["workloads"] if w["chips"] == 4]
+    assert len(four) <= max(1, len(MAN["workloads"]) // 2)
+    for w in four:
+        assert "4 chips for steadiness alone" in w["why"], w["name"]
+
+
+def test_the_servers_environment_takes_allocator_variables_only():
+    from benchmark.machine import BenchFailure
+    from benchmark.served import server_environment
+    base = {"PATH": "/bin", "SEAWEEDFS_TPU_CODER": "numpy",
+            "MALLOC_ARENA_MAX": "8"}
+    env = server_environment(False, None, base)
+    assert "SEAWEEDFS_TPU_CODER" not in env and env["PATH"] == "/bin"
+    assert env["MALLOC_ARENA_MAX"] == "8" and "JAX_PLATFORMS" not in env
+    env = server_environment(False, {"MALLOC_ARENA_MAX": "1",
+                                     "MALLOC_TRIM_THRESHOLD_": "1024"}, base)
+    assert env["MALLOC_ARENA_MAX"] == "1"
+    assert env["MALLOC_TRIM_THRESHOLD_"] == "1024"
+    assert server_environment(True, None, base)["JAX_PLATFORMS"] == "cpu"
+    for smuggled in ({"SEAWEEDFS_TPU_EC_FUSED_CRC": "0"},
+                     {"JAX_PLATFORMS": "cpu"}, {"XLA_FLAGS": "--x"},
+                     {"LD_PRELOAD": "libjemalloc.so"},
+                     {"MALLOC_ARENA_MAX": 1}):
+        with pytest.raises(BenchFailure):
+            server_environment(False, smuggled, base)
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in MAN["configs"]])
+def test_a_configurations_server_env_is_the_allocators(config):
+    """What a configuration says of its server's environment starts
+    the server; `assumed` says why it is there."""
+    from benchmark.served import server_environment
+    entry = next(c for c in MAN["configs"] if c["name"] == config)
+    with open(os.path.join(ROOT, entry["file"])) as f:
+        cfg = json.load(f)
+    said = cfg.get("server_env")
+    env = server_environment(False, said, {})
+    assert all(env[k] == v for k, v in (said or {}).items())
+    assert (said is None) or "server_env" in cfg["assumed"]
+
+
 # -- inputs from the seed -----------------------------------------------------
 
 def test_every_seed_gives_the_same_set_of_sizes_in_another_order():
@@ -279,6 +323,62 @@ def test_window_statistics_count_a_failure_as_slower_than_any():
     assert got["p50_ms"] == pytest.approx(10.0)
 
 
+def test_lost_bytes_of_three_needles_made_by_hand():
+    """Block b of the volume is block b // 10 of shard b % 10: a record
+    inside a healthy block, one inside shard 3's block of the second
+    row, and one that straddles shard 2's block and shard 3's."""
+    mib = ecref.BLOCK
+    healthy = (5 * mib + 4096, ecref.record_bytes(70000))
+    inside = (13 * mib + 8, ecref.record_bytes(300000))
+    straddling = (3 * mib - 1000, ecref.record_bytes(5000))
+    assert ecref.record_bytes(70000) == 70032      # 28 around, 4 padding
+    assert ecref.record_bytes(5000) == 5032
+    assert ecref.record_bytes(4) == 40             # a whole 8 of padding
+    lost = [3, 11]
+    assert ecref.lost_bytes(*healthy, lost) == 0
+    assert ecref.lost_bytes(*inside, lost) == inside[1] == 300032
+    assert ecref.lost_bytes(*straddling, lost) == 5032 - 1000
+    # a lost parity shard holds no byte of the volume; four lost data
+    # shards hold what lies in their blocks, to the byte
+    assert ecref.lost_bytes(0, 20 * mib, [11, 13]) == 0
+    assert ecref.lost_bytes(mib // 2, 4 * mib, [0, 1, 2, 3]) == \
+        7 * mib // 2
+    assert ecref.lost_bytes(9 * mib + 5, 2 * mib, [0, 9]) == 2 * mib - 5
+
+    # what a window's reads add up to (run.py `pool_facts`): two volumes
+    # of those three needles, keys 0-5; key 4 is volume 2's `inside`
+    from benchmark import run
+    gone = [ecref.lost_bytes(*r, lost) for r in (healthy, inside,
+                                                 straddling)]
+    ctx = {"key_facts": {"bytes": np.tile([70000, 300000, 5000], 2),
+                         "lost_bytes": np.tile(gone, 2)},
+           "res": {"ids": np.array([0, 4, 2, -1, 4, 1, 3]),
+                   "ok": np.array([0, 0, 1, 2, 0, 0, 0])},
+           "req": {"inside": np.array([1, 1, 1, 1, 1, 0, 1], bool)}}
+    assert run.pool_facts(ctx) == {
+        "pool_reads": 5, "pool_read_bytes": 70000 * 2 + 300000 * 2 + 5000,
+        "pool_lost_bytes": 300032 * 2 + 4032,
+        "pool_reads_on_lost_shards": 3}
+
+
+def test_index_entries_and_the_record_header(tmp_path):
+    path = tmp_path / "v.idx"
+    path.write_bytes(ecref.INDEX_ENTRY.pack(7, 1, 100)
+                     + ecref.INDEX_ENTRY.pack(0x1234, 131072, 4200)
+                     + ecref.INDEX_ENTRY.pack(7, 17, -1))
+    assert ecref.index_entries(str(path)) == {7: (136, -1),
+                                              0x1234: (1 << 20, 4200)}
+    assert ecref.RECORD_HEADER.size == 16 and ecref.INDEX_ENTRY.size == 16
+
+
+def test_work_of_a_read_that_meets_lost_bytes_by_hand():
+    # 1000 lost bytes under RS(10,4): ten survivors' intervals in and
+    # one out; one output row of 8 bit-planes against 80
+    assert work.lost_read_bytes(10, 1000) == 11000
+    assert work.lost_read_ops(10, 1000) == 2 * 8 * 80 * 1000
+    assert work.lost_read_bytes(10, 1000) == work.coder_bytes(10, 1, 1000)
+
+
 def test_per_layer_readers_on_facts_made_by_hand():
     facts = {"requests": {"attempted": 1000, "failed": 0, "op": "write",
                           "p99_ms": 12.5, "client_cpu_share": 9.0,
@@ -300,6 +400,4 @@ def test_per_layer_readers_on_facts_made_by_hand():
     facts.update(requests=None, jobs={"op": "ec.encode", "count": 2,
                                       "shard_bytes": 1, "lost": 2},
                  coder_rows={"encode_crc_kernel": {"seconds": 2.5}})
-    assert read("seal_coder_call_share") == pytest.approx(25.0)
-    assert read("rebuild_coder_call_share") is None
     assert read("server_cpu_us_per_req") is None
