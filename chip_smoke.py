@@ -578,19 +578,21 @@ def served_leg(work: str, seed: int, rehearse: bool,
         check(drained == calls["rebuild.drain"] > 0,
               f"served: rebuild_inflight {dev['rebuild_inflight']} "
               f"against {calls['rebuild.drain']} drains")
-        # ... and a direct call (the degraded reads') still fences and
-        # records.
-        check(rows.get("reconstruct_kernel", 0) > 0,
-              f"served: the degraded reads left no reconstruct kernel "
-              f"row: {rows}")
+        # ... and the degraded reads went through the third rung's one
+        # unfenced call a launch (ec/degraded.py): its stage rows, as
+        # many dispatches as drains, every rebuilt byte drained.
+        check(calls.get("read.dispatch", 0) == calls.get("read.drain", 0)
+              > 0 and calls.get("read.degraded", 0) > 0
+              and rows.get("read.drain", 0) == rows.get("read.interval", 0)
+              > 0,
+              f"served: the degraded reads left no rows of the third "
+              f"rung: {calls}")
         check(dev["conservation"]["ok"], f"served: {dev['conservation']}")
     finally:
         stop_child(p)
     check(p.returncode == 0,
           f"served: server exited with {p.returncode}:\n"
           f"{log_tail(log_path)}")
-    widths = sorted({r["geometry"] for r in dev["kernels"]
-                     if r["kernel"] == "reconstruct_kernel"})
     return {"pass": True, "resolved": resolved,
             "seconds": round(time.perf_counter() - t_leg, 1),
             "volumes": parts,
@@ -605,7 +607,7 @@ def served_leg(work: str, seed: int, rehearse: bool,
             "shard_bytes": summed("shard_bytes"),
             "parity_blocks_checked": summed("parity_blocks_checked"),
             "ecc_entries_checked": summed("ecc_entries_checked"),
-            "reconstruct_geometries": widths,
+            "degraded_read_launches": calls["read.dispatch"],
             "kernel_bytes": rows,
             # fenced walls of the coder calls: H2D + kernel + D2H
             "kernel_call_seconds": kernel_seconds,

@@ -19,6 +19,9 @@
 #include <cstdint>
 #include <cstring>
 
+#include <cerrno>
+#include <unistd.h>
+
 #if defined(__x86_64__) || defined(_M_X64)
 #include <immintrin.h>
 #define SW_X86 1
@@ -179,6 +182,30 @@ void sw_gf_mix(const uint8_t* mat, int rows, int cols,
             uint8_t coef = mat[r * cols + c];
             if (coef) sw_gf_mul_add(coef, ins[c], outs[r], n);
         }
+    }
+}
+
+// dsts[i] gets lens[i] bytes of file fds[i] from offs[i], for n reads in
+// one call: an EC needle's shard intervals, and the ten survivors of a
+// degraded read's gather (ec/degraded.py), behind ONE release of the
+// interpreter's lock.  got[i] is what read i got (short at a file's
+// end, -1 on an error).
+void sw_pread_rows(int n, const int* fds, const int64_t* offs,
+                   uint8_t* const* dsts, const int64_t* lens,
+                   int64_t* got) {
+    for (int i = 0; i < n; i++) {
+        size_t done = 0, len = (size_t)lens[i];
+        while (done < len) {
+            ssize_t r = pread(fds[i], dsts[i] + done, len - done,
+                              (off_t)(offs[i] + (int64_t)done));
+            if (r < 0 && errno == EINTR) continue;
+            if (r <= 0) {
+                if (r < 0 && done == 0) done = (size_t)-1;
+                break;
+            }
+            done += (size_t)r;
+        }
+        got[i] = (int64_t)done;
     }
 }
 

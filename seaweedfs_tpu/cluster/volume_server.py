@@ -30,14 +30,15 @@ from ..ec import TOTAL_SHARDS, to_ext
 from ..ec.encoder import rebuild_ec_files, write_ec_files, \
     write_sorted_file_from_idx
 from ..ec.shard_bits import ShardBits
-from ..ec.volume import EcVolume, NeedleNotFound
+from ..ec.degraded import DegradedReader, Lost
+from ..ec.volume import EcVolume, NeedleNotFound, read_many
 from ..events import emit as emit_event
 from ..fault import registry as _fault
 from ..codecs import get_codec
 from ..stats import flows as _flows
 from ..stats import roofline as _roofline
 from ..stats.metrics import (ec_repair_read_bytes_total,
-                             needle_repairs_total, observe_ec_stage)
+                             needle_repairs_total)
 from ..storage.scrub import ScrubDaemon
 from ..storage.store import Store
 from ..storage.vacuum import vacuum as vacuum_volume
@@ -173,6 +174,12 @@ class VolumeServer:
         self._vol_loc_cache: dict[int, tuple[float, dict]] = {}
         self._ec_read_pool: concurrent.futures.ThreadPoolExecutor | None = None
         self._ec_pool_lock = threading.Lock()
+        # The degraded read's third rung, and the scrub's block repair.
+        self.degraded = DegradedReader(
+            locations=self._ec_shard_locations,
+            fetch=self._fetch_shard_interval, pool=self._ec_pool,
+            node=self.url,
+            forget=lambda vid: self._ec_loc_cache.pop(vid, None))
         self._reap_partial_files()
         self._load_ec_volumes()
         # -fsync: force per-write durability (every POST behaves like
@@ -1139,19 +1146,28 @@ class VolumeServer:
         local shard -> remote shard via peers -> on-the-fly reconstruction
         gathering >=10 shard intervals from the cluster.  Returns the
         parsed needle; response shaping lives in _serve_needle."""
+        t0 = time.perf_counter() if _roofline.ARMED else None
         self._ensure_ec_version(ev)
         try:
             _offset, _size, intervals = ev.locate_needle(key)
         except NeedleNotFound as e:
             raise rpc.RpcError(404, str(e)) from None
         try:
-            blob = b"".join(self._read_ec_interval(ev, iv)
-                            for iv in intervals)
+            # Rung one for every interval at once, into the needle's
+            # own bytes (ec/volume.py `read_many`); rung two interval
+            # by interval; what neither could read goes to the third
+            # together, so that the lost intervals of one stripe row
+            # share their survivors.
+            blob, parts, lost = self._read_ec_intervals(ev, intervals)
+            if lost:
+                self.degraded.intervals(ev, [parts[i] for i in lost])
         except Exception as e:  # noqa: BLE001
             raise rpc.RpcError(500, f"{type(e).__name__}: {e}") from None
-        n = Needle.from_bytes(blob, ev.version)
+        n = Needle.from_bytes(blob.tobytes(), ev.version)
         if n.cookie != cookie:
             raise rpc.RpcError(403, "cookie mismatch")
+        if t0 is not None:
+            _roofline.note_read(t0, ev.codec.name, bool(lost), len(blob))
         return n
 
     def _ensure_ec_version(self, ev: EcVolume) -> None:
@@ -1223,137 +1239,48 @@ class VolumeServer:
                     max_workers=32, thread_name_prefix="ec-read")
             return self._ec_read_pool
 
-    def _read_ec_interval(self, ev: EcVolume, interval) -> bytes:
-        sid, off = interval.to_shard_id_and_offset(
-            ev.large_block_size, ev.small_block_size)
-        size = interval.size
-        # 1. local shard
-        shard = ev.shards.get(sid)
-        if shard is not None:
-            buf = shard.read_at(off, size)
-            if len(buf) == size:
-                return buf
-        # 2. remote shard holders (failover across every holder, like
-        #    readRemoteEcShardInterval walking sourceDataNodes)
-        locations = self._ec_shard_locations(ev.vid)
-        with trace_span("ec.shard_fetch", vid=ev.vid, shard=sid,
-                        size=size):
-            data = self._fetch_shard_interval(ev, locations, sid, off,
-                                              size)
-        if data is not None:
-            return data
-        # 3. reconstruct from >=10 other shard intervals.
-        return self._reconstruct_shard_interval(ev, sid, off, size)
-
-    def _reconstruct_shard_interval(self, ev: EcVolume, sid: int,
-                                    off: int, size: int) -> bytes:
-        """One shard interval through the decode path, codec-aware:
-        gather the SAME byte range from the codec's planned MINIMAL
-        survivor set — the local group for an in-group LRC loss (5
-        reads), the first data_shards survivors for RS — widening to
-        more siblings only when a planned read fails, and stopping the
-        widened fan-out as soon as the erasure pattern solves (the old
-        "any >=10" ladder, generalized to pick the cheapest survivor
-        set).  Reads fan out in parallel (store_ec.go:322-376 launches
-        one goroutine per shard); every gathered byte lands in
-        SeaweedFS_ec_repair_read_bytes_total{codec=}.  Shared by the
-        degraded read ladder and the scrub's corrupt-block repair."""
-        locations = self._ec_shard_locations(ev.vid)
-        codec = ev.codec
-        with trace_span("ec.reconstruct", vid=ev.vid, shard=sid,
-                        size=size, codec=codec.name) as rspan:
-            # Pool threads have no thread-local trace context — hand
-            # them this span's context explicitly.
-            tp = rspan.traceparent() or None
-            pool = self._ec_pool()
-            t_gather = time.perf_counter()
-            candidates = [s for s in range(codec.total_shards)
-                          if s != sid]
-            have: dict[int, bytes] = {}
-
-            def solvable() -> bool:
-                try:
-                    codec.decode_matrix(tuple(have), (sid,))
-                    return True
-                except ValueError:
-                    return False
-
-            try:
-                plan = codec.repair_plan(tuple(candidates), [sid])[0]
-            except ValueError:
-                raise rpc.RpcError(
-                    500, f"shard {sid} of ec volume {ev.vid} is "
-                         f"unrecoverable under codec {codec.name}"
-                ) from None
-            futs = {
-                pool.submit(
-                    self._fetch_shard_interval, ev, locations, other,
-                    off, size, tp):
-                other for other in plan.reads
-            }
-            for f in concurrent.futures.as_completed(futs):
-                buf = f.result()
-                if buf is not None:
-                    have[futs[f]] = buf
-            plan_ok = len(have) == len(plan.reads)
-            if not plan_ok and not solvable():
-                # A planned read failed: widen to every remaining
-                # sibling, stopping as soon as the pattern solves.
-                futs = {
-                    pool.submit(
-                        self._fetch_shard_interval, ev, locations,
-                        other, off, size, tp):
-                    other for other in candidates
-                    if other not in plan.reads
-                }
-                for f in concurrent.futures.as_completed(futs):
-                    buf = f.result()
-                    if buf is not None:
-                        have[futs[f]] = buf
-                        if solvable():
-                            break
-                for f in futs:
-                    f.cancel()
-            # Network fan-out cost, separate from the GF solve below.
-            gathered_bytes = sum(len(b) for b in have.values())
-            observe_ec_stage("shard_gather",
-                             time.perf_counter() - t_gather,
-                             gathered_bytes)
-            ec_repair_read_bytes_total.inc(gathered_bytes,
-                                           codec=codec.name)
-            if not solvable():
-                # The location map let us down — drop it so the next
-                # read refreshes immediately instead of waiting out the
-                # TTL.
-                self._ec_loc_cache.pop(ev.vid, None)
-                raise rpc.RpcError(
-                    500, f"cannot reconstruct shard {sid}: only "
-                         f"{len(have)} shard intervals reachable")
-            if plan_ok and plan.local:
-                # Degraded read / repair served entirely from the
-                # shard's locality group — the LRC payoff.
-                emit_event("ec.repair.local", node=self.url(),
-                           vid=ev.vid, shard=sid, codec=codec.name,
-                           reads=len(have), bytes=gathered_bytes)
-            import jax
-            import numpy as np
-            arrs = {k: np.frombuffer(v, dtype=np.uint8)
-                    for k, v in have.items()}
-            # Execution-fenced device time: block_until_ready is a
-            # no-op passthrough for numpy/native coders and fences the
-            # async dispatch for jax/pallas ones, so the histogram
-            # records real solve time, not dispatch time.
-            t_dev = time.perf_counter()
-            rec = jax.block_until_ready(
-                ev.coder.reconstruct(arrs, wanted=[sid]))
-            observe_ec_stage("reconstruct_device",
-                             time.perf_counter() - t_dev, size)
-            t_stage = time.perf_counter()
-            out = np.asarray(rec[sid]).tobytes()
-            observe_ec_stage("host_staging",
-                             time.perf_counter() - t_stage, size)
-            rspan.set(gathered=len(have))
-            return out
+    def _read_ec_intervals(self, ev: EcVolume, intervals):
+        """A needle's shard intervals from the first two rungs, into
+        ONE array of the record's bytes: (the array; per interval its
+        view of the array or, where neither rung could read it, the
+        `Lost` that asks the third rung (ec/degraded.py) to fill that
+        view; the indexes of those)."""
+        import numpy as np
+        blob = np.empty(sum(iv.size for iv in intervals), dtype=np.uint8)
+        parts, where, at = [], [], 0
+        for iv in intervals:
+            parts.append(blob[at:at + iv.size])
+            where.append(iv.to_shard_id_and_offset(
+                ev.large_block_size, ev.small_block_size))
+            at += iv.size
+        # 1. local shards, every interval in one call
+        local = [i for i, (sid, _off) in enumerate(where)
+                 if sid in ev.shards]
+        full = read_many([(ev.shards[where[i][0]], where[i][1], parts[i])
+                          for i in local])
+        read = {i for i, ok in zip(local, full) if ok}
+        missing = [i for i in range(len(parts)) if i not in read]
+        locations = self._ec_shard_locations(ev.vid) if missing else {}
+        lost = []
+        for i in missing:
+            (sid, off), size = where[i], intervals[i].size
+            # 2. remote shard holders (failover across every holder,
+            #    like readRemoteEcShardInterval walking sourceDataNodes)
+            if locations.get(sid):
+                with trace_span("ec.shard_fetch", vid=ev.vid, shard=sid,
+                                size=size):
+                    data = self._fetch_shard_interval(ev, locations, sid,
+                                                      off, size)
+                if data is not None:
+                    parts[i][:] = np.frombuffer(data, dtype=np.uint8)
+                    continue
+            # 3. reconstruct from the other shards' intervals.
+            lost.append(i)
+            parts[i] = Lost(sid, off, size, parts[i],
+                            (intervals[i].is_large_block,
+                             intervals[i].block_index
+                             // ev.codec.data_shards))
+        return blob, parts, lost
 
     def _fetch_shard_interval(self, ev: EcVolume,
                               locations: dict[int, list[str]],
@@ -1474,8 +1401,7 @@ class VolumeServer:
         round could still use."""
         from ..core.crc import crc32c
         try:
-            data = self._reconstruct_shard_interval(ev, sid, offset,
-                                                    size)
+            data = self.degraded.interval(ev, sid, offset, size)
         except Exception:  # noqa: BLE001 — not enough healthy shards
             return False
         shard = ev.shards.get(sid)

@@ -84,6 +84,22 @@ def _log_device(role: str, codes: bool, note: str = "") -> None:
                    "initialises no JAX backend%s", role, note)
 
 
+def _warm_reads(role: str) -> None:
+    """A role that serves erasure-coded needles says once which way a
+    GET's shard reads go (ec/volume.py `read_many_path`: one call of
+    the host library, or a `preadv` a row where it is not built), and
+    on the device coder compiles the degraded read's programs now,
+    beside its first requests and off their path (ec/degraded.py):
+    called once the role serves, after `_log_device` resolved the
+    coder.  A host coder compiles nothing."""
+    from ..ec.volume import read_many_path
+    from ..ops.erasure import default_backend
+    glog.infof("%s ec reads: %s", role, read_many_path())
+    if default_backend() == "pallas":
+        from ..ec.degraded import warm_in_background
+        warm_in_background()
+
+
 def _wait_forever(servers: list, grace: float | None = None) -> int:
     stop = threading.Event()
 
@@ -345,6 +361,7 @@ def run_volume(flags: Flags, args: list[str]) -> int:
     vs.start()
     glog.infof("volume server serving at %s (dirs %s)",
                vs.server.url(), dirs)
+    _warm_reads("volume")
     g = _start_volume_grpc(vs, flags, flags.get("ip", "127.0.0.1"))
     return _wait_forever([vs] + ([g] if g else []),
                          grace=flags.get_float("shutdown.grace", 30.0))
@@ -522,6 +539,7 @@ def run_server(flags: Flags, args: list[str]) -> int:
     servers.append(vs)
     glog.infof("master at %s, volume at %s", m.server.url(),
                vs.server.url())
+    _warm_reads("server")
     g = _start_master_grpc(m, flags, ip)
     if g:
         servers.append(g)

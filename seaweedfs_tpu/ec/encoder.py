@@ -191,11 +191,15 @@ def _is_ready(handle) -> bool:
 
 def write_sorted_file_from_idx(base_file_name: str,
                                ext: str = ".ecx") -> None:
-    """Generate the sorted `.ecx` from the `.idx` (WriteSortedFileFromIdx)."""
+    """Generate the sorted `.ecx` from the `.idx` (WriteSortedFileFromIdx).
+    Written beside its name and moved over it: a mounted EcVolume maps
+    the file it opened (ec/volume.py), and a file cut to nothing under a
+    mapping is a SIGBUS at the next lookup, not an error."""
     with open(base_file_name + ".idx", "rb") as f:
         db = MemDb.from_idx(f)
-    with open(base_file_name + ext, "wb") as out:
+    with open(base_file_name + ext + ".tmp", "wb") as out:
         out.write(db.to_sorted_bytes())
+    os.replace(base_file_name + ext + ".tmp", base_file_name + ext)
 
 
 def _shard_write(f, sid: int, buf, accs) -> None:

@@ -13,6 +13,8 @@ exposes the same reconstruction hook.
 
 from __future__ import annotations
 
+import functools
+import mmap
 import os
 import threading
 
@@ -48,8 +50,58 @@ class EcVolumeShard:
     def read_at(self, offset: int, size: int) -> bytes:
         return os.pread(self._f.fileno(), size, offset)
 
+    def fileno(self) -> int:
+        return self._f.fileno()
+
+    def read_into(self, buf, offset: int) -> int:
+        """Fill the writable buffer `buf` from `offset`, in place;
+        the bytes read (short at the file's end)."""
+        return os.preadv(self._f.fileno(), [buf], offset)
+
     def close(self) -> None:
         self._f.close()
+
+
+@functools.lru_cache(maxsize=1)
+def _pread_rows():
+    """utils/native.py `pread_rows`, or None where the host library is
+    not built (or an older one was named by hand)."""
+    from ..utils import native
+    lib = native.load()
+    if lib is None or not hasattr(lib, "sw_pread_rows"):
+        return None
+    return native.pread_rows_fn(lib)
+
+
+def read_many_path() -> str:
+    """The way `read_many` reads in this process: `sw_pread_rows` (one
+    call of the host library a GET) or `preadv` (a call a row, where
+    the library is not built).  A server logs it once at its start and
+    `/debug/device` shows it as `ec_reads`: the second way costs a
+    degraded GET beside fifteen others twenty times the first's gather
+    (`read_many`'s readings)."""
+    return "sw_pread_rows" if _pread_rows() is not None else "preadv"
+
+
+def read_many(reads: list[tuple[EcVolumeShard, int, np.ndarray]]
+              ) -> list[bool]:
+    """Fill every `row` of `reads` — (shard, offset, row), `row` a
+    writable uint8 vector — from its shard's file at its offset; True
+    where a read came in full.  The reads of one GET go out in ONE call
+    of the host library where it is built: the interpreter's lock is
+    dropped and retaken once, not once a read, and beside sixteen busy
+    request threads each retake waits its turn (PERF.md section 6,
+    PR 36: ten `preadv` calls of a degraded read's gather took 71-73 ms
+    there, a needle's own five intervals the most of a healthy read's
+    14).  Without the library, or for one read, `preadv` a row."""
+    batch = _pread_rows() if len(reads) > 1 else None
+    if batch is None:
+        return [sh.read_into(row, off) == row.nbytes
+                for sh, off, row in reads]
+    got = batch([sh.fileno() for sh, _o, _r in reads],
+                [off for _s, off, _r in reads],
+                [row for _s, _o, row in reads])
+    return [n == row.nbytes for n, (_s, _o, row) in zip(got, reads)]
 
 
 class EcVolume:
@@ -74,6 +126,14 @@ class EcVolume:
         self.shards: dict[int, EcVolumeShard] = {}
         self._ecx = open(base_file_name + ".ecx", "r+b")
         self.ecx_size = os.path.getsize(base_file_name + ".ecx")
+        # The sorted index is searched, and a tombstone written, through
+        # one shared mapping of the file (upstream searches by pread): a
+        # lookup then drops the interpreter's lock for no read, where
+        # ten preads a GET each waited their turn to retake it beside
+        # sixteen busy request threads.  An empty index maps nothing
+        # and is never searched.
+        self._index = mmap.mmap(self._ecx.fileno(), self.ecx_size) \
+            if self.ecx_size else None
         self._ecj_lock = threading.Lock()
         self.load_local_shards()
         # Version detection is lazy: a server holding only parity shards
@@ -144,12 +204,12 @@ class EcVolume:
 
     def _search_ecx(self, needle_id: int):
         lo, hi = 0, self.ecx_size // t.NEEDLE_MAP_ENTRY_SIZE
-        fd = self._ecx.fileno()
+        index = self._index
         while lo < hi:
             mid = (lo + hi) // 2
-            buf = os.pread(fd, t.NEEDLE_MAP_ENTRY_SIZE,
-                           mid * t.NEEDLE_MAP_ENTRY_SIZE)
-            e = t.NeedleMapEntry.from_bytes(buf)
+            e = t.NeedleMapEntry.from_bytes(
+                index[mid * t.NEEDLE_MAP_ENTRY_SIZE:
+                      (mid + 1) * t.NEEDLE_MAP_ENTRY_SIZE])
             if e.key == needle_id:
                 return e, mid
             if e.key < needle_id:
@@ -227,13 +287,15 @@ class EcVolume:
             return
         size_off = (pos * t.NEEDLE_MAP_ENTRY_SIZE + t.NEEDLE_ID_SIZE +
                     t.OFFSET_SIZE)
-        os.pwrite(self._ecx.fileno(),
-                  t.size_to_bytes(t.TOMBSTONE_FILE_SIZE), size_off)
+        tombstone = t.size_to_bytes(t.TOMBSTONE_FILE_SIZE)
+        self._index[size_off:size_off + len(tombstone)] = tombstone
         with self._ecj_lock:
             with open(self.base_file_name + ".ecj", "ab") as f:
                 f.write(t.put_uint64(needle_id))
 
     def close(self) -> None:
+        if self._index is not None:
+            self._index.close()
         self._ecx.close()
         for s in self.shards.values():
             s.close()

@@ -21,7 +21,9 @@ The same kernel serves encode (B = parity bit-matrix) and reconstruction
 
 from __future__ import annotations
 
+import collections
 import functools
+import threading
 import time
 
 import jax
@@ -34,6 +36,7 @@ from ..stats import roofline as _roofline
 from ..stats.metrics import observe_ec_stage
 from ..utils import jaxenv
 from . import crc_fold
+from .erasure import READ_WIDTHS, read_width
 
 
 def _record_roofline(kernel: str, coder, *, out_rows: int,
@@ -293,6 +296,36 @@ def pad_to_block(n: int, block_n: int = BLOCK_N) -> int:
     return -(-n // block_n) * block_n
 
 
+class _Programs:
+    """Which shapes of `apply_bitmatrix_pallas` the read path has
+    compiled, process-wide (the jitted function's cache is): a caller
+    whose shape is not ready compiles it, or waits for the thread that
+    is compiling it (the warm-up at the server's start, another GET),
+    and for no other shape's."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._ready: set = set()
+        self._making: dict = {}
+
+    def ensure(self, key, make, *args) -> None:
+        """`make(*args)` compiles the program `key` names."""
+        if key in self._ready:      # the hot path: one set lookup
+            return
+        with self._lock:
+            gate = self._making.setdefault(key, threading.Lock())
+        with gate:
+            if key not in self._ready:
+                make(*args)
+                self._ready.add(key)
+
+    def count(self) -> int:
+        return len(self._ready)
+
+
+READ_PROGRAMS = _Programs()
+
+
 def _on_tpu() -> bool:
     """Whether JAX resolved to a TPU.  Discovery errors propagate: a
     TPU that fails to initialise must not turn into interpret mode."""
@@ -332,6 +365,14 @@ class PallasCoder:
             plane_major(pb, self.parity_shards, self.data_shards),
             jnp.bfloat16)
         self._crc_consts = None
+        # rows a read's launch gives: the most shards the scheme loses
+        self.read_rows = self.total_shards - self.data_shards
+        self._mats: collections.OrderedDict = collections.OrderedDict()
+        self._mats_lock = threading.Lock()
+
+    # Decode matrices kept on the device, least recently used out
+    # first: a volume's loss pattern is one key a set of wanted rows.
+    _MATS_KEPT = 256
 
     def _apply(self, mat_pm: jax.Array, shards: jax.Array,
                out_rows: int) -> jax.Array:
@@ -422,11 +463,35 @@ class PallasCoder:
         data = jnp.asarray(data, jnp.uint8)
         return jnp.concatenate([data, self.encode(data)], axis=0)
 
-    @functools.lru_cache(maxsize=256)
-    def _decode_mat_pm(self, present: tuple[int, ...], wanted: tuple[int, ...]):
+    def _decode_mat_pm(self, present: tuple[int, ...],
+                       wanted: tuple[int, ...], out_rows: int = 0):
+        """(the plane-major decode matrix of a loss pattern ON THE
+        DEVICE, the survivors it reads), kept after the pattern's first
+        use in a bounded map on the coder: no bit-matrix build and no
+        matrix transfer after it.  With `out_rows` the matrix has that
+        many row planes, the wanted rows first and zeros after them:
+        the read path's one shape for any number of wanted rows."""
+        key = (present, wanted, out_rows)
+        with self._mats_lock:
+            hit = self._mats.get(key)
+            if hit is not None:
+                self._mats.move_to_end(key)
+                return hit
         bmat, used = self.codec.decode_bitmatrix(present, wanted)
-        pm = self._plane_major(np.asarray(bmat), len(wanted), len(used))
-        return jnp.asarray(pm, jnp.bfloat16), used
+        bmat = np.asarray(bmat)
+        rows = out_rows or len(wanted)
+        if rows != len(wanted):
+            full = np.zeros((8 * rows, bmat.shape[1]), bmat.dtype)
+            full[:bmat.shape[0]] = bmat
+            bmat = full
+        pm = self._plane_major(bmat, rows, len(used))
+        # converted on the host: the transfer is the only device work
+        hit = (jax.device_put(np.asarray(pm, dtype=jnp.bfloat16)), used)
+        with self._mats_lock:
+            self._mats[key] = hit
+            while len(self._mats) > self._MATS_KEPT:
+                self._mats.popitem(last=False)
+        return hit
 
     def _check_wanted(self, wanted) -> None:
         bad = [w for w in wanted if not 0 <= w < self.total_shards]
@@ -434,26 +499,100 @@ class PallasCoder:
             raise ValueError(
                 f"shard ids {bad} out of range [0, {self.total_shards})")
 
+    # -- reads: one program a width ------------------------------------
+
+    def _ensure_read_program(self, in_rows: int, width: int) -> None:
+        """The read path's program for `in_rows` survivors of `width`
+        bytes is compiled when this returns: by one call on zeros, as
+        a read makes it, here or on the thread that came first."""
+        READ_PROGRAMS.ensure(
+            (in_rows, self.read_rows, width, self.block_n, self.mm,
+             self.interpret), self._compile_read, in_rows, width)
+
+    def _compile_read(self, in_rows: int, width: int) -> None:
+        mat = jax.device_put(np.zeros(
+            (8 * self.read_rows, 8 * in_rows), dtype=jnp.bfloat16))
+        jax.block_until_ready(self._launch_read(
+            mat, np.zeros((in_rows, width), np.uint8)))
+
+    def _launch_read(self, mat_pm: jax.Array, stacked) -> jax.Array:
+        return apply_bitmatrix_pallas(
+            mat_pm, jnp.asarray(stacked, jnp.uint8), self.read_rows,
+            int(stacked.shape[0]), interpret=self.interpret,
+            block_n=self.block_n, mm=self.mm)
+
+    def warm_reads(self) -> None:
+        """Compile the read path's programs for the scheme's data
+        shards as survivors, every width of READ_WIDTHS, shortest
+        first (a narrower read set, LRC's group of five, compiles at
+        its first read).  Off the request path: the server calls it in
+        the background once it is up (ec/degraded.py)."""
+        for width in READ_WIDTHS:
+            self._ensure_read_program(self.data_shards, width)
+
+    def reconstruct_padded(self, present, stacked, wanted) -> jax.Array:
+        """The read path's call (ec/degraded.py), unfenced: row j of
+        the (len(present), W) uint8 HOST array `stacked` is shard
+        `present[j]`, ids ascending, W one of READ_WIDTHS (what lies
+        past the interval's bytes may be anything: columns do not mix).
+        One transfer and one launch of the width's one program; returns
+        the (read_rows, W) handle whose first len(wanted) rows are the
+        wanted shards, waiting for nothing.  A width whose program is
+        not compiled yet is compiled here, once, whoever else asks."""
+        present, wanted = tuple(present), tuple(wanted)
+        self._check_wanted(wanted)
+        if len(wanted) > self.read_rows:
+            raise ValueError(
+                f"{len(wanted)} rows wanted of a call that gives "
+                f"{self.read_rows}")
+        mat_pm, used = self._decode_mat_pm(present, wanted, self.read_rows)
+        if used != present:
+            # More survivors than the decode reads: it takes its own.
+            stacked = stacked[[present.index(s) for s in used]]
+        return self._read_call(mat_pm, stacked)
+
+    def _read_call(self, mat_pm: jax.Array, stacked) -> jax.Array:
+        self._ensure_read_program(*stacked.shape)
+        if _roofline.ARMED:
+            _roofline.LEDGER.mark_device()
+        return self._launch_read(mat_pm, stacked)
+
     def reconstruct(self, shards: dict[int, jax.Array],
-                    wanted: list[int] | None = None) -> dict[int, jax.Array]:
+                    wanted: list[int] | None = None) -> dict[int, np.ndarray]:
+        """The coders' common entry: `wanted` shards (default: every
+        one that is not in `shards`) from the survivors, as host
+        arrays.  It goes the read path's way whatever its width: the
+        survivors padded to one of READ_WIDTHS in one host array, the
+        kept matrix, the width's one program, wider input in pieces —
+        nothing is compiled for a width.  Fenced, one
+        `reconstruct_kernel` row a launch."""
         present = tuple(sorted(shards))
         if wanted is None:
             wanted = [s for s in range(self.total_shards) if s not in shards]
         self._check_wanted(wanted)
         if not wanted:
             return {}
-        mat_pm, used = self._decode_mat_pm(present, tuple(wanted))
-        stacked = jnp.stack([jnp.asarray(shards[s], jnp.uint8) for s in used])
-        # The callers (degraded reads) stage each result to the host at
-        # once: the fence costs them nothing.
-        t0 = time.perf_counter()
-        rec = jax.block_until_ready(
-            self._apply(mat_pm, stacked, len(wanted)))
-        _observe_call("reconstruct_kernel", self, t0,
-                      out_rows=len(wanted),
-                      in_rows=int(stacked.shape[0]),
-                      n=int(stacked.shape[1]))
-        return {w: rec[i] for i, w in enumerate(wanted)}
+        rows = {s: np.asarray(shards[s], dtype=np.uint8) for s in present}
+        n = len(rows[present[0]])
+        out = {w: np.empty(n, np.uint8) for w in wanted}
+        for g in range(0, len(wanted), self.read_rows):
+            group = tuple(wanted[g:g + self.read_rows])
+            mat_pm, used = self._decode_mat_pm(present, group,
+                                               self.read_rows)
+            for off in range(0, n, READ_WIDTHS[-1]):
+                take = min(n - off, READ_WIDTHS[-1])
+                width = read_width(take)
+                stacked = np.empty((len(used), width), np.uint8)
+                for j, s in enumerate(used):
+                    stacked[j, :take] = rows[s][off:off + take]
+                t0 = time.perf_counter()
+                rec = np.asarray(self._read_call(mat_pm, stacked))
+                _observe_call("reconstruct_kernel", self, t0,
+                              out_rows=self.read_rows,
+                              in_rows=len(used), n=width)
+                for i, w in enumerate(group):
+                    out[w][off:off + take] = rec[i, :take]
+        return out
 
     def reconstruct_unfenced(self, present, stacked, wanted) -> jax.Array:
         """`reconstruct` for the caller that drains later (ec/encoder.py
@@ -464,7 +603,9 @@ class PallasCoder:
         `wanted[i]`, waiting for neither: no per-shard transfer and no
         stack on the device.  Like `encode_unfenced` it records no row,
         and a device error surfaces where the caller collects the
-        handle."""
+        handle.  Its shape is the job's chunk, the same for every call
+        of a job: a read, whose widths are its intervals', goes through
+        `reconstruct_padded`."""
         present, wanted = tuple(present), tuple(wanted)
         self._check_wanted(wanted)
         mat_pm, used = self._decode_mat_pm(present, wanted)

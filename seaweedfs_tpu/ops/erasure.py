@@ -43,6 +43,43 @@ class ErasureCoder(Protocol):
 
 _BACKENDS = ("numpy", "native", "jax", "pallas")
 
+# Widths (bytes a row) of the reconstruct calls a READ makes: a degraded
+# GET, the scrub's block repair, `reconstruct`.  The survivors of an
+# interval are padded on the host to the smallest of these that holds
+# them, and the wanted rows to the coder's `read_rows`, so that a width
+# has ONE program whatever the interval's size and the loss pattern:
+# nothing is compiled per interval.  An interval of the served path
+# never exceeds one small block (1 MiB); a wider call goes in pieces of
+# the last width.  They live here, off JAX, because the server's read
+# path (ec/degraded.py) sizes its host buffers by them with any coder.
+#
+# Steps of four, so a padded call moves at most four times its
+# interval's bytes.  The chip's readings (PR 36, `_stage/a8_widths.py`
+# on a v5e, the rung's own statements on a pooled (10, W) buffer, ms a
+# call at the median, one wanted row / four; PERF.md section 6 has the
+# table).  ONE caller, device: 4 KiB 1.32 / 1.35, 16 KiB 1.40 / 1.47,
+# 64 KiB 1.63 / 1.66, 256 KiB 2.75 / 2.85, 1 MiB 6.29 / 6.77 — flat to
+# 64 KiB (the transfers' and the launch's fixed cost), then the bytes'.
+# `NativeCoder.reconstruct` beside it: 0.29 / 0.30, 0.38 / 0.33, 0.36 /
+# 0.44, 0.49 / 0.82, 1.16 / 2.47: the host coder wins three- to fivefold
+# at every width.  SIXTEEN callers at once, as the upstream shape has
+# them, device: 7.8 / 8.3, 8.0 / 8.3, 8.4 / 8.7, 9.6 / 11.3, 19.2 / 22.2;
+# host coder: 36.6 / 38.7, 36.8 / 38.5, 38.2 / 39.6, 42.0 / 43.3, 46.4 /
+# 53.9 — its call drops and retakes the interpreter's lock a few times
+# and each retake waits its turn, so beside other threads it loses
+# two- to fivefold at every width.  The rung therefore has one path,
+# the coder the process resolved (ROADMAP A8, for this path).
+READ_WIDTHS = (4 << 10, 16 << 10, 64 << 10, 256 << 10, 1 << 20)
+
+
+def read_width(n: int) -> int:
+    """The smallest of READ_WIDTHS that holds `n` bytes, the last for
+    a wider `n` (the caller then goes in pieces)."""
+    for w in READ_WIDTHS:
+        if n <= w:
+            return w
+    return READ_WIDTHS[-1]
+
 
 def _native_available() -> bool:
     from ..utils import native as native_mod

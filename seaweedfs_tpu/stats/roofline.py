@@ -24,7 +24,12 @@ What a process that runs EC kernels counts about them, served at
   clock whenever a profiler session runs;
 - the mark that an EC admin job runs in the process (`ec_job`), and
   two rows among the stage rows for the needle requests the volume
-  server answered beside a job and alone (`note_request`).
+  server answered beside a job and alone (`note_request`);
+- the degraded read's third rung (ec/degraded.py): its stages on the
+  GET's own thread (`read.gather`, `read.dispatch`, `read.drain`), the
+  intervals it rebuilt (`note_intervals`), and two request rows for
+  the GETs of erasure-coded needles that did and did not reach it
+  (`note_read`).
 
 The program computes no share of a roofline: its walls are host fences
 and it knows no peak of the device.  That number is the benchmark's,
@@ -105,7 +110,9 @@ PIPELINE_STAGES = ("stack", "dispatch", "device", "drain")
 # that no sum over `seal.` or `rebuild.` takes them for a main-thread
 # row).  The two `req.` rows are the request plane's,
 # booked by `note_request` on the threads that answer needle requests:
-# what a job in the same process costs them.
+# what a job in the same process costs them.  The `read.` rows are a
+# degraded GET's: its stages on its own thread, many threads at once,
+# so their seconds sum to no wall.
 
 STAGES = {
     "seal.stack_wait":
@@ -176,6 +183,35 @@ STAGES = {
         "threads at once, a shard file always the same thread's (CRC "
         "accumulator feed first on the non-fused path); seconds are "
         "summed over the threads",
+    "read.gather":
+        "a degraded read's third rung (ec/degraded.py), on the thread "
+        "that answers the GET: the planned survivors' byte range read "
+        "straight into the rows of one pooled (survivors, W) host "
+        "buffer, W the width of ops/coder_pallas.py READ_WIDTHS that "
+        "holds the range; one count a launch, bytes = gathered",
+    "read.dispatch":
+        "the same thread: the coder's read call "
+        "(PallasCoder.reconstruct_padded: ONE H2D of the buffer, the "
+        "launch of the width's one program, request of the copy back; "
+        "a host coder reconstructs here); one count a launch, bytes = "
+        "the buffer's",
+    "read.drain":
+        "the same thread: np.asarray of the launch's rows, the wait "
+        "for the round trip, and the slices to the intervals' sizes; "
+        "one count a launch, bytes = rebuilt bytes handed to the GET",
+    "read.interval":
+        "a counter beside read.dispatch, no stage of its own: count = "
+        "lost shard intervals the rung rebuilt (several of one GET in "
+        "one stripe row share a launch), bytes = their sizes, seconds "
+        "= the rung's wall for them, gather to slices",
+    "read.degraded":
+        "request plane: a GET of an erasure-coded needle that reached "
+        "the third rung for any of its intervals; seconds = the whole "
+        "handler, locate to the needle parsed and shaped, bytes = the "
+        "needle's record",
+    "read.healthy":
+        "the same for a GET of an erasure-coded needle whose "
+        "intervals were all read from shards",
     "req.beside_job":
         "request plane, not a job's thread: a needle request (upload, "
         "read or delete on a fid path) the volume server answered "
@@ -200,7 +236,9 @@ STAGES = {
 ANNOTATED_STAGES = frozenset(STAGES) - {
     "seal.stack", "seal.dispatch", "seal.drain",
     "rebuild.dispatch", "rebuild.drain", "beside.rebuild_read",
-    "beside.seal_write", "req.beside_job", "req.alone"}
+    "beside.seal_write", "req.beside_job", "req.alone",
+    "read.dispatch", "read.drain", "read.interval", "read.degraded",
+    "read.healthy"}
 
 kernel_seconds_total = Counter(
     "SeaweedFS_kernel_seconds_total",
@@ -353,14 +391,15 @@ class RooflineLedger:
     # -- stage rows --------------------------------------------------
 
     def add_stage(self, stage: str, codec: str, seconds: float,
-                  nbytes: int) -> None:
+                  nbytes: int, count: int = 1) -> None:
         """One closed stage of the served EC file pipeline (StageClock
-        is the caller).  Totals only: no ring entry."""
+        is the caller), or `count` of a counter row.  Totals only: no
+        ring entry."""
         with self._lock:
             row = self._stages.get((stage, codec))
             if row is None:
                 row = self._stages[(stage, codec)] = [0, 0.0, 0]
-            row[0] += 1
+            row[0] += count
             row[1] += seconds
             row[2] += nbytes
 
@@ -661,6 +700,24 @@ def note_request(t0: float, beside: int, nbytes: int) -> None:
         "", time.perf_counter() - t0, nbytes)
 
 
+def note_read(t0: float, codec: str, degraded: bool,
+              nbytes: int) -> None:
+    """One GET of an erasure-coded needle answered
+    (cluster/volume_server.py `_ec_read`, which checks ARMED before it
+    reads `t0`): under `read.degraded` if any of its intervals went
+    through the third rung, else under `read.healthy`."""
+    LEDGER.add_stage("read.degraded" if degraded else "read.healthy",
+                     codec, time.perf_counter() - t0, nbytes)
+
+
+def note_intervals(codec: str, count: int, seconds: float,
+                   nbytes: int) -> None:
+    """`count` lost intervals of `nbytes` in all that one launch of
+    the third rung rebuilt in `seconds` (ec/degraded.py)."""
+    if ARMED:
+        LEDGER.add_stage("read.interval", codec, seconds, nbytes, count)
+
+
 def _device_memory_stats() -> list[dict]:
     """jax.local_devices() with memory stats where the backend reports
     them; empty for a process that has run no kernel: asking
@@ -691,8 +748,9 @@ def debug_doc(node: str, role: str) -> dict:
     the drains of the seals and of the rebuilds found the oldest chunk
     in flight (SEAL_INFLIGHT, REBUILD_INFLIGHT: `ready` or `waited`)
     and how the seals' hand-overs found the writer threads
-    (SEAL_WRITER)."""
-    from ..ec import encoder
+    (SEAL_WRITER), and which way a GET's shard reads go (`ec_reads`:
+    ec/volume.py `read_many_path`)."""
+    from ..ec import encoder, volume
     return {"node": node, "role": role, "armed": ARMED,
             "kernels": LEDGER.kernel_table() + LEDGER.stage_table(),
             "recent": LEDGER.recent(16),
@@ -703,4 +761,5 @@ def debug_doc(node: str, role: str) -> dict:
             "seal_buffers": encoder.CHUNK_POOL.counts(),
             "seal_inflight": encoder.SEAL_INFLIGHT.counts(),
             "seal_writer": encoder.SEAL_WRITER.counts(),
-            "rebuild_inflight": encoder.REBUILD_INFLIGHT.counts()}
+            "rebuild_inflight": encoder.REBUILD_INFLIGHT.counts(),
+            "ec_reads": volume.read_many_path()}

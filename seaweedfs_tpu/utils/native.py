@@ -95,3 +95,32 @@ def gf_encode_fn(lib: ctypes.CDLL):
                    ctypes.POINTER(ctypes.c_void_p),
                    ctypes.POINTER(ctypes.c_void_p), ctypes.c_size_t]
     return fn
+
+
+def pread_rows_fn(lib: ctypes.CDLL):
+    """Wrap void sw_pread_rows(int n, const int* fds, const int64* offs,
+    uint8* const* dsts, const int64* lens, int64* got): n reads, each
+    of lens[i] bytes of fds[i] from offs[i] into a buffer of its own,
+    behind one release of the interpreter's lock (ctypes drops it
+    around a CDLL call, once, where n `os.preadv` calls drop and retake
+    it n times — beside sixteen busy request threads each retake waits
+    its turn)."""
+    fn = lib.sw_pread_rows
+    fn.restype = None
+    fn.argtypes = [ctypes.c_int, ctypes.POINTER(ctypes.c_int),
+                   ctypes.POINTER(ctypes.c_int64),
+                   ctypes.POINTER(ctypes.c_void_p),
+                   ctypes.POINTER(ctypes.c_int64),
+                   ctypes.POINTER(ctypes.c_int64)]
+
+    def pread_rows(fds: list[int], offs: list[int], rows) -> list[int]:
+        """Fill each of `rows` (writable numpy uint8 vectors) from its
+        file at its offset; the bytes each read got."""
+        n = len(fds)
+        got = (ctypes.c_int64 * n)()
+        fn(n, (ctypes.c_int * n)(*fds), (ctypes.c_int64 * n)(*offs),
+           (ctypes.c_void_p * n)(*[r.ctypes.data for r in rows]),
+           (ctypes.c_int64 * n)(*[r.nbytes for r in rows]), got)
+        return list(got)
+
+    return pread_rows

@@ -1,5 +1,7 @@
 """One seal and one rebuild of a small volume through the volume
-server's own admin handlers, in process, for tests/test_stage_clock.py.
+server's own admin handlers, and a GET of its needle while a data
+shard is gone and again when it is back, in process, for
+tests/test_stage_clock.py.
 
 `drive(tmp)` returns what the operator surfaces said afterwards.  Run as
 a script, the same drive happens under `jax.profiler` on the CPU
@@ -52,8 +54,12 @@ def drive(tmp: str, payload_bytes: int = 3 << 20) -> dict:
         admin("ec/mount")
         admin("delete_volume")
         admin("ec/delete_shards", shards=LOST)
+        # a GET of the needle while shard 3 is gone takes the degraded
+        # read's third rung; after the rebuild the same GET is healthy
+        degraded = bytes(rpc.call(f"{url}/{a['fid']}"))
         rebuilt = admin("ec/rebuild")["rebuilt_shards"]
         admin("ec/mount")
+        assert bytes(rpc.call(f"{url}/{a['fid']}")) == degraded
 
         finish = {t: JOURNAL.snapshot(type_=t, limit=1)[0]
                   for t in ("ec.encode.finish", "ec.rebuild.finish")}

@@ -194,6 +194,85 @@ def test_ec_delete_journal(ec_base, tmp_path):
     assert entries[-1].size == t.TOMBSTONE_FILE_SIZE
 
 
+def test_the_index_is_one_mapping_searched_and_written(ec_base, tmp_path):
+    """The sorted index has ONE path whatever its size: the mapping of
+    the `.ecx` that a lookup searches is the one a delete writes its
+    tombstone through, so the file, a second reader of it and a volume
+    mounted later all see the delete at once."""
+    import shutil
+    base, payloads = ec_base
+    work = str(tmp_path / "1")
+    for ext in [".ecx"] + [to_ext(i) for i in range(TOTAL_SHARDS)]:
+        shutil.copyfile(base + ext, work + ext)
+    before = open(work + ".ecx", "rb").read()
+    ev, other = _open_ec(work), _open_ec(work)
+    try:
+        for key in (1, 60, 120):            # first, middle, last entry
+            assert ev.read_needle(key).data == payloads[key]
+        ev.delete_needle(60)
+        ev.delete_needle(999)               # not there: nothing written
+        now = open(work + ".ecx", "rb").read()
+        assert len(now) == len(before)
+        changed = [i for i in range(len(now)) if now[i] != before[i]]
+        pos = 59 * t.NEEDLE_MAP_ENTRY_SIZE + t.NEEDLE_ID_SIZE + t.OFFSET_SIZE
+        assert changed and set(changed) <= set(range(pos, pos + t.SIZE_SIZE))
+        for vol in (ev, other):
+            with pytest.raises(NeedleNotFound):
+                vol.find_needle_from_ecx(60)
+            assert vol.read_needle(59).data == payloads[59]
+            assert vol.read_needle(61).data == payloads[61]
+    finally:
+        ev.close()
+        other.close()
+    later = _open_ec(work)
+    try:
+        with pytest.raises(NeedleNotFound):
+            later.find_needle_from_ecx(60)
+        assert later.read_needle(120).data == payloads[120]
+    finally:
+        later.close()
+
+
+def test_an_index_written_anew_leaves_a_mounted_volume_its_own(ec_base,
+                                                               tmp_path):
+    """`write_sorted_file_from_idx` moves the new `.ecx` over the old
+    name: the volume that mapped the old file keeps searching it (a
+    file cut to nothing under a mapping would be a SIGBUS)."""
+    import shutil
+    base, payloads = ec_base
+    work = str(tmp_path / "1")
+    for ext in [".idx", ".ecx"] + [to_ext(i) for i in range(TOTAL_SHARDS)]:
+        shutil.copyfile(base + ext, work + ext)
+    ev = _open_ec(work)
+    try:
+        old = os.stat(work + ".ecx").st_ino
+        write_sorted_file_from_idx(work)
+        assert os.stat(work + ".ecx").st_ino != old
+        assert not os.path.exists(work + ".ecx.tmp")
+        assert open(work + ".ecx", "rb").read() == \
+            open(base + ".ecx", "rb").read()
+        assert ev.read_needle(77).data == payloads[77]
+    finally:
+        ev.close()
+
+
+def test_an_empty_index_mounts_and_finds_nothing(ec_base, tmp_path):
+    import shutil
+    base, _ = ec_base
+    work = str(tmp_path / "1")
+    for i in range(TOTAL_SHARDS):
+        shutil.copyfile(base + to_ext(i), work + to_ext(i))
+    open(work + ".ecx", "wb").close()
+    ev = _open_ec(work, version=3)
+    try:
+        with pytest.raises(NeedleNotFound):
+            ev.find_needle_from_ecx(1)
+        ev.delete_needle(1)                 # no entry, no journal line
+        assert not os.path.exists(work + ".ecj")
+    finally:
+        ev.close()
+
+
 def test_locate_data_boundaries():
     """Port of TestLocateData (ec_test.go:189-200)."""
     intervals = locate_data(LARGE, SMALL, DATA_SHARDS * LARGE + 1,

@@ -34,7 +34,9 @@ pytestmark = pytest.mark.roofline
 BLOCK = SMALL_BLOCK_SIZE
 COUNTED_ONLY = {"seal.stack", "seal.dispatch", "seal.drain",
                 "rebuild.dispatch", "rebuild.drain", "beside.rebuild_read",
-                "beside.seal_write", "req.beside_job", "req.alone"}
+                "beside.seal_write", "req.beside_job", "req.alone",
+                "read.dispatch", "read.drain", "read.interval",
+                "read.degraded", "read.healthy"}
 # the read-ahead and writer threads' rows: beside the main thread, in
 # no sum
 BESIDE = {"seal.stack", "beside.rebuild_read", "beside.seal_write"}
@@ -288,6 +290,10 @@ def test_debug_device_serves_seal_inflight(tmp_path, monkeypatch):
     writes = next(r for r in got["kernels"]
                   if r["kernel"] == "beside.seal_write")
     assert writes["count"] == 14 * drains["count"]
+    # ... and which way a GET's shard reads go in this process
+    from seaweedfs_tpu.ec.volume import read_many_path
+    assert got["ec_reads"] == read_many_path()
+    assert got["ec_reads"] in ("sw_pread_rows", "preadv")
 
 
 def test_debug_device_serves_rebuild_inflight(tmp_path, monkeypatch):

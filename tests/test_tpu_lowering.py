@@ -6,7 +6,8 @@ on the CPU: a kernel whose block shapes or ops the TPU lowering refuses
 once did) fails HERE, in tier-1, before any chip time is spent.  Every
 Pallas kernel the default served path can reach is exported at the
 served shapes: the (10, 4 MiB) encode chunk, 1-4 wanted rows of decode,
-the 5-row LRC in-group decode — bf16 and int8, default block_n.  What
+the 5-row LRC in-group decode — bf16 and int8, default block_n — and
+the degraded read's programs at every width of READ_WIDTHS.  What
 Mosaic then makes of the module only the chip can say: chip_smoke.py.
 """
 
@@ -18,7 +19,7 @@ import pytest
 from jax import export
 
 from seaweedfs_tpu.ec.encoder import DEFAULT_CHUNK
-from seaweedfs_tpu.ops.coder_pallas import (BLOCK_N,
+from seaweedfs_tpu.ops.coder_pallas import (BLOCK_N, READ_WIDTHS,
                                             apply_bitmatrix_crc_pallas,
                                             apply_bitmatrix_pallas,
                                             crc_kernel_consts)
@@ -41,6 +42,22 @@ def test_plain_kernel_lowers_for_tpu(out_rows, in_rows, mm):
         S((in_rows, DEFAULT_CHUNK), jnp.uint8))
     assert exp.platforms == ("tpu",)
     assert [a.shape for a in exp.out_avals] == [(out_rows, DEFAULT_CHUNK)]
+
+
+@pytest.mark.parametrize("in_rows", [10, 5])
+@pytest.mark.parametrize("width", READ_WIDTHS)
+def test_read_programs_lower_for_tpu(width, in_rows):
+    """The degraded read's one program a width (four row planes out,
+    whatever the loss pattern), for RS's ten survivors and the LRC
+    group's five, as the chip's coder asks for it (int8)."""
+    def fn(bmat, shards):
+        return apply_bitmatrix_pallas(bmat, shards, 4, in_rows,
+                                      interpret=False, block_n=BLOCK_N,
+                                      mm="int8")
+    exp = export.export(jax.jit(fn), platforms=["tpu"])(
+        S((32, 8 * in_rows), jnp.bfloat16), S((in_rows, width), jnp.uint8))
+    assert exp.platforms == ("tpu",)
+    assert [a.shape for a in exp.out_avals] == [(4, width)]
 
 
 @pytest.mark.parametrize("mm", ["bf16", "int8"])
