@@ -146,18 +146,21 @@ def bench_tpu(dev) -> dict | None:
 
     from seaweedfs_tpu.ops import rs_bitmatrix
     from seaweedfs_tpu.ops.coder_jax import plane_major
-    from seaweedfs_tpu.ops.coder_pallas import apply_bitmatrix_pallas
+    from seaweedfs_tpu.ops.coder_pallas import (LANES, WORD_ROW,
+                                                apply_bitmatrix_pallas,
+                                                tile_matrix)
+    from seaweedfs_tpu.ops.erasure import host_rows
 
-    enc_pm = jnp.asarray(plane_major(
-        rs_bitmatrix.parity_bitmatrix(10, 14), 4, 10), jnp.float32)
+    enc_mat = jnp.asarray(tile_matrix(plane_major(
+        rs_bitmatrix.parity_bitmatrix(10, 14), 4, 10)))
     present = tuple(i for i in range(14) if i not in LOST)
     dec_b, _used = rs_bitmatrix.decode_bitmatrix(10, 14, present, LOST)
-    dec_pm = jnp.asarray(plane_major(np.asarray(dec_b), 4, 10), jnp.float32)
+    dec_mat = jnp.asarray(tile_matrix(plane_major(np.asarray(dec_b), 4, 10)))
 
-    # On-device data (bytes as uint8).
+    # On-device data in the kernels' transfer layout: (10, N / 512,
+    # 128) uint32 words, the row-major bytes of a (10, N) chunk.
     key = jax.random.PRNGKey(0)
-    data = jax.random.randint(key, (10, N), 0, 256, dtype=jnp.int32
-                              ).astype(jnp.uint8)
+    data = jax.random.bits(key, (10, N // WORD_ROW, LANES), jnp.uint32)
     jax.block_until_ready(data)
     timed = _make_timed()
     limit = roofline_limit_mbps(peaks)
@@ -175,7 +178,7 @@ def bench_tpu(dev) -> dict | None:
     for block_n in (8192, 16384, 32768, 65536):
         for mm in ("bf16", "int8"):
             try:
-                dt = timed(apply_bitmatrix_pallas, enc_pm, data, 4, 10,
+                dt = timed(apply_bitmatrix_pallas, enc_mat, data, 4, 10,
                            block_n=block_n, mm=mm, iters=3)
                 mbps = checked_mbps(dt, f"tune {block_n}/{mm}")
                 if mbps is None:
@@ -192,10 +195,10 @@ def bench_tpu(dev) -> dict | None:
     log(f"selected block_n={block_n} mm={mm} "
         f"(roofline {limit:.0f} MB/s)")
 
-    t_enc = timed(apply_bitmatrix_pallas, enc_pm, data, 4, 10,
+    t_enc = timed(apply_bitmatrix_pallas, enc_mat, data, 4, 10,
                   block_n=block_n, mm=mm)
     # Reconstruction: same kernel, decode matrix over the 10 survivors.
-    t_dec = timed(apply_bitmatrix_pallas, dec_pm, data, 4, 10,
+    t_dec = timed(apply_bitmatrix_pallas, dec_mat, data, 4, 10,
                   block_n=block_n, mm=mm)
     enc_mbps = checked_mbps(t_enc, "encode")
     dec_mbps = checked_mbps(t_dec, "reconstruct")
@@ -204,10 +207,10 @@ def bench_tpu(dev) -> dict | None:
     rt_mbps = data.nbytes / (t_enc + t_dec) / 1e6
     # Correctness spot check against the oracle on a slice.
     from seaweedfs_tpu.ops.coder_numpy import NumpyCoder
-    sl = np.asarray(data[:, :65536])
-    got = np.asarray(apply_bitmatrix_pallas(
-        enc_pm, jnp.asarray(sl), 4, 10, block_n=block_n, mm=mm))
-    ok = np.array_equal(got, NumpyCoder(10, 4).encode(sl))
+    sl = np.asarray(data[:, :65536 // WORD_ROW])
+    got = host_rows(apply_bitmatrix_pallas(
+        enc_mat, jnp.asarray(sl), 4, 10, block_n=block_n, mm=mm))
+    ok = np.array_equal(got, NumpyCoder(10, 4).encode(host_rows(sl)))
     log(f"encode {enc_mbps:.0f} MB/s, reconstruct {dec_mbps:.0f} MB/s, "
         f"round-trip {rt_mbps:.0f} MB/s, correct={ok}")
     if not ok:

@@ -46,7 +46,7 @@ from typing import Callable, NamedTuple
 import numpy as np
 
 from ..events import emit as emit_event
-from ..ops.erasure import READ_WIDTHS, read_width
+from ..ops.erasure import READ_WIDTHS, host_rows, read_width
 from ..stats import roofline as _roofline
 from ..stats.metrics import ec_repair_read_bytes_total, observe_ec_stage
 from ..trace import span as trace_span
@@ -242,7 +242,7 @@ class DegradedReader:
                     wanted=list(wanted))
         with clock("read.drain") as st:
             if padded is not None:
-                rows = np.asarray(handle)
+                rows = host_rows(handle)
             else:
                 rows = [np.asarray(rec[s]) for s in wanted]
             # Execution-fenced: what is back on the host was computed.

@@ -30,7 +30,7 @@ from .integrity import BlockCrcAccumulator, ShardChecksums, ecc_lock
 from .volume_info import ec_codec_name, update_volume_info
 from ..codecs import get_codec
 from ..fault import registry as _fault
-from ..ops.erasure import ErasureCoder, new_coder
+from ..ops.erasure import ErasureCoder, host_rows, new_coder
 from ..stats.metrics import ec_repair_read_bytes_total
 from ..stats.roofline import StageClock
 from ..storage.needle_map import MemDb
@@ -364,7 +364,8 @@ def _read_chunk(fd: int, width: int, reads,
     given, else in a fresh array that belongs to whoever gets the
     chunk.  Either way the chunk is C-contiguous — a narrower last
     chunk is viewed out of the front of `flat`, not sliced out of wider
-    rows — so `jnp.asarray` does not copy it first.  The reads fill
+    rows — so the device coder sends a view of it, not a copy
+    (ops/coder_pallas.py `host_words`).  The reads fill
     disjoint views; `each` (`map`, or an executor's) says whether they
     go out one after the other or side by side."""
     if flat is None:
@@ -704,7 +705,7 @@ def _pipelined_encode(fd: int, spans, coder: ErasureCoder, outputs,
     def flush(handles, release, hand) -> None:
         with clock("seal.drain") as st:
             SEAL_INFLIGHT.note(all(_is_ready(h) for h in handles))
-            parity = np.asarray(handles[0])
+            parity = host_rows(handles[0])
             st.add_bytes(parity.nbytes)
             if fused:
                 crcs = np.asarray(handles[1])
@@ -832,7 +833,7 @@ def rebuild_ec_files(base_file_name: str,
             REBUILD_INFLIGHT.note(all(_is_ready(h) for h in handles))
             # one (rebuilt, n) array, or a host coder's row per shard
             rows = [row for h in handles
-                    for row in np.atleast_2d(np.asarray(h))]
+                    for row in np.atleast_2d(host_rows(h))]
             nbytes = sum(row.nbytes for row in rows)
             st.add_bytes(nbytes)
         release()

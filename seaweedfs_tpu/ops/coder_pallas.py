@@ -4,16 +4,26 @@ The XLA path (`coder_jax.py`) materializes the unpacked bit planes (an 8x
 expansion of the data) in HBM between the unpack and the matmul.  This
 kernel fuses the whole pipeline per tile in VMEM:
 
-    HBM --(k,BN) bytes--> VMEM
-        unpack to (8k,BN) bit planes            (VPU shifts)
-        (8r,8k) @ (8k,BN) bf16 matmul, f32 acc  (MXU)
-        mod-2 + pack to (r,BN) bytes            (VPU)
-    VMEM --(r,BN) bytes--> HBM
+    HBM --(k, 8, 128) words--> VMEM
+        unpack to (32 * 8k, 128) bit planes      (VPU shifts)
+        (64r, 64k) @ (64k, 512) matmul           (MXU)
+        mod-2 + pack to (r, 8, 128) words        (VPU)
+    VMEM --(r, 8, 128) words--> HBM
 
 so HBM traffic stays at bytes-in + bytes-out while the GF math runs at MXU
 rate.  This is the TPU replacement for klauspost/reedsolomon's AVX2 galois
 kernels (reference hot loop: weed/storage/erasure_coding/ec_encoder.go:162,
 store_ec.go:322).
+
+The bytes cross as they lie: a `(rows, n)` uint8 host chunk is sent as
+the `(rows, n / 512, 128)` uint32 view of its memory, whose (8, 128)
+tiled device layout IS the host's row-major order, so the runtime
+relayouts nothing (a uint8 array is padded to 32-row tiles on the host,
+and back); results come back so and are viewed as bytes (`host_rows`).
+Coding is column-wise, so no byte moves in the kernel: bit 8b + s of a
+word is bit s of its byte lane b, and each byte lane and each of a
+tile's eight sublane rows is a set of columns of its own
+(`tile_matrix`).
 
 The same kernel serves encode (B = parity bit-matrix) and reconstruction
 (B = decode bit-matrix for the survivor set) — only the matrix changes.
@@ -36,7 +46,7 @@ from ..stats import roofline as _roofline
 from ..stats.metrics import observe_ec_stage
 from ..utils import jaxenv
 from . import crc_fold
-from .erasure import READ_WIDTHS, read_width
+from .erasure import CODER_LAYOUT, READ_WIDTHS, host_rows, read_width
 
 
 def _record_roofline(kernel: str, coder, *, out_rows: int,
@@ -68,129 +78,173 @@ def _observe_call(kernel: str, coder, t0: float, *, out_rows: int,
                          measured_bytes=(in_rows + out_rows) * n)
 
 
-# Lane-dimension tile: one grid step processes k x BLOCK_N bytes.
-# 8k x BLOCK_N bf16 bit planes = 80*4096*2B = 640KB VMEM for RS(10,4) —
-# comfortably inside VMEM while long enough to amortize the small matmul M.
+# Bytes of each row one grid step processes: one (8, 128) tile of words.
 BLOCK_N = 4096
+# The transfer layout: a row is n / WORD_ROW sublane rows of LANES words.
+LANES = 128
+WORD_ROW = 4 * LANES
+# Sublane rows of one (8, 128) 32-bit tile.
+_SUB = 8
 
 
-def _rs_kernel(b_ref, d_ref, o_ref, *, out_rows: int, in_rows: int,
-               mm_dtype):
-    """One tile: bytes (in_rows, BN) -> bytes (out_rows, BN)."""
-    x = d_ref[:].astype(jnp.int32)
-    # Plane-major unpack: row s*k + j is bit s of shard j. Stays 2D.
-    bits = jnp.concatenate(
-        [(x >> s) & 1 for s in range(8)], axis=0).astype(mm_dtype)
+def host_words(rows) -> np.ndarray:
+    """The `(rows, n)` uint8 host array as `(rows, n / WORD_ROW, LANES)`
+    uint32 words, a view of its memory; a width that is no whole number
+    of blocks is copied into a padded array first (counted)."""
+    rows = np.asarray(rows, dtype=np.uint8)
+    pad = -rows.shape[1] % BLOCK_N
+    if pad:
+        rows = np.pad(rows, ((0, 0), (0, pad)))
+    CODER_LAYOUT.note_in(rows.nbytes if pad else 0)
+    return rows.view(np.uint32).reshape(rows.shape[0], -1, LANES)
+
+
+def _planes(x: jax.Array, mm_dtype) -> jax.Array:
+    """(R, 128) int32 words -> (8R, 512) bit planes: row s*R + i holds
+    bit s of row i's bytes, byte lane b in lanes 128b..128b+127."""
+    return jnp.concatenate(
+        [jnp.concatenate([(x >> (8 * b + s)) & 1 for s in range(8)],
+                         axis=0) for b in range(4)],
+        axis=1).astype(mm_dtype)
+
+
+def _parity(b_ref, x: jax.Array, out_rows: int, mm_dtype) -> jax.Array:
+    """One tile: (in_rows * 8, 128) int32 words, row 8j + t sublane t of
+    row j -> (out_rows * 8, 128) words of the product."""
     acc_t = jnp.float32 if mm_dtype == jnp.bfloat16 else jnp.int32
-    acc = jnp.dot(b_ref[:], bits, preferred_element_type=acc_t)
+    acc = jnp.dot(b_ref[...], _planes(x, mm_dtype),
+                  preferred_element_type=acc_t)
     pbits = acc.astype(jnp.int32) & 1  # sums <= 8k < 2^24: exact either way
-    out = pbits[0:out_rows]
+    m = out_rows * _SUB
+    byte = pbits[0:m]
     for s in range(1, 8):
-        out = out | (pbits[s * out_rows:(s + 1) * out_rows] << s)
-    o_ref[:] = out.astype(jnp.uint8)
+        byte = byte | (pbits[s * m:(s + 1) * m] << s)
+    out = byte[:, 0:LANES]
+    for b in range(1, 4):
+        out = out | (byte[:, b * LANES:(b + 1) * LANES] << (8 * b))
+    return out
+
+
+def _words_of(block: jax.Array) -> jax.Array:
+    """A (rows, sub, 128) uint32 block as (rows * sub, 128) int32."""
+    rows, sub, lanes = block.shape
+    return pltpu.bitcast(block.reshape(rows * sub, lanes), jnp.int32)
+
+
+def _rs_kernel(b_ref, d_ref, o_ref, *, out_rows: int, mm_dtype) -> None:
+    """One block: words (in_rows, sub, 128) -> words (out_rows, sub,
+    128), one (8, 128) tile of every row at a time."""
+    for t in range(0, d_ref.shape[1], _SUB):
+        out = _parity(b_ref, _words_of(d_ref[:, t:t + _SUB, :]),
+                      out_rows, mm_dtype)
+        o_ref[:, t:t + _SUB, :] = pltpu.bitcast(out, jnp.uint32) \
+            .reshape(out_rows, _SUB, LANES)
+
+
+_MM_DTYPES = {"bf16": jnp.bfloat16, "int8": jnp.int8}
+
+
+def tile_matrix(bmat, mm: str = "bf16") -> np.ndarray:
+    """The kernels' matrix, made once on the host: the (8r, 8k)
+    plane-major 0/1 bit matrix -> (64r, 64k) in the matmul's dtype, row
+    8i + t and column 8j + t holding bmat[i, j], so one matmul codes
+    each of a tile's eight sublane rows (the planes' row 8j + t,
+    `_planes`) apart from the others."""
+    return np.kron(np.asarray(bmat, np.float32),
+                   np.eye(_SUB, dtype=np.float32)).astype(_MM_DTYPES[mm])
+
+
+def _block_spec(rows: int, block_n: int) -> pl.BlockSpec:
+    return pl.BlockSpec((rows, block_n // WORD_ROW, LANES),
+                        lambda i: (0, i, 0), memory_space=pltpu.VMEM)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("out_rows", "in_rows", "interpret",
                                     "block_n", "mm"))
-def apply_bitmatrix_pallas(bmat_pm: jax.Array, shards: jax.Array,
+def apply_bitmatrix_pallas(bmat: jax.Array, words: jax.Array,
                            out_rows: int, in_rows: int,
                            interpret: bool = False,
                            block_n: int = BLOCK_N,
                            mm: str = "bf16") -> jax.Array:
-    """(8*out_rows, 8*in_rows) plane-major bit matrix x (in_rows, n) bytes.
-
-    n must be a multiple of block_n (the file pipeline's buffers are);
-    `pad_to_block` below handles ragged tails.
-    """
-    n = shards.shape[1]
-    grid = (n // block_n,)
-    mm_dtype = jnp.bfloat16 if mm == "bf16" else jnp.int8
+    """`tile_matrix` of an (8*out_rows, 8*in_rows) plane-major bit
+    matrix x (in_rows, n) bytes, both ways in the transfer layout:
+    `words` (in_rows, n / WORD_ROW, LANES) uint32 -> (out_rows, n /
+    WORD_ROW, LANES) uint32.  n must be a multiple of block_n, itself
+    of BLOCK_N (`host_words` pads a host array)."""
+    subs = words.shape[1]
+    mm_dtype = _MM_DTYPES[mm]
     kernel = functools.partial(_rs_kernel, out_rows=out_rows,
-                               in_rows=in_rows, mm_dtype=mm_dtype)
+                               mm_dtype=mm_dtype)
+    n = subs * WORD_ROW
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(n // block_n,),
         in_specs=[
-            pl.BlockSpec((8 * out_rows, 8 * in_rows), lambda i: (0, 0),
+            pl.BlockSpec((64 * out_rows, 64 * in_rows), lambda i: (0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((in_rows, block_n), lambda i: (0, i),
-                         memory_space=pltpu.VMEM),
+            _block_spec(in_rows, block_n),
         ],
-        out_specs=pl.BlockSpec((out_rows, block_n), lambda i: (0, i),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((out_rows, n), jnp.uint8),
+        out_specs=_block_spec(out_rows, block_n),
+        out_shape=jax.ShapeDtypeStruct((out_rows, subs, LANES), jnp.uint32),
         cost_estimate=pl.CostEstimate(
             flops=2 * 8 * out_rows * 8 * in_rows * n,
             bytes_accessed=(in_rows + out_rows) * n,
             transcendentals=0,
         ),
         interpret=interpret,
-    )(bmat_pm.astype(mm_dtype), shards)
+    )(bmat.astype(mm_dtype), words)
 
 
-# Rows per bit plane in the CRC fold: shard rows (data then parity) are
-# zero-padded to a multiple of this so every per-plane slice of the
-# fold sits on a whole (16, 128) bf16 tile.
-_CRC_ROW_ALIGN = 16
+# Shard rows (data then parity) of the CRC fold are zero-padded to a
+# multiple of this, so every bit plane's slice of the fold's (rows * 8,
+# 128) words sits on whole (16, 128) bf16 tiles.
+_CRC_ROW_ALIGN = 2
 # CRC constants are zero-padded from 32 to one full lane tile: the MXU
 # spends a whole 128-wide pass on a 32-wide operand either way, and
 # full tiles keep every matmul and store in the kernel aligned.
 _CRC_LANES = 128
 
 
-def _rs_crc_kernel(b_ref, d_ref, w0_ref, pl_ref, pm_ref, o_ref, c_ref, *,
-                   out_rows: int, in_rows: int, mm_dtype, tpb: int):
-    """One tile of the CRC-fused pipeline: bytes (in_rows, BN) ->
-    parity bytes (out_rows, BN), PLUS this tile's contribution to the
-    CRC32-C of the `.ecc` block it lies in, for every shard row
-    (in_rows data rows, then out_rows parity rows), accumulated into
-    c_ref across the block's `tpb` tiles (ops/crc_fold.py has the
-    algebra).  pm_ref is the tile-position-in-block shift matrix,
-    selected by the grid index mod tpb, which makes the per-tile
-    partials plain sums.  c_ref holds 0/1 bit COUNTS (rows x 32 live
+def _rs_crc_kernel(b_ref, d_ref, w_ref, pm_ref, o_ref, c_ref, *,
+                   out_rows: int, mm_dtype, tpb: int):
+    """One block of the CRC-fused pipeline: words (in_rows, sub, 128) ->
+    parity words (out_rows, sub, 128), PLUS every sublane row's
+    contribution to the CRC32-C of the `.ecc` block it lies in, for
+    every shard row (data rows, then parity rows), accumulated into
+    c_ref across the `.ecc` block's `tpb` grid steps (ops/crc_fold.py
+    has the algebra).  A sublane row is 512 bytes of one shard row:
+    w_ref holds the 512-byte weights of every (bit, byte lane, lane)
+    with the bit-plane fold applied, so one matmul gives each sublane
+    row's partial as if it ended its tile; pm_ref is the tile-in-block
+    shift matrix, selected by the grid index mod tpb; the sublane
+    row's own place in its tile is applied by the caller.  c_ref holds
+    0/1 bit COUNTS (row 8j + t: sublane t of shard row j; 32 live
     lanes); the caller reduces them mod 2 and packs the words."""
-    x = d_ref[:].astype(jnp.int32)
-    bits = jnp.concatenate(
-        [(x >> s) & 1 for s in range(8)], axis=0).astype(mm_dtype)
-    acc_t = jnp.float32 if mm_dtype == jnp.bfloat16 else jnp.int32
-    acc = jnp.dot(b_ref[:], bits, preferred_element_type=acc_t)
-    pbits = acc.astype(jnp.int32) & 1
-    out = pbits[0:out_rows]
-    for s in range(1, 8):
-        out = out | (pbits[s * out_rows:(s + 1) * out_rows] << s)
-    o_ref[:] = out.astype(jnp.uint8)
+    _rs_kernel(b_ref, d_ref, o_ref, out_rows=out_rows, mm_dtype=mm_dtype)
 
-    # CRC fold, always bf16 x bf16 -> f32 (exact: operands are 0/1 and
-    # every sum is <= BN < 2^24).  Planes are re-unpacked from the
-    # stacked (data, parity, zero pad) rows so each plane is `rpad`
-    # rows — the parity matmul's planes are in_rows apart, which no
-    # tile boundary divides.
-    rows = in_rows + out_rows
-    rpad = c_ref.shape[0]
+    # CRC fold, bf16 x bf16 -> f32 (exact: operands are 0/1 and every
+    # sum is <= 8 * 512 < 2^24).
+    x, out = _words_of(d_ref[...]), _words_of(o_ref[...])
+    m = c_ref.shape[0]
     stacked = [x, out]
-    if rpad > rows:
-        stacked.append(jnp.zeros((rpad - rows, x.shape[1]), jnp.int32))
+    if m > x.shape[0] + out.shape[0]:
+        stacked.append(jnp.zeros((m - x.shape[0] - out.shape[0], LANES),
+                                 jnp.int32))
     y = jnp.concatenate(stacked, axis=0)
     planes = jnp.concatenate(
-        [(y >> s) & 1 for s in range(8)], axis=0).astype(jnp.bfloat16)
-    u = jnp.dot(planes, w0_ref[:], preferred_element_type=jnp.float32)
-    ub = u.astype(jnp.int32) & 1            # (8 * rpad, 128)
-    fold = jnp.zeros((rpad, _CRC_LANES), jnp.float32)
-    for s in range(8):
-        fold = fold + jnp.dot(
-            ub[s * rpad:(s + 1) * rpad].astype(jnp.bfloat16),
-            pl_ref[s * _CRC_LANES:(s + 1) * _CRC_LANES],
-            preferred_element_type=jnp.float32)
-    vb = (fold.astype(jnp.int32) & 1).astype(jnp.bfloat16)
-    sh = jnp.dot(vb, pm_ref[:], preferred_element_type=jnp.float32) \
+        [(y >> (8 * b + s)) & 1 for s in range(8) for b in range(4)],
+        axis=1).astype(jnp.bfloat16)
+    u = jnp.dot(planes, w_ref[...], preferred_element_type=jnp.float32)
+    ub = (u.astype(jnp.int32) & 1).astype(jnp.bfloat16)
+    sh = jnp.dot(ub, pm_ref[...], preferred_element_type=jnp.float32) \
         .astype(jnp.int32) & 1
 
     @pl.when(pl.program_id(0) % tpb == 0)
     def _():
-        c_ref[:] = jnp.zeros_like(c_ref)
+        c_ref[...] = jnp.zeros_like(c_ref)
 
-    c_ref[:] = c_ref[:] + sh
+    c_ref[...] = c_ref[...] + sh
 
 
 def _pad_crc_const(mat: np.ndarray) -> np.ndarray:
@@ -204,77 +258,76 @@ def _pad_crc_const(mat: np.ndarray) -> np.ndarray:
 
 def crc_kernel_consts(block_n: int, crc_block: int = crc_fold.BLOCK):
     """Device constants for `apply_bitmatrix_crc_pallas` at one
-    (block_n, crc_block) geometry: (w0 (block_n, 128), planes_t
-    (8*128, 128), posmats_t (tpb*128, 128)), bf16."""
+    (block_n, crc_block) geometry: (w (32*128, 128), posmats_t
+    (tpb*128, 128)), bf16.  Row 512s + 128b + l of w is the weight of
+    bit s of byte 4l + b of a 512-byte sublane row — the word layout's
+    byte order — folded through the bit plane's map A_s."""
+    row = crc_fold.tables(WORD_ROW, crc_block)
     t = crc_fold.tables(block_n, crc_block)
-    w0 = np.zeros((block_n, _CRC_LANES), np.uint8)
-    w0[:, :32] = t.w0
-    return (jnp.asarray(w0, jnp.bfloat16),
-            jnp.asarray(_pad_crc_const(t.planes_t), jnp.bfloat16),
-            jnp.asarray(_pad_crc_const(t.posmats_t), jnp.bfloat16))
+    w = np.zeros((8, 4, LANES, _CRC_LANES), np.uint8)
+    for s in range(8):
+        ws = row.w0.astype(np.int64) @ t.planes[s].T.astype(np.int64) % 2
+        w[s, :, :, :32] = ws.reshape(LANES, 4, 32).transpose(1, 0, 2)
+    return (jax.device_put(w.reshape(-1, _CRC_LANES).astype(jnp.bfloat16)),
+            jax.device_put(_pad_crc_const(t.posmats_t).astype(jnp.bfloat16)))
 
 
 @functools.partial(jax.jit,
                    static_argnames=("out_rows", "in_rows", "interpret",
                                     "block_n", "mm", "crc_block"))
-def apply_bitmatrix_crc_pallas(bmat_pm: jax.Array, shards: jax.Array,
-                               w0: jax.Array, planes_t: jax.Array,
-                               posmats_t: jax.Array,
+def apply_bitmatrix_crc_pallas(bmat: jax.Array, words: jax.Array,
+                               w: jax.Array, posmats_t: jax.Array,
                                out_rows: int, in_rows: int,
                                interpret: bool = False,
                                block_n: int = BLOCK_N,
                                mm: str = "bf16",
                                crc_block: int = crc_fold.BLOCK):
     """apply_bitmatrix_pallas plus fused `.ecc` CRC32-C: returns
-    (parity (out_rows, n) uint8, crcs (in_rows + out_rows,
-    n // crc_block) uint32) — the crc32c of every `.ecc` block of every
-    shard row, data rows first, then parity rows.
+    (parity words (out_rows, n / WORD_ROW, LANES) uint32, crcs (in_rows
+    + out_rows, n // crc_block) uint32) — the crc32c of every `.ecc`
+    block of every shard row, data rows first, then parity rows.
 
     n must be a multiple of crc_block and the input must start on a
-    `.ecc` block boundary; w0/planes_t/posmats_t come from
+    `.ecc` block boundary; w/posmats_t come from
     `crc_kernel_consts(block_n, crc_block)`.
     """
-    n = shards.shape[1]
+    subs = words.shape[1]
+    n = subs * WORD_ROW
     if n % crc_block or crc_block % block_n:
         raise ValueError(
             f"width {n} must be a multiple of the .ecc block "
             f"{crc_block}, itself a multiple of block_n {block_n}")
-    grid = (n // block_n,)
+    sub = block_n // WORD_ROW
     tpb = crc_block // block_n
     nb = n // crc_block
     rows = in_rows + out_rows
     rpad = -(-rows // _CRC_ROW_ALIGN) * _CRC_ROW_ALIGN
-    mm_dtype = jnp.bfloat16 if mm == "bf16" else jnp.int8
+    mm_dtype = _MM_DTYPES[mm]
     kernel = functools.partial(_rs_crc_kernel, out_rows=out_rows,
-                               in_rows=in_rows, mm_dtype=mm_dtype,
-                               tpb=tpb)
+                               mm_dtype=mm_dtype, tpb=tpb)
     parity, counts = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(n // block_n,),
         in_specs=[
-            pl.BlockSpec((8 * out_rows, 8 * in_rows), lambda i: (0, 0),
+            pl.BlockSpec((64 * out_rows, 64 * in_rows), lambda i: (0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((in_rows, block_n), lambda i: (0, i),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((block_n, _CRC_LANES), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((8 * _CRC_LANES, _CRC_LANES), lambda i: (0, 0),
+            _block_spec(in_rows, block_n),
+            pl.BlockSpec((32 * LANES, _CRC_LANES), lambda i: (0, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((_CRC_LANES, _CRC_LANES),
                          lambda i: (i % tpb, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=[
-            pl.BlockSpec((out_rows, block_n), lambda i: (0, i),
-                         memory_space=pltpu.VMEM),
+            _block_spec(out_rows, block_n),
             # One accumulator block per `.ecc` block: its index holds
             # still for tpb consecutive grid steps.
-            pl.BlockSpec((rpad, _CRC_LANES), lambda i: (i // tpb, 0),
+            pl.BlockSpec((rpad * sub, _CRC_LANES), lambda i: (i // tpb, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((out_rows, n), jnp.uint8),
-            jax.ShapeDtypeStruct((nb * rpad, _CRC_LANES), jnp.int32),
+            jax.ShapeDtypeStruct((out_rows, subs, LANES), jnp.uint32),
+            jax.ShapeDtypeStruct((nb * rpad * sub, _CRC_LANES), jnp.int32),
         ],
         cost_estimate=pl.CostEstimate(
             flops=2 * 8 * out_rows * 8 * in_rows * n
@@ -283,17 +336,18 @@ def apply_bitmatrix_crc_pallas(bmat_pm: jax.Array, shards: jax.Array,
             transcendentals=0,
         ),
         interpret=interpret,
-    )(bmat_pm.astype(mm_dtype), shards, w0, planes_t, posmats_t)
-    bits = counts.reshape(nb, rpad, _CRC_LANES)[:, :rows, :32] & 1
-    words = jnp.sum(
-        bits.astype(jnp.uint32) << jnp.arange(32, dtype=jnp.uint32),
+    )(bmat.astype(mm_dtype), words, w, posmats_t)
+    bits = counts.reshape(nb, rpad, sub, _CRC_LANES)[:, :rows, :, :32] & 1
+    # Sublane row t's own place in its tile: 512 * (sub - 1 - t) bytes
+    # of zeros follow it there.
+    shift = crc_fold.tables(WORD_ROW, crc_block).posmats[-sub:]
+    state = jnp.einsum("brti,toi->bro", bits,
+                       jnp.asarray(shift, jnp.int32)) & 1
+    crcs = jnp.sum(
+        state.astype(jnp.uint32) << jnp.arange(32, dtype=jnp.uint32),
         axis=2, dtype=jnp.uint32)
     const = crc_fold.tables(block_n, crc_block).block_const
-    return parity, words.T ^ jnp.uint32(const)
-
-
-def pad_to_block(n: int, block_n: int = BLOCK_N) -> int:
-    return -(-n // block_n) * block_n
+    return parity, crcs.T ^ jnp.uint32(const)
 
 
 class _Programs:
@@ -361,9 +415,8 @@ class PallasCoder:
             if interpret is None else interpret
         self._plane_major = plane_major
         pb = self.codec.parity_bitmatrix()
-        self._parity_pm = jnp.asarray(
-            plane_major(pb, self.parity_shards, self.data_shards),
-            jnp.bfloat16)
+        self._parity_mat = jax.device_put(tile_matrix(
+            plane_major(pb, self.parity_shards, self.data_shards), self.mm))
         self._crc_consts = None
         # rows a read's launch gives: the most shards the scheme loses
         self.read_rows = self.total_shards - self.data_shards
@@ -374,19 +427,25 @@ class PallasCoder:
     # first: a volume's loss pattern is one key a set of wanted rows.
     _MATS_KEPT = 256
 
-    def _apply(self, mat_pm: jax.Array, shards: jax.Array,
+    def _apply(self, mat: jax.Array, words: jax.Array,
                out_rows: int) -> jax.Array:
-        n = shards.shape[1]
-        padded = pad_to_block(n, self.block_n)
-        if padded != n:
-            shards = jnp.pad(shards, ((0, 0), (0, padded - n)))
         # in_rows follows the stacked survivors, not the scheme: a
         # minimal-read LRC decode feeds 5 rows, not data_shards.
-        out = apply_bitmatrix_pallas(mat_pm, shards, out_rows,
-                                     int(shards.shape[0]),
-                                     interpret=self.interpret,
-                                     block_n=self.block_n, mm=self.mm)
-        return out[:, :n]
+        return apply_bitmatrix_pallas(mat, words, out_rows,
+                                      int(words.shape[0]),
+                                      interpret=self.interpret,
+                                      block_n=self.block_n, mm=self.mm)
+
+    @staticmethod
+    def _send(rows) -> jax.Array:
+        """Issues the transfer of the host rows as words and returns."""
+        return jax.device_put(host_words(rows))
+
+    @staticmethod
+    def _bytes_of(words: jax.Array, n: int):
+        """The handle to drain: the words, or for a padded width the
+        `(rows, n)` bytes on the host, waited for."""
+        return words if n % BLOCK_N == 0 else host_rows(words)[:, :n]
 
     @property
     def fused_crc_ok(self) -> bool:
@@ -396,24 +455,24 @@ class PallasCoder:
         return crc_fold.BLOCK % self.block_n == 0
 
     def _on_device(self, data) -> jax.Array:
-        """`data` as a (data_shards, n) uint8 device array; for a host
-        array this issues the transfer and returns."""
-        data = jnp.asarray(data, jnp.uint8)
+        """`data` as the (data_shards, n / WORD_ROW, LANES) words on the
+        device; issues the transfer and returns."""
+        data = np.asarray(data, np.uint8)
         if data.shape[0] != self.data_shards:
             raise ValueError(
                 f"expected {self.data_shards} data shards, "
                 f"got {data.shape[0]}")
-        return data
+        return self._send(data)
 
-    def _launch_crc(self, data: jax.Array) -> tuple[jax.Array, jax.Array]:
+    def _launch_crc(self, words: jax.Array) -> tuple[jax.Array, jax.Array]:
         if self._crc_consts is None:
             self._crc_consts = crc_kernel_consts(self.block_n)
         return apply_bitmatrix_crc_pallas(
-            self._parity_pm, data, *self._crc_consts,
+            self._parity_mat, words, *self._crc_consts,
             self.parity_shards, self.data_shards,
             interpret=self.interpret, block_n=self.block_n, mm=self.mm)
 
-    def encode_with_crc(self, data) -> tuple[jax.Array, jax.Array]:
+    def encode_with_crc(self, data) -> tuple[np.ndarray, jax.Array]:
         """Encode AND checksum in one fused kernel.
 
         Returns (parity (p, n) uint8, crcs (k + p, n // BLOCK) uint32)
@@ -423,50 +482,59 @@ class PallasCoder:
         must start block-aligned in its shard files (the encoder's
         chunks are and do).
         """
-        data = self._on_device(data)
+        n = np.shape(data)[1]
+        if n % crc_fold.BLOCK:
+            raise ValueError(
+                f"width {n} must be a multiple of the .ecc block "
+                f"{crc_fold.BLOCK}")
+        words = self._on_device(data)
         t0 = time.perf_counter()
         # Execution-fenced wall: a dispatch-only wall would flatter
         # the fused kernel.
-        parity, crcs = jax.block_until_ready(self._launch_crc(data))
+        parity, crcs = jax.block_until_ready(self._launch_crc(words))
         _observe_call("encode_crc_kernel", self, t0,
                       out_rows=self.parity_shards,
-                      in_rows=self.data_shards, n=int(data.shape[1]),
-                      crc=True)
-        return parity, crcs
+                      in_rows=self.data_shards, n=n, crc=True)
+        return host_rows(parity), crcs
 
-    def encode(self, data) -> jax.Array:
-        data = self._on_device(data)
+    def encode(self, data) -> np.ndarray:
+        n = np.shape(data)[1]
+        words = self._on_device(data)
         t0 = time.perf_counter()
         out = jax.block_until_ready(
-            self._apply(self._parity_pm, data, self.parity_shards))
+            self._apply(self._parity_mat, words, self.parity_shards))
         _observe_call("encode_kernel", self, t0,
                       out_rows=self.parity_shards,
-                      in_rows=int(data.shape[0]), n=int(data.shape[1]))
-        return out
+                      in_rows=self.data_shards, n=n)
+        return host_rows(out)[:, :n]
 
     def encode_unfenced(self, data, crc: bool = False) -> tuple:
         """`encode` (or, with `crc`, `encode_with_crc`) for the caller
         that drains later (ec/encoder.py `_pipelined_encode`): issues
         the transfer and the kernel and returns the handles, `(parity,)`
-        or `(parity, crcs)`, waiting for neither.  It records no row:
-        `RooflineLedger.record` takes execution-fenced walls only, and a
-        dispatch-only wall would read as an impossible rate.  A device
-        error surfaces where the caller collects the handles."""
-        data = self._on_device(data)
+        or `(parity, crcs)`, waiting for neither; the parity handle is
+        the words, which `host_rows` views as bytes once drained.  It
+        records no row: `RooflineLedger.record` takes execution-fenced
+        walls only, and a dispatch-only wall would read as an impossible
+        rate.  A device error surfaces where the caller collects the
+        handles."""
+        n = np.shape(data)[1]
+        words = self._on_device(data)
         if _roofline.ARMED:
             _roofline.LEDGER.mark_device()
         if crc:
-            return self._launch_crc(data)
-        return (self._apply(self._parity_pm, data, self.parity_shards),)
+            return self._launch_crc(words)
+        return (self._bytes_of(
+            self._apply(self._parity_mat, words, self.parity_shards), n),)
 
-    def encode_all(self, data) -> jax.Array:
-        data = jnp.asarray(data, jnp.uint8)
-        return jnp.concatenate([data, self.encode(data)], axis=0)
+    def encode_all(self, data) -> np.ndarray:
+        data = np.asarray(data, np.uint8)
+        return np.concatenate([data, self.encode(data)], axis=0)
 
-    def _decode_mat_pm(self, present: tuple[int, ...],
+    def _decode_mat(self, present: tuple[int, ...],
                        wanted: tuple[int, ...], out_rows: int = 0):
-        """(the plane-major decode matrix of a loss pattern ON THE
-        DEVICE, the survivors it reads), kept after the pattern's first
+        """(the tiled decode matrix of a loss pattern ON THE DEVICE, the
+        survivors it reads), kept after the pattern's first
         use in a bounded map on the coder: no bit-matrix build and no
         matrix transfer after it.  With `out_rows` the matrix has that
         many row planes, the wanted rows first and zeros after them:
@@ -485,8 +553,8 @@ class PallasCoder:
             full[:bmat.shape[0]] = bmat
             bmat = full
         pm = self._plane_major(bmat, rows, len(used))
-        # converted on the host: the transfer is the only device work
-        hit = (jax.device_put(np.asarray(pm, dtype=jnp.bfloat16)), used)
+        # tiled on the host: the transfer is the only device work
+        hit = (jax.device_put(tile_matrix(pm, self.mm)), used)
         with self._mats_lock:
             self._mats[key] = hit
             while len(self._mats) > self._MATS_KEPT:
@@ -510,16 +578,17 @@ class PallasCoder:
              self.interpret), self._compile_read, in_rows, width)
 
     def _compile_read(self, in_rows: int, width: int) -> None:
-        mat = jax.device_put(np.zeros(
-            (8 * self.read_rows, 8 * in_rows), dtype=jnp.bfloat16))
-        jax.block_until_ready(self._launch_read(
-            mat, np.zeros((in_rows, width), np.uint8)))
+        # zeros sent from the host: a device-side `jnp.zeros` would be
+        # one more program to compile
+        mat = jax.device_put(np.zeros((64 * self.read_rows, 64 * in_rows),
+                                      _MM_DTYPES[self.mm]))
+        jax.block_until_ready(self._launch_read(mat, jax.device_put(
+            np.zeros((in_rows, width // WORD_ROW, LANES), np.uint32))))
 
-    def _launch_read(self, mat_pm: jax.Array, stacked) -> jax.Array:
+    def _launch_read(self, mat: jax.Array, words: jax.Array) -> jax.Array:
         return apply_bitmatrix_pallas(
-            mat_pm, jnp.asarray(stacked, jnp.uint8), self.read_rows,
-            int(stacked.shape[0]), interpret=self.interpret,
-            block_n=self.block_n, mm=self.mm)
+            mat, words, self.read_rows, int(words.shape[0]),
+            interpret=self.interpret, block_n=self.block_n, mm=self.mm)
 
     def warm_reads(self) -> None:
         """Compile the read path's programs for the scheme's data
@@ -536,8 +605,9 @@ class PallasCoder:
         `present[j]`, ids ascending, W one of READ_WIDTHS (what lies
         past the interval's bytes may be anything: columns do not mix).
         One transfer and one launch of the width's one program; returns
-        the (read_rows, W) handle whose first len(wanted) rows are the
-        wanted shards, waiting for nothing.  A width whose program is
+        the handle of the (read_rows, W) words (`host_rows` views them
+        as bytes) whose first len(wanted) rows are the wanted shards,
+        waiting for nothing.  A width whose program is
         not compiled yet is compiled here, once, whoever else asks."""
         present, wanted = tuple(present), tuple(wanted)
         self._check_wanted(wanted)
@@ -545,17 +615,17 @@ class PallasCoder:
             raise ValueError(
                 f"{len(wanted)} rows wanted of a call that gives "
                 f"{self.read_rows}")
-        mat_pm, used = self._decode_mat_pm(present, wanted, self.read_rows)
+        mat, used = self._decode_mat(present, wanted, self.read_rows)
         if used != present:
             # More survivors than the decode reads: it takes its own.
             stacked = stacked[[present.index(s) for s in used]]
-        return self._read_call(mat_pm, stacked)
+        return self._read_call(mat, stacked)
 
-    def _read_call(self, mat_pm: jax.Array, stacked) -> jax.Array:
+    def _read_call(self, mat: jax.Array, stacked) -> jax.Array:
         self._ensure_read_program(*stacked.shape)
         if _roofline.ARMED:
             _roofline.LEDGER.mark_device()
-        return self._launch_read(mat_pm, stacked)
+        return self._launch_read(mat, self._send(stacked))
 
     def reconstruct(self, shards: dict[int, jax.Array],
                     wanted: list[int] | None = None) -> dict[int, np.ndarray]:
@@ -577,7 +647,7 @@ class PallasCoder:
         out = {w: np.empty(n, np.uint8) for w in wanted}
         for g in range(0, len(wanted), self.read_rows):
             group = tuple(wanted[g:g + self.read_rows])
-            mat_pm, used = self._decode_mat_pm(present, group,
+            mat, used = self._decode_mat(present, group,
                                                self.read_rows)
             for off in range(0, n, READ_WIDTHS[-1]):
                 take = min(n - off, READ_WIDTHS[-1])
@@ -586,7 +656,7 @@ class PallasCoder:
                 for j, s in enumerate(used):
                     stacked[j, :take] = rows[s][off:off + take]
                 t0 = time.perf_counter()
-                rec = np.asarray(self._read_call(mat_pm, stacked))
+                rec = host_rows(self._read_call(mat, stacked))
                 _observe_call("reconstruct_kernel", self, t0,
                               out_rows=self.read_rows,
                               in_rows=len(used), n=width)
@@ -599,25 +669,27 @@ class PallasCoder:
         `rebuild_ec_files`) and has the survivors in ONE host array
         already: row j of the (len(present), n) uint8 `stacked` is
         shard `present[j]`, ids ascending.  Issues one transfer and the
-        kernel and returns the (len(wanted), n) handle, row i shard
-        `wanted[i]`, waiting for neither: no per-shard transfer and no
-        stack on the device.  Like `encode_unfenced` it records no row,
+        kernel and returns the handle of the (len(wanted), n) words
+        (`host_rows` views them as bytes), row i shard `wanted[i]`,
+        waiting for neither: no per-shard transfer and no stack on the
+        device.  Like `encode_unfenced` it records no row,
         and a device error surfaces where the caller collects the
         handle.  Its shape is the job's chunk, the same for every call
         of a job: a read, whose widths are its intervals', goes through
         `reconstruct_padded`."""
         present, wanted = tuple(present), tuple(wanted)
         self._check_wanted(wanted)
-        mat_pm, used = self._decode_mat_pm(present, wanted)
+        mat, used = self._decode_mat(present, wanted)
         if used != present:
             # More survivors than the decode reads: it takes its own.
             stacked = stacked[[present.index(s) for s in used]]
         if _roofline.ARMED:
             _roofline.LEDGER.mark_device()
-        return self._apply(mat_pm, jnp.asarray(stacked, jnp.uint8),
-                           len(wanted))
+        return self._bytes_of(
+            self._apply(mat, self._send(stacked), len(wanted)),
+            stacked.shape[1])
 
     def verify(self, shards) -> bool:
-        shards = jnp.asarray(shards, jnp.uint8)
+        shards = np.asarray(shards, np.uint8)
         parity = self.encode(shards[: self.data_shards])
-        return bool(jnp.array_equal(parity, shards[self.data_shards:]))
+        return bool(np.array_equal(parity, shards[self.data_shards:]))

@@ -129,7 +129,6 @@ class CrcFoldTables:
     Attributes (numpy, ready to be cast to the kernel's matmul dtype):
       w0      (tile_n, 32) uint8 — bit-0 column contribution weights
       planes  (8, 32, 32)  uint8 — A_s = Sh^s, plane-fold matrices
-      planes_t (256, 32)   uint8 — A_s transposed, stacked 2D for Pallas
       posmats (tpb, 32, 32) uint8 — P^(tpb-1-j), position-in-block shift
       posmats_t (tpb*32, 32) uint8 — transposed, stacked 2D for Pallas
       block_const  uint32  — crc32c of `block` zero bytes
@@ -174,8 +173,6 @@ class CrcFoldTables:
         for s in range(1, 8):
             planes[s] = _matmul2(shmat, planes[s - 1])
         self.planes = planes
-        self.planes_t = np.concatenate(
-            [planes[s].T for s in range(8)], axis=0)
 
         p_tile = _mat_pow(smat, tile_n)  # advance one whole tile
         posmats = np.zeros((self.tpb, 32, 32), dtype=np.uint8)

@@ -24,6 +24,7 @@ operating on (shards, n) uint8 arrays; results are byte-identical.
 from __future__ import annotations
 
 import os
+import threading
 from typing import Protocol
 
 import numpy as np
@@ -79,6 +80,51 @@ def read_width(n: int) -> int:
         if n <= w:
             return w
     return READ_WIDTHS[-1]
+
+
+class _LayoutCount:
+    """How the device coder's transfers crossed, process-wide
+    (`/debug/device` `coder_layout`): `words_in` / `words_out` count
+    arrays sent and taken back as the `(rows, n / 512, 128)` uint32
+    words whose tiled device layout is the chunk's row-major bytes
+    (ops/coder_pallas.py), `padded` / `padded_bytes` the inputs whose
+    width was not a whole number of kernel blocks and were first copied
+    on the host into a padded array, and the bytes of those copies."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._counts = dict.fromkeys(
+            ("words_in", "words_out", "padded", "padded_bytes"), 0)
+
+    def note_in(self, padded_bytes: int = 0) -> None:
+        with self._lock:
+            self._counts["words_in"] += 1
+            if padded_bytes:
+                self._counts["padded"] += 1
+                self._counts["padded_bytes"] += padded_bytes
+
+    def note_out(self) -> None:
+        with self._lock:
+            self._counts["words_out"] += 1
+
+    def counts(self) -> dict:
+        with self._lock:
+            return dict(self._counts)
+
+
+CODER_LAYOUT = _LayoutCount()
+
+
+def host_rows(handle) -> np.ndarray:
+    """A coder result on the host as `(rows, n)` uint8: a device coder's
+    `(rows, n / 512, 128)` uint32 words viewed as the bytes they hold,
+    no copy; a host coder's bytes as they are.  `np.asarray` of a device
+    array waits for it and its copy back."""
+    out = np.asarray(handle)
+    if out.dtype == np.uint8:
+        return out
+    CODER_LAYOUT.note_out()
+    return out.view(np.uint8).reshape(out.shape[0], -1)
 
 
 def _native_available() -> bool:

@@ -748,9 +748,11 @@ def debug_doc(node: str, role: str) -> dict:
     the drains of the seals and of the rebuilds found the oldest chunk
     in flight (SEAL_INFLIGHT, REBUILD_INFLIGHT: `ready` or `waited`)
     and how the seals' hand-overs found the writer threads
-    (SEAL_WRITER), and which way a GET's shard reads go (`ec_reads`:
-    ec/volume.py `read_many_path`)."""
+    (SEAL_WRITER), which way a GET's shard reads go (`ec_reads`:
+    ec/volume.py `read_many_path`), and how the device coder's transfers
+    crossed (`coder_layout`: ops/erasure.py CODER_LAYOUT)."""
     from ..ec import encoder, volume
+    from ..ops import erasure
     return {"node": node, "role": role, "armed": ARMED,
             "kernels": LEDGER.kernel_table() + LEDGER.stage_table(),
             "recent": LEDGER.recent(16),
@@ -762,4 +764,5 @@ def debug_doc(node: str, role: str) -> dict:
             "seal_inflight": encoder.SEAL_INFLIGHT.counts(),
             "seal_writer": encoder.SEAL_WRITER.counts(),
             "rebuild_inflight": encoder.REBUILD_INFLIGHT.counts(),
-            "ec_reads": volume.read_many_path()}
+            "ec_reads": volume.read_many_path(),
+            "coder_layout": erasure.CODER_LAYOUT.counts()}

@@ -35,6 +35,56 @@ def test_parity_only_reconstruction_skips_data_solve():
         assert np.array_equal(rec[i], shards[i])
 
 
+# -- the device coder's transfer layout ----------------------------------------
+
+def _host_bytes_pass_through():
+    from seaweedfs_tpu.ops.erasure import host_rows
+    rows = np.arange(24, dtype=np.uint8).reshape(2, 12)
+    assert host_rows(rows) is rows
+    return 0
+
+
+def _a_fused_width_off_the_ecc_block_is_refused_unsent():
+    from seaweedfs_tpu.ops.coder_pallas import PallasCoder
+    from seaweedfs_tpu.ops.crc_fold import BLOCK
+    with pytest.raises(ValueError, match=".ecc block"):
+        PallasCoder().encode_with_crc(np.zeros((10, BLOCK - 512), np.uint8))
+    return 0
+
+
+def _words_come_back_as_bytes():
+    from seaweedfs_tpu.ops.coder_pallas import PallasCoder
+    from seaweedfs_tpu.ops.erasure import host_rows
+    data = np.random.default_rng(2).integers(0, 256, (4, 8192),
+                                             dtype=np.uint8)
+    (handle,) = PallasCoder(4, 2).encode_unfenced(data)
+    rows = host_rows(handle)
+    assert rows.shape == (2, 8192) and rows.base is not None
+    assert np.array_equal(rows, NumpyCoder(4, 2).encode(data))
+    return 1
+
+
+@pytest.mark.parametrize("check", [
+    _host_bytes_pass_through,
+    _a_fused_width_off_the_ecc_block_is_refused_unsent,
+    _words_come_back_as_bytes,
+], ids=["host", "refused", "words"])
+def test_coder_layout_counts_what_crossed(check):
+    """`/debug/device` serves `coder_layout`; a host array passes
+    `host_rows` as it is and counts nothing, a refused call sends
+    nothing, and words taken back count once."""
+    from seaweedfs_tpu.ops.erasure import CODER_LAYOUT
+    from seaweedfs_tpu.stats import roofline
+    before = roofline.debug_doc("n:1", "volume")["coder_layout"]
+    assert set(before) == {"words_in", "words_out", "padded",
+                           "padded_bytes"}
+    sent = check()
+    after = CODER_LAYOUT.counts()
+    assert after["words_in"] - before["words_in"] == sent
+    assert after["words_out"] - before["words_out"] == sent
+    assert after["padded"] == before["padded"]
+
+
 # -- switches that became constants -------------------------------------------
 # Each of these environment variables once selected a path on the device
 # coder's layer by hand.  Setting one now selects nothing: the default is

@@ -25,7 +25,7 @@ from seaweedfs_tpu.ops import coder_pallas
 from seaweedfs_tpu.ops.coder_numpy import NumpyCoder
 from seaweedfs_tpu.ops.coder_pallas import (READ_PROGRAMS, PallasCoder,
                                             apply_bitmatrix_pallas)
-from seaweedfs_tpu.ops.erasure import READ_WIDTHS, read_width
+from seaweedfs_tpu.ops.erasure import READ_WIDTHS, host_rows, read_width
 from seaweedfs_tpu.stats import roofline
 
 MIB = 1 << 20
@@ -121,8 +121,10 @@ def test_the_padded_call_is_one_transfer_one_program(oracle, coder):
     for j, s in enumerate(present):
         buf[j, :size] = full[s]
     programs = apply_bitmatrix_pallas._cache_size()
-    out = np.asarray(coder.reconstruct_padded(present, buf, (2, 0)))
+    handle = coder.reconstruct_padded(present, buf, (2, 0))
+    out = host_rows(handle)
     assert out.shape == (coder.read_rows, width)
+    assert np.shares_memory(out, np.asarray(handle))
     assert np.array_equal(out[0, :size], full[2])
     assert np.array_equal(out[1, :size], full[0])
     assert not out[2:].any()

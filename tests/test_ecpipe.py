@@ -86,6 +86,103 @@ def test_fused_kernel_crcs_bit_exact(codec, mm):
         coder.encode_with_crc(data[:, :BLOCK + 4096])
 
 
+# ---------------------------------------------------------------------------
+# The transfer layout: the chunk's bytes as (rows, n / 512, 128) words
+# ---------------------------------------------------------------------------
+
+RS_LOST = (3, 11)          # the rebuild cell's loss
+DATA_LOST = (0, 1, 2, 3)   # the degraded read's: four data shards
+
+
+@pytest.mark.parametrize("call,n", [
+    pytest.param("encode", 2 * BLOCK, id="encode"),
+    pytest.param("encode_crc", 2 * BLOCK, id="encode_crc"),
+    pytest.param("rebuild", 16 * 4096, id="rebuild_3_11"),
+    *[pytest.param("read", w, id=f"read_{w}")
+      for w in (4 << 10, 16 << 10, 64 << 10, 256 << 10, 1 << 20)],
+    pytest.param("encode", 1000, id="encode_padded"),
+    pytest.param("rebuild", 5000, id="rebuild_padded"),
+])
+def test_word_layout_calls_match_numpy(call, n):
+    """Every device call in the transfer layout against NumpyCoder: the
+    fenced and the unfenced encode, with and without the fused CRCs
+    (each the crc32c of its `.ecc` block, byte for byte), the rebuild's
+    loss of shards 3 and 11, four lost data shards at every read width,
+    and widths that are no whole number of blocks, which take ONE host
+    pad a transfer and say so in `coder_layout`.  A drained handle is
+    the bytes of the words that came back: a view, not a copy."""
+    from seaweedfs_tpu.ops.coder_pallas import BLOCK_N
+    from seaweedfs_tpu.ops.erasure import CODER_LAYOUT, READ_WIDTHS, host_rows
+    if call == "read":
+        assert n in READ_WIDTHS
+    rng = np.random.default_rng(n)
+    data = rng.integers(0, 256, (10, n), dtype=np.uint8)
+    oracle = NumpyCoder(10, 4)
+    full = oracle.encode_all(data)
+    coder = PallasCoder(mm="int8")
+    before = CODER_LAYOUT.counts()
+    if call in ("encode", "encode_crc"):
+        crc = call == "encode_crc"
+        fenced = coder.encode_with_crc(data) if crc \
+            else (coder.encode(data),)
+        handles = coder.encode_unfenced(data, crc=crc)
+        sends, want = 2, full[10:]
+        got = [fenced[0], host_rows(handles[0])]
+        if crc:
+            want_crcs = [[crc32c(row[b * BLOCK:(b + 1) * BLOCK].tobytes())
+                          for b in range(n // BLOCK)] for row in full]
+            for h in (fenced, handles):
+                assert np.asarray(h[1]).tolist() == want_crcs
+    elif call == "rebuild":
+        present = tuple(s for s in range(14) if s not in RS_LOST)[:10]
+        handles = (coder.reconstruct_unfenced(
+            present, np.ascontiguousarray(full[list(present)]), RS_LOST),)
+        sends, want = 1, full[list(RS_LOST)]
+        got = [host_rows(handles[0])]
+    else:
+        present = tuple(range(4, 14))
+        handles = (coder.reconstruct_padded(
+            present, np.ascontiguousarray(full[4:]), DATA_LOST),)
+        sends, want = 1, full[:4]
+        got = [host_rows(handles[0])]
+    for rows in got:
+        assert rows.dtype == np.uint8 and np.array_equal(rows, want)
+    if n % BLOCK_N == 0:
+        # the unfenced handle is the words themselves, drained in place
+        assert handles[0].dtype == np.uint32
+        assert handles[0].shape == (len(want), n // 512, 128)
+        assert np.shares_memory(got[-1], np.asarray(handles[0]))
+    after = CODER_LAYOUT.counts()
+    padded = sends if n % BLOCK_N else 0
+    assert after["words_in"] - before["words_in"] == sends
+    assert after["padded"] - before["padded"] == padded
+    assert after["padded_bytes"] - before["padded_bytes"] == \
+        padded * 10 * -(-n // BLOCK_N) * BLOCK_N
+
+
+def test_the_transfer_is_a_view_of_the_pooled_chunk(monkeypatch):
+    """What crosses to the device is the chunk's own memory, viewed as
+    words: no copy on the host, before or after the coder."""
+    from seaweedfs_tpu.ec.encoder import CHUNK_POOL
+    from seaweedfs_tpu.ops import coder_pallas
+    coder = PallasCoder()
+    sent = []
+    real = coder_pallas.jax.device_put
+    monkeypatch.setattr(coder_pallas.jax, "device_put",
+                        lambda x, *a, **k: sent.append(x) or real(x, *a, **k))
+    buf = CHUNK_POOL.take(10 * BLOCK)
+    try:
+        chunk = buf[:10 * BLOCK].reshape(10, BLOCK)
+        chunk[:] = np.random.default_rng(5).integers(0, 256, chunk.shape,
+                                                     dtype=np.uint8)
+        (handle,) = coder.encode_unfenced(chunk)
+        assert len(sent) == 1 and sent[0].dtype == np.uint32
+        assert np.shares_memory(sent[0], buf)
+        np.asarray(handle)
+    finally:
+        CHUNK_POOL.give(buf)
+
+
 def test_int8_mm_correctness_gate():
     """Satellite: int8 is the on-TPU serving default (BENCH tuned it
     fastest) — gate it against the NumpyCoder oracle for encode AND
